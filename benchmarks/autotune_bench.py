@@ -80,12 +80,10 @@ def run(print_fn=print):
         "rank_corr_positive_attention": False,
         "rank_corr_positive_mamba": False,
     }
-    probe = MeasuredRunner()
-    derived["pallas_available"] = probe.available()
-    if not probe.available():
-        print_fn("[autotune] pallas unavailable (REPRO_NO_PALLAS?) — "
-                 "skipping measurements")
-        return derived
+    if not MeasuredRunner().available():
+        raise RuntimeError(
+            "the autotune bench measures Pallas kernels, and REPRO_NO_PALLAS "
+            "turns measurement off; unset it to run this bench")
 
     t = Table(f"autotune: predicted vs measured ({mode})",
               ["kernel", "configs", "spearman", "tuned config",

@@ -2,8 +2,10 @@
 
 Prints, per (arch x shape) on the single-pod mesh: the three roofline terms,
 the dominant bottleneck, MODEL_FLOPS/HLO_FLOPs, and per-device memory.  The
-dry-run itself must run in a separate process (512 fake devices); this bench
-only *reads* its records, so `-m benchmarks.run` stays single-device."""
+dry-run itself runs in a separate, CPU-only process (512 fake host devices);
+this bench only *reads* its records, so `-m benchmarks.run` stays
+single-device.  `ensure_some_records` starts that process, and refuses to
+once this process has imported JAX: `benchmarks.run` calls it first."""
 from __future__ import annotations
 
 import json
@@ -11,8 +13,6 @@ import os
 import subprocess
 import sys
 from typing import Dict, List
-
-from .common import Table
 
 DRYRUN_PATH = os.environ.get("REPRO_DRYRUN_JSONL", "results/dryrun.jsonl")
 
@@ -35,18 +35,24 @@ def ensure_some_records(print_fn=print) -> List[Dict]:
     recs = load_records()
     if recs:
         return recs
+    if "jax" in sys.modules:
+        raise RuntimeError(
+            "no dry-run records, and this process has imported JAX: start "
+            "the dry-run before importing JAX (benchmarks.run does)")
     # generate one representative cell so the bench is self-contained
     print_fn("[roofline] no dry-run records found; running one cell "
-             "(gemma-2b x train_4k) in a subprocess...")
+             "(gemma-2b x train_4k) in a CPU-only subprocess...")
     env = dict(os.environ, PYTHONPATH="src")
     subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", "--arch", "gemma-2b",
          "--shape", "train_4k", "--out", DRYRUN_PATH],
-        env=env, check=False, timeout=1800)
+        env=env, check=True, timeout=1800)
     return load_records()
 
 
 def run(print_fn=print):
+    from .common import Table
+
     recs = ensure_some_records(print_fn)
     single = [r for r in recs if r["mesh"] == "16x16"]
     multi = [r for r in recs if r["mesh"] == "2x16x16"]

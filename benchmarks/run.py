@@ -37,34 +37,37 @@ contract); any mismatch makes the run exit nonzero so CI gates on it.
 """
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import sys
 import time
 import traceback
 
-from . import (autotune_bench, bridge_validation, fig7_tile, fig8_buffer,
-               fig9_order, fig10_parallelism, fig11_shape, fig12_arraysize,
-               fig13_futureproof, flexion_bench, roofline, service_bench,
-               table3_area)
 from ._compare import derived_equal, public_derived
-from .common import bench_mode, campaign_mode
 
+# name -> (module, headline metric); modules import lazily so the roofline
+# dry-run child can start before this process imports JAX
 BENCHES = {
-    "table3": (table3_area, "fullflex_overhead_pct"),
-    "fig7": (fig7_tile, "fullflex1000_speedup"),
-    "fig8": (fig8_buffer, "speedup_1k_to_64k"),
-    "fig9": (fig9_order, "fullflex0100_speedup"),
-    "fig10": (fig10_parallelism, "fullflex_speedup_16x64"),
-    "fig11": (fig11_shape, "fullflex_speedup"),
-    "fig12": (fig12_arraysize, "speedup_256_to_1024"),
-    "fig13": (fig13_futureproof, "fullflex1111_geomean_future"),
-    "flexion": (flexion_bench, "partflex1000_hf_T"),
-    "roofline": (roofline, "cells_ok"),
-    "bridge": (bridge_validation, "long_decode_speedup"),
-    "service": (service_bench, "_speedup_vs_sequential"),
-    "autotune": (autotune_bench, "parity_ok"),
+    "table3": ("table3_area", "fullflex_overhead_pct"),
+    "fig7": ("fig7_tile", "fullflex1000_speedup"),
+    "fig8": ("fig8_buffer", "speedup_1k_to_64k"),
+    "fig9": ("fig9_order", "fullflex0100_speedup"),
+    "fig10": ("fig10_parallelism", "fullflex_speedup_16x64"),
+    "fig11": ("fig11_shape", "fullflex_speedup"),
+    "fig12": ("fig12_arraysize", "speedup_256_to_1024"),
+    "fig13": ("fig13_futureproof", "fullflex1111_geomean_future"),
+    "flexion": ("flexion_bench", "partflex1000_hf_T"),
+    "roofline": ("roofline", "cells_ok"),
+    "bridge": ("bridge_validation", "long_decode_speedup"),
+    "service": ("service_bench", "_speedup_vs_sequential"),
+    "autotune": ("autotune_bench", "parity_ok"),
 }
+
+
+def _module(name: str):
+    return importlib.import_module(f".{BENCHES[name][0]}", __package__)
+
 
 BENCH_SCHEMA = "repro-bench-mapper/v7"
 
@@ -98,7 +101,7 @@ def _warm_engine(engine: str) -> None:
                             search_fixed_configs)
     from repro.core.engine import ROW_BUCKET, warmup_engine
 
-    from .common import ga_budget
+    from .common import bench_mode, ga_budget
 
     cfg = ga_budget()
     is_campaign = engine.startswith("campaign")
@@ -144,10 +147,10 @@ def _run_once(names):
     results = {}
     failed = 0
     for name in names:
-        mod, headline = BENCHES[name]
+        headline = BENCHES[name][1]
         t0 = time.time()
         try:
-            derived = mod.run()
+            derived = _module(name).run()
             results[name] = derived
             dt_us = (time.time() - t0) * 1e6
             csv_rows.append((name, dt_us, derived.get(headline)))
@@ -175,6 +178,7 @@ def _bench_json(engine_rows, engine_results, devices=None):
     metrics (+ campaign phase timings), pairwise speedups between passes,
     and — when a ``--devices`` pass ran — a ``device_scaling`` block
     recording the pool size and the campaign → sharded-campaign speedup."""
+    from .common import bench_mode
     doc = {
         "schema": BENCH_SCHEMA,
         "bench_mode": bench_mode(),
@@ -215,11 +219,8 @@ def _bench_json(engine_rows, engine_results, devices=None):
             doc[key] = _speedup_row(engine_rows[a], engine_rows[b])
     if devices:
         label = f"campaign-d{devices}"
-        try:
-            import jax
-            available = len(jax.local_devices())
-        except Exception:  # noqa: BLE001
-            available = None
+        import jax
+        available = len(jax.local_devices())
         try:
             requested = int(devices)
         except ValueError:
@@ -236,26 +237,16 @@ def _bench_json(engine_rows, engine_results, devices=None):
     return doc
 
 
-def _enable_persistent_jax_cache() -> None:
-    """Persistent XLA compilation cache for bench runs: the batched engine's
-    one-time program compile amortizes across processes (set
-    REPRO_JAX_CACHE_DIR=0 to disable, or point it somewhere else)."""
-    cache_dir = os.environ.get(
-        "REPRO_JAX_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro-flex-xla"))
-    if cache_dir == "0":
-        return
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
-    except Exception:  # noqa: BLE001 - cache is best-effort
-        pass
-
-
 def main(argv=None) -> int:
     argv = argv if argv is not None else sys.argv[1:]
-    _enable_persistent_jax_cache()
+    # the roofline bench reads dry-run records; generating them starts a
+    # JAX child, which must happen before this process imports JAX
+    if "roofline" in argv or not any(a in BENCHES for a in argv):
+        _module("roofline").ensure_some_records()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    from .common import bench_mode, campaign_mode
+    enable_compile_cache()
     json_path = None
     engines = None
     campaign = False
@@ -347,10 +338,9 @@ def main(argv=None) -> int:
             os.environ["REPRO_ENGINE"] = engine
             os.environ.pop("REPRO_CAMPAIGN", None)
             os.environ.pop("REPRO_DEVICES", None)
-        try:
-            _warm_engine(engine)
-        except Exception:  # noqa: BLE001 - warmup is best-effort
-            traceback.print_exc()
+        # a warmup that fails would leave the compile inside the timed
+        # pass, so it fails the run
+        _warm_engine(engine)
         rows, results, nfail = _run_once(names)
         engine_rows[engine] = rows
         engine_results[engine] = results

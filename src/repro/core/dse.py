@@ -170,7 +170,10 @@ def future_proofing_study(base_model: str = "alexnet",
                           timings: Optional[Dict[str, float]] = None,
                           flexion: Optional[Dict[str, float]] = None,
                           wflexion: Optional[Dict[str, float]] = None,
-                          flexion_samples: int = 20_000
+                          flexion_samples: int = 20_000,
+                          results: Optional[Dict[Tuple[str, str],
+                                                 Tuple[FlexSpec,
+                                                       ModelResult]]] = None
                           ) -> Dict[str, Dict[str, float]]:
     """Fig 13: rows = accelerator variants, cols = models, values = runtime
     normalized to InFlex-0000-<base>-Opt on that model.
@@ -200,8 +203,15 @@ def future_proofing_study(base_model: str = "alexnet",
     ``model_flexion_campaign`` batch where each variant spec is paired with
     the union of every future model's layers (W-F is workload-dependent, so
     the column reports the variant's average coverage of the whole future
-    suite's map spaces)."""
+    suite's map spaces).
+
+    ``results`` (optional dict) receives ``{(row_name, model): (spec,
+    ModelResult)}`` for every table cell before normalization: the
+    searched or replayed per-layer mappings and costs, and the spec they
+    were evaluated under."""
     cfg = cfg or GAConfig()
+    cells: Dict[Tuple[str, str], Tuple[FlexSpec, ModelResult]] = \
+        results if results is not None else {}
     t_acc: Dict[str, float] = timings if timings is not None else {}
 
     def tick(phase: str, t0: float) -> None:
@@ -232,10 +242,12 @@ def future_proofing_study(base_model: str = "alexnet",
     if campaign:
         replays = evaluate_fixed_genome_many(
             [(get_model(m), frozen, genome) for m in future_models])
-        row = {m: res.runtime for m, res in zip(future_models, replays)}
     else:
-        row = {m: evaluate_fixed_genome(get_model(m), frozen, genome).runtime
-               for m in future_models}
+        replays = [evaluate_fixed_genome(get_model(m), frozen, genome)
+                   for m in future_models]
+    row = {m: res.runtime for m, res in zip(future_models, replays)}
+    cells.update({(frozen.name, m): (frozen, res)
+                  for m, res in zip(future_models, replays)})
     baseline_rt.update(row)
     table[f"InFlex0000-{base_model}-Opt"] = row
     tick("replay_frozen", t0)
@@ -246,12 +258,15 @@ def future_proofing_study(base_model: str = "alexnet",
     row = {}
     for m in future_models:
         if m == base_model:
-            row[m] = baseline_rt[m]
+            cells["InFlex0000-X-Opt", m] = cells[frozen.name, m]
         elif campaign:
-            row[m] = designs[m][1].runtime
+            cells["InFlex0000-X-Opt", m] = (
+                FlexSpec(name=f"probe-{m}", hw=frozen.hw), designs[m][1])
         else:
-            _, _, res = design_fixed_accelerator(m, hw, cfg)
-            row[m] = res.runtime
+            spec_m, _, res = design_fixed_accelerator(m, hw, cfg)
+            cells["InFlex0000-X-Opt", m] = (
+                FlexSpec(name=f"probe-{m}", hw=spec_m.hw), res)
+        row[m] = cells["InFlex0000-X-Opt", m][1].runtime
     table["InFlex0000-X-Opt"] = row
     tick("design_fixed", t0)
 
@@ -287,17 +302,20 @@ def future_proofing_study(base_model: str = "alexnet",
              for spec in flex_specs], cfg))
         for m in future_models:
             for spec in flex_specs:
-                table[spec.name][m] = next(all_res).runtime
+                cells[spec.name, m] = (spec, next(all_res))
     else:
         for m in future_models:
             layers = get_model(m)
             if cfg.engine == "batched":
-                results = search_specs_batched(layers, flex_specs, cfg)
+                model_res = search_specs_batched(layers, flex_specs, cfg)
             else:
-                results = [search_model(layers, spec, cfg)
-                           for spec in flex_specs]
-            for spec, mres in zip(flex_specs, results):
-                table[spec.name][m] = mres.runtime
+                model_res = [search_model(layers, spec, cfg)
+                             for spec in flex_specs]
+            for spec, mres in zip(flex_specs, model_res):
+                cells[spec.name, m] = (spec, mres)
+    for m in future_models:
+        for spec in flex_specs:
+            table[spec.name][m] = cells[spec.name, m][1].runtime
     tick("flex_sweep", t0)
 
     # normalize by the frozen baseline per column

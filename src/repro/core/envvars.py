@@ -59,13 +59,14 @@ REGISTRY: Tuple[EnvVar, ...] = (
         ("repro.core.device_pool", "benchmarks.run")),
     EnvVar(
         "REPRO_FLEXION_BACKEND", "choice: numpy / jax", "auto",
-        "Forces the MC flexion predicate backend; auto picks jax only on "
-        "non-CPU backends (numpy is the golden stream on CPU).",
+        "Forces the MC flexion predicate backend; auto picks jax on an "
+        "accelerator backend and numpy (the golden stream) on the CPU.",
         ("repro.core.flexion_batched",)),
     EnvVar(
         "REPRO_NO_PALLAS", "flag", "off",
         "Kernel-bridge autotuning falls back to the modeled objective "
-        "instead of measured Pallas interpret-mode wall-clock.",
+        "instead of measured Pallas wall-clock (the autotune bench then "
+        "refuses to run).",
         ("repro.core.kernel_bridge",)),
     EnvVar(
         "REPRO_SERVICE_CLIENTS", "int", "4",
@@ -77,11 +78,6 @@ REGISTRY: Tuple[EnvVar, ...] = (
         "When set, the multi-pod roofline/bridge dry runs append each "
         "lowered program record to this JSONL file.",
         ("benchmarks.roofline", "benchmarks.bridge_validation")),
-    EnvVar(
-        "REPRO_JAX_CACHE_DIR", "path", "unset",
-        "Persistent jax compilation cache for bench runs (cuts repeat "
-        "bench-smoke compile time; never affects results).",
-        ("benchmarks.run",)),
 )
 
 _BY_NAME = {v.name: v for v in REGISTRY}

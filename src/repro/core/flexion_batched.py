@@ -163,16 +163,13 @@ def _default_reference(spec: FlexSpec) -> FlexSpec:
 
 
 def _backend() -> str:
+    """``REPRO_FLEXION_BACKEND`` when set; otherwise jax on an accelerator
+    backend and numpy (the golden float64 stream) on the CPU."""
     forced = get_env("REPRO_FLEXION_BACKEND", "")
     if forced in ("numpy", "jax"):
         return forced
-    try:
-        import jax
-        if jax.default_backend() != "cpu":
-            return "jax"
-    except Exception:  # noqa: BLE001 - jax is optional for flexion
-        pass
-    return "numpy"
+    import jax
+    return "numpy" if jax.default_backend() == "cpu" else "jax"
 
 
 def _draw_tiles(dims: np.ndarray, rng: np.random.Generator, n: int,

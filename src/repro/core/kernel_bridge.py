@@ -21,8 +21,8 @@ legality the mapper applies (``raw_tile_feasibility``) is mirrored here in
 numpy (``bridge_tile_feasible``) with the identical float32 arithmetic, and
 the property tests pin the two to exact agreement.
 
-``MeasuredRunner`` times lowered kernels (interpret mode on CPU, compiled on
-device) behind a ``ResultCache`` timing cache, and ``tune_kernel`` runs the
+``MeasuredRunner`` times lowered kernels (compiled on TPU, interpret mode on
+CPU) behind a ``ResultCache`` timing cache, and ``tune_kernel`` runs the
 serial GA with measured wall-clock as the objective — falling back to the
 modeled objective when Pallas is unavailable (``REPRO_NO_PALLAS=1``), so the
 tier-1 suite stays hermetic.  ``rank_correlation_study`` records how well
@@ -51,10 +51,14 @@ from .result_cache import ResultCache
 from .spec import FlexSpec
 from .workloads import Layer, gemm
 
-# MXU sublane granularity: blocks snap to multiples of this when the dim
-# offers one (full 128-lane alignment is a compiler concern; sub-8 blocks
-# are accepted only when no aligned divisor fits, so lowering stays total).
-MXU_ALIGN = 8
+# Mosaic's block rule: a block dimension that lands in the last two axes of
+# a BlockSpec is a multiple of the TPU tile — LANES on the last axis,
+# SUBLANES[executed bits] on the second-to-last — or the full array
+# dimension.  The full dimension always meets the rule, so lowering always
+# finds a block; a dim with no 128-multiple divisor leaves only the full dim,
+# which may overflow VMEM, so ``config_legal`` still judges every lowering.
+LANES = 128
+SUBLANES = {32: 8, 16: 16, 8: 32}
 
 # Per-core VMEM budget the lowered working set must fit (pallas guide).
 VMEM_BUDGET_BYTES = 16 * 2 ** 20
@@ -109,6 +113,21 @@ def mamba_workload(batch: int, seq: int, d_inner: int, d_state: int
     return KernelWorkload("mamba", (batch, seq, d_inner, d_state))
 
 
+# The kernels at the widths of a real layer: one 4096-square GEMM, 16 heads
+# of 4096-token attention at head_dim 128, and a 2048-channel selective scan
+# over 4096 steps, with a legal block for each (matmul per order: the A/B-
+# stationary orders keep a 4096-long output stripe resident, so their
+# stripe-side block is halved to fit VMEM).
+REAL_WIDTH = {"matmul": (4096, 4096, 4096), "attention": (16, 4096, 128),
+              "mamba": (1, 4096, 2048, 16)}
+REAL_WIDTH_BLOCKS = {
+    "matmul": {"out": (512, 512, 512), "a": (256, 512, 512),
+               "b": (512, 256, 512)},
+    "attention": (256, 256),
+    "mamba": (128, 512),
+}
+
+
 # --------------------------------------------------------------------------
 # Lowering
 # --------------------------------------------------------------------------
@@ -128,15 +147,16 @@ class KernelConfig:
                 self.order, self.bits)
 
 
-def _snap_block(dim: int, target: int, align: int = MXU_ALIGN) -> int:
-    """Largest divisor of ``dim`` that is <= ``target``, preferring
-    ``align``-multiples when the dim offers one.  Total: 1 always divides,
-    so every (dim, target) snaps to a legal block."""
+def _snap_block(dim: int, target: int, align: int) -> int:
+    """The block for ``dim`` nearest ``target`` under the block rule: the
+    largest divisor of ``dim`` that is <= ``target`` and a multiple of
+    ``align`` (or ``dim`` itself); when none is that small, the smallest
+    such divisor.  Total: ``dim`` always qualifies."""
     dim = int(dim)
-    target = max(1, min(int(target), dim))
-    divs = [int(d) for d in ga_ops.divisors(dim) if d <= target]
-    aligned = [d for d in divs if d % align == 0]
-    return (aligned or divs)[-1]
+    legal = [int(d) for d in ga_ops.divisors(dim)
+             if d % align == 0 or d == dim]
+    below = [d for d in legal if d <= int(target)]
+    return below[-1] if below else legal[0]
 
 
 def _matmul_order(order_perm: Tuple[int, ...]) -> str:
@@ -148,22 +168,19 @@ def _matmul_order(order_perm: Tuple[int, ...]) -> str:
     return {1: "out", 2: "a", 0: "b"}[innermost]
 
 
-def _vmem(kind: str, shape: Tuple[int, ...], block: Tuple[int, ...],
-          bits: int) -> float:
+def _vmem(wl: KernelWorkload, cfg: "KernelConfig") -> float:
     """Width-aware VMEM working set of a lowered config (lazy kernel module
     imports keep repro.core Pallas-free)."""
-    db = bytes_of(bits)
-    if kind == "matmul":
+    db = bytes_of(cfg.bits)
+    if wl.kind == "matmul":
         from ..kernels.tiled_matmul import vmem_bytes
-        bm, bn, bk = block
-        return vmem_bytes(bm, bn, bk, db)
-    if kind == "attention":
+        m, n, _ = wl.shape
+        return vmem_bytes(*cfg.block, db, cfg.order, m, n)
+    if wl.kind == "attention":
         from ..kernels.flash_attention import vmem_bytes
-        bq, bkv = block
-        return vmem_bytes(bq, bkv, shape[2], db)
+        return vmem_bytes(*cfg.block, wl.shape[2], db)
     from ..kernels.mamba_scan import vmem_bytes
-    chunk, d_block = block
-    return vmem_bytes(chunk, d_block, shape[3], db)
+    return vmem_bytes(*cfg.block, wl.shape[3], db)
 
 
 def _block_dims(wl: KernelWorkload) -> Tuple[int, ...]:
@@ -176,41 +193,62 @@ def _block_dims(wl: KernelWorkload) -> Tuple[int, ...]:
     return (wl.shape[1], wl.shape[2])         # (L, D)
 
 
+def _block_aligns(kind: str, bits: int) -> Tuple[int, ...]:
+    """The tile each block component must be a multiple of (unless it is
+    the full dim), from where it lands in the kernel's BlockSpecs: matmul
+    bm is the sublane axis of the A block, bn and bk are lane axes (of the
+    B block and the A block); attention bq and bkv are the sublane axes of
+    the (1, b, d) Q and K/V blocks; the scan's chunk is a sublane axis and
+    d_block the lane axis of the (1, chunk, d_block) x/dt/y blocks."""
+    sub = SUBLANES[bits]
+    if kind == "matmul":
+        return (sub, LANES, LANES)
+    if kind == "attention":
+        return (sub, sub)
+    return (sub, LANES)
+
+
 def lower_mapping(wl: KernelWorkload, mapping: Mapping) -> KernelConfig:
     """Lower one Mapping onto the workload's kernel knobs.
 
     T genes are read through the same GEMM normalization the Layer uses
-    (gene 0 = K-dim tile, 1 = C/reduction, 2 = Y-dim), snapped to
-    MXU-preferring divisors; blocks then shrink (largest first) until the
-    VMEM budget holds, so the result is always ``config_legal``.
+    (gene 0 = K-dim tile, 1 = C/reduction, 2 = Y-dim), snapped to divisors
+    that follow the TPU block rule (``_block_aligns``); blocks then shrink
+    (largest first) until the VMEM budget holds.  The result always meets
+    the block rule, and is ``config_legal`` unless even the smallest legal
+    blocks overflow VMEM.
     """
     t = mapping.tiles
     if wl.kind == "matmul":
-        m, n, k = wl.shape
-        block = [_snap_block(m, t[0]), _snap_block(n, t[2]),
-                 _snap_block(k, t[1])]
+        block = [t[0], t[2], t[1]]
         order = _matmul_order(mapping.order)
-        bits = _k.kernel_bits(int(mapping.repr_bits), "matmul")
     elif wl.kind == "attention":
-        s = wl.shape[1]
-        block = [_snap_block(s, t[0]), _snap_block(s, t[2])]
+        block = [t[0], t[2]]
         order = ""
-        bits = _k.kernel_bits(int(mapping.repr_bits), "attention")
     elif wl.kind == "mamba":
-        _, length, d, _ = wl.shape
-        block = [_snap_block(length, t[2]), _snap_block(d, t[0])]
+        block = [t[2], t[0]]
         order = ""
-        bits = _k.kernel_bits(int(mapping.repr_bits), "mamba")
     else:
         raise ValueError(f"unknown kernel kind {wl.kind!r}")
+    bits = _k.kernel_bits(int(mapping.repr_bits), wl.kind)
 
     dims = _block_dims(wl)
-    while (_vmem(wl.kind, wl.shape, tuple(block), bits)
-           > VMEM_BUDGET_BYTES and max(block) > 1):
-        i = int(np.argmax(block))
-        block[i] = _snap_block(dims[i], block[i] // 2)
-    return KernelConfig(kind=wl.kind, block=tuple(block), order=order,
-                        bits=bits)
+    aligns = _block_aligns(wl.kind, bits)
+    block = [_snap_block(d, b, a) for d, b, a in zip(dims, block, aligns)]
+    cfg = KernelConfig(kind=wl.kind, block=tuple(block), order=order,
+                       bits=bits)
+    # shrink the largest block that can still shrink until VMEM fits
+    while _vmem(wl, cfg) > VMEM_BUDGET_BYTES:
+        smaller = [_snap_block(d, b // 2, a)
+                   for d, b, a in zip(dims, cfg.block, aligns)]
+        shrinkable = [i for i in range(len(block))
+                      if smaller[i] < cfg.block[i]]
+        if not shrinkable:
+            break
+        i = max(shrinkable, key=lambda j: cfg.block[j])
+        cfg = dataclasses.replace(
+            cfg, block=cfg.block[:i] + (smaller[i],) + cfg.block[i + 1:])
+    return cfg
 
 
 def lower_genome(wl: KernelWorkload, space: MapSpace,
@@ -219,23 +257,21 @@ def lower_genome(wl: KernelWorkload, space: MapSpace,
 
 
 def config_legal(wl: KernelWorkload, cfg: KernelConfig) -> bool:
-    """The lowered-config legality predicate: per-block divisibility with
-    the MXU-alignment preference (a block is acceptable iff it is its own
-    snap fixpoint), the width-aware VMEM budget, and — for matmul — a known
-    stationarity order.  ``lower_mapping`` output satisfies this for every
-    genome (totality)."""
+    """The lowered-config legality predicate: each block divides its dim
+    and follows the TPU block rule (a multiple of its tile from
+    ``_block_aligns``, or the full dim), the width-aware VMEM budget holds,
+    and the order and width are in the kernel's menus."""
     dims = _block_dims(wl)
     if len(cfg.block) != len(dims):
         return False
-    for dim, b in zip(dims, cfg.block):
-        if b < 1 or dim % b != 0 or b != _snap_block(dim, b):
+    if cfg.bits not in _k.SUPPORTED_BITS[cfg.kind]:
+        return False
+    for dim, b, a in zip(dims, cfg.block, _block_aligns(cfg.kind, cfg.bits)):
+        if b < 1 or dim % b != 0 or (b % a != 0 and b != dim):
             return False
     if cfg.kind == "matmul" and cfg.order not in ("out", "a", "b"):
         return False
-    if cfg.bits not in _k.SUPPORTED_BITS[cfg.kind]:
-        return False
-    return _vmem(cfg.kind, wl.shape, cfg.block, cfg.bits) \
-        <= VMEM_BUDGET_BYTES
+    return _vmem(wl, cfg) <= VMEM_BUDGET_BYTES
 
 
 def bridge_tile_feasible(tiles: np.ndarray,
@@ -328,7 +364,8 @@ def make_inputs(wl: KernelWorkload, seed: int = 0) -> tuple:
 
 def run_config(wl: KernelWorkload, cfg: KernelConfig, inputs: tuple,
                use_pallas: bool = True):
-    """Execute one lowered config (interpret mode on CPU — see ops)."""
+    """Execute one lowered config (compiled on TPU, interpreted on CPU —
+    see ``kernels.ops``)."""
     from ..kernels import ops
 
     if wl.kind == "matmul":
@@ -388,8 +425,7 @@ class MeasuredRunner:
     bit-reproducible tests; without it, real wall-clock is taken as the
     best of ``repeats`` timed calls after ``warmup`` compile/warm calls.
     ``force_available`` pins availability for tests; otherwise Pallas
-    execution is considered unavailable when ``REPRO_NO_PALLAS`` is set or
-    the kernel entry points fail to import.
+    execution is unavailable only when ``REPRO_NO_PALLAS`` is set.
     """
 
     def __init__(self, cache: Optional[ResultCache] = None,
@@ -406,15 +442,15 @@ class MeasuredRunner:
         self.measured_calls = 0     # real/fake timings taken (cache misses)
 
     def available(self) -> bool:
+        """False only on request (``force_available`` or the
+        ``REPRO_NO_PALLAS`` opt-out); a Pallas install that fails to import
+        raises instead of silently disabling measurement."""
         if self.force_available is not None:
             return bool(self.force_available)
         if get_env("REPRO_NO_PALLAS"):
             return False
-        try:
-            from ..kernels import ops  # noqa: F401
-            return True
-        except Exception:  # noqa: BLE001 - any import failure disables
-            return False
+        from ..kernels import ops  # noqa: F401
+        return True
 
     def inputs_for(self, wl: KernelWorkload) -> tuple:
         if wl not in self._inputs:
@@ -481,7 +517,8 @@ def tune_kernel(wl: KernelWorkload, spec: FlexSpec,
     same ``ga_ops.next_population`` breeding step — with the per-genome
     objective swapped: cost-model-feasible genomes are lowered and timed
     (deduped through the runner's timing cache), infeasible ones keep the
-    model's BIG-penalized runtime so they can never win.  With a frozen
+    model's BIG-penalized runtime and genomes whose lowering fails
+    ``config_legal`` score BIG, so neither can win.  With a frozen
     timing cache (injected ``timer``) the whole trajectory is
     bit-reproducible.
     """
@@ -524,7 +561,10 @@ def tune_kernel(wl: KernelWorkload, spec: FlexSpec,
         if measured:
             obj = modeled.copy()     # infeasible keep the BIG penalty
             for i in np.nonzero(feasible)[0]:
-                obj[i] = runner.measure(wl, lower_genome(wl, space, pop[i]))
+                kcfg = lower_genome(wl, space, pop[i])
+                # a lowering the chip would refuse is never run
+                obj[i] = (runner.measure(wl, kcfg)
+                          if config_legal(wl, kcfg) else BIG)
         else:
             obj = modeled
         order_idx = np.argsort(obj, kind="stable")
@@ -587,7 +627,9 @@ def rank_correlation_study(wl: KernelWorkload, spec: FlexSpec,
                            n_samples: int = 16, seed: int = 0,
                            runner: Optional[MeasuredRunner] = None) -> dict:
     """Sample genomes, lower them, and correlate model-predicted runtime
-    with measured wall-clock per DISTINCT lowered config.
+    with measured wall-clock per DISTINCT lowered config.  Configs that
+    fail ``config_legal`` are left out and never run; ``all_legal`` says
+    whether any was.
 
     The sampled genome set, the lowered config set and the predicted costs
     are fully deterministic (seeded sampling + pure lowering); only the
@@ -602,19 +644,20 @@ def rank_correlation_study(wl: KernelWorkload, spec: FlexSpec,
 
     configs: List[KernelConfig] = []
     predicted: List[float] = []
-    seen: Dict[KernelConfig, int] = {}
+    seen = set()
     for g in genomes:
         mapping = space.decode(g)
         kcfg = lower_mapping(wl, mapping)
         if kcfg in seen:
             continue
-        seen[kcfg] = len(configs)
-        configs.append(kcfg)
-        predicted.append(predicted_runtime(wl, spec, mapping, kcfg))
+        seen.add(kcfg)
+        if config_legal(wl, kcfg):       # the chip would refuse the others
+            configs.append(kcfg)
+            predicted.append(predicted_runtime(wl, spec, mapping, kcfg))
 
     measured = [runner.measure(wl, kcfg) for kcfg in configs]
     corr = spearman(predicted, measured) if len(configs) >= 2 else 0.0
-    legal = all(config_legal(wl, kcfg) for kcfg in configs)
+    legal = len(configs) == len(seen)
     return {
         "kind": wl.kind,
         "n_sampled": int(n_samples),

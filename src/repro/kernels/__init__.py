@@ -1,5 +1,5 @@
 """Pallas TPU kernels for the compute hot-spots (matmul / flash attention /
-selective scan) plus version-compat helpers shared by the kernel modules.
+selective scan).
 
 Also the ONE place that maps the mapper's R-axis bit-widths onto executable
 kernel dtypes (``kernel_bits`` / ``dtype_for_bits``) — defined here, not in
@@ -37,16 +37,3 @@ def dtype_for_bits(bits: int, kind: str = "matmul"):
     return {8: jnp.int8, 16: jnp.bfloat16,
             32: jnp.float32}[kernel_bits(bits, kind)]
 
-
-def tpu_compiler_params(**kwargs):
-    """Construct TPU compiler params across jax versions.
-
-    jax renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams``; try
-    the new name first and fall back to the old one.  Imported lazily so the
-    pure-jnp oracles (``ref``) stay importable on builds without pallas-TPU.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)

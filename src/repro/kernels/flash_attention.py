@@ -16,6 +16,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# the kernel computes in float32; full-precision MXU passes keep it within
+# float32 tolerance of the reference (one bf16 pass would not)
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -33,7 +36,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         q = q_ref[0].astype(jnp.float32) * scale          # (bq, d)
         k = k_ref[0].astype(jnp.float32)                  # (bkv, d)
         v = v_ref[0].astype(jnp.float32)
-        logits = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+        logits = jnp.dot(q, k.T, precision=_F32,
+                         preferred_element_type=jnp.float32)
         if causal:
             q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32,
                                                        (bq, bkv), 0)
@@ -46,7 +50,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_prev * corr + p.sum(axis=1, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+            p, v, precision=_F32, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     if causal:

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import tpu_compiler_params
-
 
 def _scan_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref, h_ref, *,
                  chunk: int):
@@ -84,7 +82,7 @@ def mamba_scan(x: jnp.ndarray, dt: jnp.ndarray, b: jnp.ndarray,
                                lambda bb, dd, cc: (bb, cc, dd)),
         out_shape=jax.ShapeDtypeStruct((B, L, D), x.dtype),
         scratch_shapes=[pltpu.VMEM((d_block, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, b, c, a_log_neg, d_skip.reshape(1, -1))

@@ -1,9 +1,11 @@
 """jit'd public entry points for the Pallas kernels.
 
-On CPU (this container) the kernels execute with interpret=True; on TPU the
-same `pl.pallas_call` lowers to Mosaic.  `use_pallas=False` falls back to
-the XLA reference path — that is what the multi-pod dry-run lowers, so
-compile artifacts never depend on interpret mode.
+On a TPU the `pl.pallas_call`s lower to Mosaic.  On the CPU backend (the
+test suite, `JAX_PLATFORMS=cpu`) they run in Pallas's TPU interpret mode,
+which emulates the TPU pipeline's block semantics; any other backend is an
+error rather than a silent interpreter.  `use_pallas=False` runs the XLA
+reference path — that is what the multi-pod dry-run lowers, so compile
+artifacts never depend on interpret mode.
 """
 from __future__ import annotations
 
@@ -11,6 +13,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 from . import dtype_for_bits, ref
 from .flash_attention import flash_attention as _flash
@@ -19,8 +22,16 @@ from .mamba_scan import mamba_scan as _mamba
 from .tiled_matmul import tiled_matmul as _matmul
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _interpret():
+    """Compiled on TPU, interpreted on CPU, refused anywhere else."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return pltpu.InterpretParams()
+    raise RuntimeError(
+        f"Pallas kernels run compiled on a TPU or interpreted on the CPU "
+        f"backend; the default backend is {backend!r}")
 
 
 def _cast(arrays, bits, kind):

@@ -1,7 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 # ^ MUST precede every other import (jax locks device count on first init).
+# A compile-only tool on host devices: it never opens an accelerator.
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
 mesh) cell with 512 placeholder host devices, and extract the roofline terms
 from the compiled artifacts.
@@ -220,6 +222,8 @@ def main(argv=None):
                     help="hillclimb knob: dpxtp, e.g. 1x256")
     ap.add_argument("--tag", default="", help="label for this variant")
     args = ap.parse_args(argv)
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     overrides = {}
     for ov in args.override:

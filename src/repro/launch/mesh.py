@@ -8,20 +8,24 @@ proves the pod axis shards.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary mesh (tests use small ones, e.g. (2, 2))."""
-    return jax.make_mesh(shape, axes)
+    """Arbitrary mesh (tests use small ones, e.g. (2, 2)).  Axes are
+    ``Auto``: the sharding rules in ``repro.dist`` place arrays with
+    ``with_sharding_constraint``, which binds only to Auto axes (JAX's
+    ``make_mesh`` defaults to Explicit ones)."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def mesh_axis_size(mesh, name: str) -> int:

@@ -52,6 +52,8 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-batch", type=int, default=4)
     args = ap.parse_args(argv)
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     run_serving(args.arch, smoke=args.smoke, n_requests=args.requests,
                 max_new=args.max_new, max_batch=args.max_batch)
 

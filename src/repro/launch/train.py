@@ -130,6 +130,8 @@ def main(argv=None):
                     help="pick mesh/FSDP/SP/microbatch via the TOPS "
                          "pod-level DSE (dp*tp = --dp * --tp chips)")
     args = ap.parse_args(argv)
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     mesh_shape, overrides, n_micro = (args.dp, args.tp), None, args.n_micro
     if args.autoshard:
         mesh_shape, overrides, n_micro = pick_mesh_autoshard(

@@ -2,7 +2,8 @@
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import HWConfig, lower_bound_cycles
 from repro.core.cost_model import evaluate_mapping

@@ -10,6 +10,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.dist.api import (axis_rules, constrain, current_rules,
                             logical_to_spec, validate_spec)
+from repro.launch.mesh import make_mesh
 from repro.dist.sharding import (DEFAULT_RULES, batch_spec, cache_shardings,
                                  make_rules, param_shardings)
 
@@ -26,14 +27,14 @@ def test_logical_to_spec_resolution():
 
 
 def test_validate_spec_unknown_mesh_axis_drops():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     assert validate_spec(P("model"), (8,), mesh) in (P(), P(None))
     # unknown axis inside a tuple truncates the kept prefix
     assert validate_spec(P(("data", "model")), (8,), mesh) == P(("data",))
 
 
 def test_validate_spec_duplicate_axis_drops_second_use():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     spec = validate_spec(P("data", "data"), (4, 4), mesh)
     assert spec in (P("data"), P("data", None))
     spec = validate_spec(P(("data",), ("data",)), (4, 4), mesh)
@@ -41,7 +42,7 @@ def test_validate_spec_duplicate_axis_drops_second_use():
 
 
 def test_validate_spec_truncates_to_rank():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     assert validate_spec(P("data", None, None), (4,), mesh) == P("data")
 
 
@@ -53,7 +54,7 @@ def test_constrain_noop_outside_context():
 
 
 def test_axis_rules_binds_and_nests():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     outer = make_rules(mesh)
     inner = dict(outer, batch=None)
     with axis_rules(mesh, outer):
@@ -66,7 +67,7 @@ def test_axis_rules_binds_and_nests():
 
 
 def test_constrain_inside_context_and_jit():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     rules = make_rules(mesh)
 
     def fn(x):
@@ -79,7 +80,7 @@ def test_constrain_inside_context_and_jit():
 
 
 def test_make_rules_filters_to_mesh_and_knobs():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     r = make_rules(mesh)
     assert r["heads"] is None and r["batch"] == ("data",)
     assert r["act_seq"] is None and r["kv_seq"] is None and r["embed"] is None
@@ -87,13 +88,13 @@ def test_make_rules_filters_to_mesh_and_knobs():
     assert r["embed"] == ("data",)
     assert r["act_seq"] is None        # no 'model' axis on this mesh
     assert r["kv_seq"] is None
-    mesh2 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh2 = make_mesh((1, 1), ("data", "model"))
     r2 = make_rules(mesh2, seq_activations=True, long_context=True)
     assert r2["act_seq"] == "model" and r2["kv_seq"] == "model"
 
 
 def test_batch_spec_shards_leading_dim():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     shard = batch_spec(mesh, make_rules(mesh))
     sh = shard(jax.ShapeDtypeStruct((4, 16), jnp.int32))
     assert sh.spec in (P(("data",)), P(("data",), None))
@@ -104,7 +105,7 @@ def test_batch_spec_shards_leading_dim():
 def test_param_and_cache_shardings_cover_every_arch():
     from repro.configs import ARCHS, get_config
     from repro.models import init_cache, init_params
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     rules = make_rules(mesh, fsdp=True)
     for arch in sorted(ARCHS.keys()):
         cfg = get_config(arch, smoke=True)
@@ -122,7 +123,7 @@ def test_param_and_cache_shardings_bind_expected_axes():
     must actually shard, not silently fall through to replication."""
     from repro.configs import get_config
     from repro.models import init_cache, init_params
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     rules = make_rules(mesh, fsdp=True)
     cfg = get_config("gemma-2b", smoke=True)
     p_spec = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))

@@ -14,6 +14,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.api import logical_to_spec, validate_spec
+from repro.launch.mesh import make_mesh
 from repro.dist.sharding import DEFAULT_RULES, make_rules
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -32,7 +33,7 @@ def run_subprocess(code: str, devices: int = 8, timeout=600) -> str:
 
 
 def test_validate_spec_dedupe_and_identity():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     # the same mesh axis may not shard two dims: the second use drops
     spec = validate_spec(P("data", "data"), (4, 4), mesh)
     assert spec in (P("data"), P("data", None))
@@ -46,11 +47,12 @@ def test_validate_spec_divisibility_multidevice():
     import jax
     from jax.sharding import PartitionSpec as P
     from repro.dist.api import validate_spec
-    mesh = jax.make_mesh((4,), ("model",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("model",))
     assert validate_spec(P("model"), (7,), mesh) in (P(), P(None))
     assert validate_spec(P("model"), (8,), mesh) == P("model")
     # tuple axes keep the longest dividing prefix
-    mesh2 = jax.make_mesh((2, 2), ("pod", "data"))
+    mesh2 = make_mesh((2, 2), ("pod", "data"))
     assert validate_spec(P(("pod", "data")), (2,), mesh2) == P(("pod",))
     print("OK")
     """
@@ -62,7 +64,7 @@ def test_logical_to_spec_and_rules():
     rules = dict(DEFAULT_RULES)
     spec = logical_to_spec(("batch", None, "heads"), rules)
     assert spec == P(("pod", "data"), None, "model")
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     r = make_rules(mesh)
     assert r["heads"] is None  # no 'model' axis on this mesh
     assert r["batch"] == ("data",)
@@ -159,7 +161,7 @@ def test_param_shardings_cover_tree():
     from repro.dist.sharding import param_shardings
     from repro.models import init_params
     cfg = get_config("olmoe-1b-7b", smoke=True)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     spec = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
     sh = param_shardings(cfg, spec, mesh)
     assert (len(jax.tree.leaves(sh)) == len(jax.tree.leaves(spec)))

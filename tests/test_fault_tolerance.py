@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import CheckpointManager, restore_state, save_state
+from repro.launch.mesh import make_mesh
 from repro.data import make_dataset
 from repro.runtime import (FaultInjector, FaultTolerantLoop,
                            HeartbeatMonitor, StragglerDetector)
@@ -38,12 +39,12 @@ def test_checkpoint_manager_keep_and_latest(tmp_path):
 def test_elastic_reshard_restore(tmp_path):
     """Save under one sharding, restore under a different mesh layout."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh1 = jax.make_mesh((1,), ("data",))
+    mesh1 = make_mesh((1,), ("data",))
     x = jax.device_put(jnp.arange(16.0).reshape(4, 4),
                        NamedSharding(mesh1, P("data")))
     save_state(str(tmp_path), 0, {"w": x})
     # "new cluster": different (trivial on 1 CPU, same code path) sharding
-    mesh2 = jax.make_mesh((1,), ("model",))
+    mesh2 = make_mesh((1,), ("model",))
     sh = {"w": NamedSharding(mesh2, P(None, "model"))}
     restored = restore_state(str(tmp_path), 0,
                              jax.eval_shape(lambda: {"w": x}), sh)

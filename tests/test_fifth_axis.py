@@ -9,7 +9,8 @@ that invariant lives in test_golden_metrics.py; here we pin the mechanics.
 """
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (FULLFLEX, GAConfig, INFLEX, PARTFLEX, Layer,
                         MapSpace, RepresentationSpec, compute_flexion,

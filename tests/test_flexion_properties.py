@@ -21,7 +21,8 @@ from repro.core import (FULLFLEX, INFLEX, PARTFLEX, compute_flexion,
                         make_variant, model_flexion)
 from repro.core.workloads import Layer
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 LAYERS = [Layer("conv", (64, 32, 28, 28, 3, 3)),
           Layer("dw", (1, 480, 14, 14, 5, 5), depthwise=True),

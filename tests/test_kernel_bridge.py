@@ -16,10 +16,11 @@ from repro.core import (GAConfig, HWConfig, MeasuredRunner, ResultCache,
                         mamba_workload, mapspace_for, matmul_workload,
                         parity_check, raw_tile_feasibility, spearman,
                         tune_kernel)
-from repro.core.kernel_bridge import (MXU_ALIGN, _matmul_order, _snap_block,
-                                      make_inputs)
+from repro.core.kernel_bridge import (LANES, SUBLANES, _matmul_order,
+                                      _snap_block, make_inputs)
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 HW = HWConfig()
 # T/O/R open, P/S pinned: the axes the kernels realize
@@ -93,6 +94,47 @@ def test_every_genome_lowers_to_legal_config(kind):
         assert config_legal(wl, cfg), (m, cfg)
 
 
+# Where each lowered block lands in the kernels' BlockSpecs, written out
+# from kernels/*.py: (block index, array dim, axis) per spec, axis -1 the
+# lane axis and -2 the sublane axis.
+_SPEC_AXES = {
+    "matmul": [(0, 0, -2), (2, 2, -1),      # A block (bm, bk)
+               (2, 2, -2), (1, 1, -1),      # B block (bk, bn)
+               (0, 0, -2), (1, 1, -1)],     # output (bm, bn) / stripes
+    "attention": [(0, 0, -2), (1, 1, -2)],  # (1, bq, d), (1, bkv, d)
+    "mamba": [(0, 0, -2), (1, 1, -1),       # (1, chunk, d_block)
+              (1, 1, -2)],                  # A block (d_block, N)
+}
+
+BLOCK_RULE_WORKLOADS = [
+    matmul_workload(512, 512, 256), matmul_workload(64, 192, 96),
+    attention_workload(4, 512, 64), attention_workload(2, 96, 32),
+    mamba_workload(2, 256, 128, 16), mamba_workload(1, 48, 200, 8),
+]
+
+
+@pytest.mark.parametrize("wl", BLOCK_RULE_WORKLOADS,
+                         ids=lambda w: f"{w.kind}{w.shape}")
+def test_lowered_configs_meet_block_rule(wl):
+    """Host-only: every lowered block that lands in the last two axes of a
+    BlockSpec is a multiple of the executed dtype's TPU tile (8/16/32
+    sublanes by 128 lanes) or the full array dim, and the config is
+    legal."""
+    for spec in (SPEC5, SPEC_F32):
+        _, mappings = _sampled_mappings(wl, spec, 48, seed=4)
+        dims = {"matmul": lambda s: (s[0], s[1], s[2]),
+                "attention": lambda s: (s[1], s[1]),
+                "mamba": lambda s: (s[1], s[2])}[wl.kind](wl.shape)
+        for m in mappings:
+            cfg = lower_mapping(wl, m)
+            assert config_legal(wl, cfg), (m, cfg)
+            for bi, di, axis in _SPEC_AXES[wl.kind]:
+                tile = LANES if axis == -1 else SUBLANES[cfg.bits]
+                b, d = cfg.block[bi], dims[di]
+                assert d % b == 0 and (b % tile == 0 or b == d), \
+                    (wl, cfg, bi, axis)
+
+
 def test_lowering_deterministic():
     wl = WORKLOADS["matmul"]
     _, mappings = _sampled_mappings(wl, SPEC5, 16, seed=3)
@@ -101,17 +143,26 @@ def test_lowering_deterministic():
 
 
 def test_snap_block_fixpoint_and_alignment():
-    """_snap_block is total, divides, respects the target, is idempotent
-    (the legality predicate's fixpoint rule), and prefers MXU multiples."""
-    for dim in (1, 3, 8, 24, 64, 96, 100, 128, 257):
-        for target in (1, 2, 5, 7, 8, 9, 63, 64, 1000):
-            b = _snap_block(dim, target)
-            assert 1 <= b <= max(1, min(target, dim))
-            assert dim % b == 0
-            assert _snap_block(dim, b) == b
-    assert _snap_block(128, 100) == 64          # aligned divisor preferred
-    assert _snap_block(96, 3) == 3              # no aligned divisor <= 3
-    assert _snap_block(64, 64) % MXU_ALIGN == 0
+    """_snap_block is total, divides, is idempotent (the lowering's
+    fixpoint), returns an aligned divisor or the full dim, and stays at or
+    under the target whenever a legal block that small exists."""
+    for align in (8, 16, 32, LANES):
+        for dim in (1, 3, 8, 24, 64, 96, 100, 128, 257, 512):
+            for target in (1, 2, 5, 7, 8, 9, 63, 64, 1000):
+                b = _snap_block(dim, target, align)
+                assert 1 <= b <= dim and dim % b == 0
+                assert b % align == 0 or b == dim
+                assert _snap_block(dim, b, align) == b
+                legal = [d for d in range(1, dim + 1) if dim % d == 0
+                         and (d % align == 0 or d == dim)]
+                if any(d <= target for d in legal):
+                    assert b == max(d for d in legal if d <= target)
+                else:
+                    assert b == min(legal)
+    assert _snap_block(128, 100, 8) == 64       # aligned divisor <= target
+    assert _snap_block(96, 3, 8) == 8           # none <= 3: smallest aligned
+    assert _snap_block(100, 64, LANES) == 100   # no lane multiple: full dim
+    assert _snap_block(512, 200, LANES) == 128
 
 
 def test_matmul_order_gene_semantics():
@@ -225,6 +276,31 @@ def test_tune_kernel_modeled_fallback():
 def test_tune_kernel_env_kill_switch(monkeypatch):
     monkeypatch.setenv("REPRO_NO_PALLAS", "1")
     assert not MeasuredRunner().available()
+
+
+def test_lowering_past_vmem_is_never_measured():
+    """4100 has no divisor that is a multiple of 8 or 128, so the block rule
+    leaves only full-dim blocks, and a 4100-square matmul overflows VMEM:
+    every lowering is illegal, and neither the study nor the tuner hands
+    one to the runner (on a chip it would crash Mosaic)."""
+    from repro.core import rank_correlation_study
+    from repro.core.kernel_bridge import BIG
+    wl = matmul_workload(4100, 4100, 4100)
+    calls = []
+
+    def timer(key):
+        calls.append(key)
+        return _fake_timer(key)
+
+    runner = MeasuredRunner(cache=ResultCache(), timer=timer,
+                            force_available=True)
+    study = rank_correlation_study(wl, SPEC_F32, n_samples=8, runner=runner)
+    assert study["n_configs"] == 0 and not study["all_legal"]
+    res = tune_kernel(wl, SPEC_F32, TUNE_CFG, runner)
+    assert res.config.block == (4100, 4100, 4100)
+    assert not config_legal(wl, res.config)
+    assert res.best_cost >= BIG
+    assert calls == []
 
 
 @pytest.mark.parametrize("kind", ["matmul", "attention", "mamba"])

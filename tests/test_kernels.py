@@ -104,11 +104,17 @@ def test_vmem_budget_tracks_r_axis_width():
     from repro.kernels.mamba_scan import vmem_bytes as scan_vmem
 
     ws = [vmem_bytes(128, 128, 128, bytes_of(b)) for b in (4, 8, 16, 32)]
-    assert ws == sorted(ws) and len(set(ws)) == len(ws)
-    # operand term halves from bf16 -> int8; the fp32 acc term does not
-    acc = 128 * 128 * 4
-    assert (vmem_bytes(128, 128, 128, 2) - acc) == \
-        2 * (vmem_bytes(128, 128, 128, 1) - acc)
+    assert ws == sorted(ws) and ws[0] < ws[1] and ws[2] < ws[3]
+    # operand term halves from int8 -> int4; the int32 output blocks and
+    # the 4-byte accumulator do not
+    acc = 2 * 128 * 128 * 4 + 128 * 128 * 4
+    assert (vmem_bytes(128, 128, 128, 1) - acc) == \
+        2 * (vmem_bytes(128, 128, 128, 0.5) - acc)
+    # the A/B-stationary orders keep a whole output stripe resident
+    assert vmem_bytes(128, 128, 128, 4, "a", n=4096) > \
+        vmem_bytes(128, 128, 128, 4)
+    assert vmem_bytes(128, 128, 128, 4, "b", m=4096) == \
+        vmem_bytes(128, 128, 128, 4, "a", n=4096)
     assert att_vmem(128, 128, 64, 2) < 16 * 2 ** 20
     assert scan_vmem(128, 512, 16, 4) < 16 * 2 ** 20
     assert att_vmem(64, 64, 32, 4) > att_vmem(64, 64, 32, 2)
@@ -119,7 +125,7 @@ def test_ops_bits_threading():
     """ops entry points execute at the R-selected width: bits chooses the
     kernel dtype (and floors at each kernel's narrowest supported width)."""
     x, y = rand((64, 64), jnp.float32), rand((64, 64), jnp.float32)
-    assert ops.matmul(x, y, bm=32, bn=32, bk=32, bits=8).dtype == jnp.int8
+    assert ops.matmul(x, y, bm=32, bn=32, bk=32, bits=8).dtype == jnp.int32
     assert ops.matmul(x, y, bm=32, bn=32, bk=32,
                       bits=16).dtype == jnp.bfloat16
     assert ops.matmul(x, y, bm=32, bn=32, bk=32, bits=None).dtype == \

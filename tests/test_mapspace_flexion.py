@@ -1,7 +1,8 @@
 """Map-space, taxonomy and flexion tests (paper Secs 3-4)."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (FULLFLEX, INFLEX, PARTFLEX, FlexSpec, HWConfig,
                         Layer, MapSpace, compute_flexion, inflex_baseline,

@@ -1,11 +1,9 @@
-"""Property tests for MapSpace legality invariants (ISSUE 2 satellite).
-
-Run under hypothesis when installed (the dev extra); otherwise they skip via
-tests/_hypothesis_compat.py.  The non-property variants at the bottom always
-run, so CI without hypothesis still covers the pinned-gene contract.
+"""Property tests for MapSpace legality invariants (hypothesis), plus
+seeded non-property variants of the pinned-gene contract at the bottom.
 """
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 import jax.numpy as jnp
 
@@ -92,7 +90,7 @@ def _check_pinned_genes_never_mutate(seed):
 
 
 def test_pinned_genes_never_mutate():
-    # always-on variant (hypothesis may be absent locally)
+    # seeded variant over a fixed sweep
     for seed in (0, 7, 123):
         _check_pinned_genes_never_mutate(seed)
 

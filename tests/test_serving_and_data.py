@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.optim import adafactor, adamw, opt_shardings, schedule_cosine, sgd
 from repro.serve import Request, ServeEngine
@@ -117,7 +118,7 @@ def test_adafactor_state_is_factored():
 
 def test_opt_shardings_mirror_params():
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     params = {"w": jnp.zeros((256, 512))}
     psh = {"w": NamedSharding(mesh, P("data", None))}
     opt = adamw(1e-3)
@@ -148,3 +149,33 @@ def test_end_to_end_tiny_training_run(tmp_path):
     assert res.restarts == 1
     losses = [m["loss"] for m in res.metrics_history]
     assert losses[-1] < losses[0]
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(tmp_path, monkeypatch, from_env):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache and the code sets
+    no directory of its own; otherwise the cache is the checkout's
+    gitignored .jax_cache/."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert compile_cache.enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == \
+                saved["jax_compilation_cache_dir"]
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            path = compile_cache.enable_compile_cache()
+            assert path == jax.config.jax_compilation_cache_dir
+            root = Path(compile_cache.__file__).resolve().parents[3]
+            assert Path(path) == root / ".jax_cache"
+            assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)

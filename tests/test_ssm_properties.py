@@ -2,7 +2,8 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.models.ssm import causal_conv1d, chunked_linear_scan
 
