@@ -5,8 +5,9 @@
     python3 chip_smoke.py --chips 4    # four chips: the device-pool campaign
 
 One process drives the chip(s).  Each phase prints one line with its wall
-time and the time JAX spent compiling in it; a failed phase prints its
-traceback and the run goes on to the next, then exits 1.  The last line of
+time and the time XLA spent compiling in it (backend compiles and
+compile-cache loads, as ``repro.core.tracing`` counts them); a failed phase
+prints its traceback and the run goes on to the next, then exits 1.  The last line of
 standard output is ``{"ok": true, "device": {...}}`` only when every phase
 passed.  Without a TPU the script exits 2 before any phase and prints no
 result.
@@ -77,31 +78,31 @@ FLEXION_ATOL = 1e-6
 
 class Phases:
     """Runs phases, prints one timing line each, remembers the failures.
-    ``on_duration`` is a JAX monitoring listener that sums the time spent
-    tracing, lowering and compiling."""
+    Each phase runs inside ``repro.core.tracing.recording``; its line
+    reports the seconds of the backend compiles (or compile-cache loads)
+    made in the phase's thread, the recorder's ``jax:compile_s``."""
 
     def __init__(self):
         self.failed = []
         self.compile_s = 0.0
-        self._lock = threading.Lock()     # compiles also run in threads
-
-    def on_duration(self, event: str, duration: float, **_) -> None:
-        if event.startswith("/jax/core/compile/"):
-            with self._lock:
-                self.compile_s += duration
 
     def run(self, name, fn, *args):
-        t0, c0 = time.perf_counter(), self.compile_s
+        from repro.core import tracing
+
+        t0, rec = time.perf_counter(), {}
         ok = True
         try:
-            fn(*args)
+            with tracing.recording(rec):
+                fn(*args)
         except Exception:  # the run goes on; the exit code reports it
             ok = False
             self.failed.append(name)
             traceback.print_exc()
+        compile_s = rec.get("jax:compile_s", 0.0)
+        self.compile_s += compile_s
         print(f"[phase] {name}: {'ok' if ok else 'FAILED'}  "
               f"wall {time.perf_counter() - t0:.1f} s  "
-              f"compile {self.compile_s - c0:.1f} s", flush=True)
+              f"compile {compile_s:.1f} s", flush=True)
         return ok
 
 
@@ -506,13 +507,11 @@ def main(argv=None) -> int:
         os.environ["JAX_PLATFORMS"] = platforms + ",cpu"
 
     import jax
-    from jax import monitoring
 
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
     state = {}
     phases = Phases()
-    monitoring.register_event_duration_secs_listener(phases.on_duration)
     if not phases.run("device", phase_device, state, args.chips):
         return 2
     t0 = time.perf_counter()

@@ -260,18 +260,21 @@ def evaluate_rows(dims: jnp.ndarray, stride: jnp.ndarray,
     including the (traced) per-row hard-partition flag (and, when given, the
     per-row operand bit-width)."""
 
-    if reprs is None:
-        def one(d_, s_, w_, t_, o_, p_, sh_, hp_):
-            return evaluate_mapping_impl(d_, s_, w_, t_, o_, p_, sh_, hw, hp_)
+    with jax.named_scope("evaluate_rows"):
+        if reprs is None:
+            def one(d_, s_, w_, t_, o_, p_, sh_, hp_):
+                return evaluate_mapping_impl(d_, s_, w_, t_, o_, p_, sh_, hw,
+                                             hp_)
 
-        return jax.vmap(one)(dims, stride, depthwise, tiles, order, par,
-                             shape_rc, hard_partition)
+            return jax.vmap(one)(dims, stride, depthwise, tiles, order, par,
+                                 shape_rc, hard_partition)
 
-    def one_r(d_, s_, w_, t_, o_, p_, sh_, hp_, r_):
-        return evaluate_mapping_impl(d_, s_, w_, t_, o_, p_, sh_, hw, hp_, r_)
+        def one_r(d_, s_, w_, t_, o_, p_, sh_, hp_, r_):
+            return evaluate_mapping_impl(d_, s_, w_, t_, o_, p_, sh_, hw,
+                                         hp_, r_)
 
-    return jax.vmap(one_r)(dims, stride, depthwise, tiles, order, par,
-                           shape_rc, hard_partition, reprs)
+        return jax.vmap(one_r)(dims, stride, depthwise, tiles, order, par,
+                               shape_rc, hard_partition, reprs)
 
 
 def lower_bound_cycles(dims: np.ndarray, depthwise: bool,

@@ -14,13 +14,13 @@ Also implements the Sec 7 "future-proofing" workflow:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from . import area_model
+from . import area_model, tracing
 from .flexion import FlexionReport
 from .flexion_batched import flexion_campaign, model_flexion_campaign
 from .mapper import (GAConfig, ModelResult, evaluate_fixed_genome,
@@ -187,9 +187,13 @@ def future_proofing_study(base_model: str = "alexnet",
     ``cfg.pipeline`` is set.  The table is bit-identical either way; only
     batching and wall clock change.
 
-    ``timings`` (optional dict) accumulates per-phase wall-clock seconds
-    under ``design_fixed`` / ``replay_frozen`` / ``flex_sweep`` (and
-    ``flexion`` when requested) — the BENCH artifact's phase breakdown.
+    ``timings`` (optional dict) accumulates per-phase seconds
+    (``time.perf_counter``) under ``design_fixed`` / ``replay_frozen`` /
+    ``flex_sweep`` (and ``flexion`` when requested) — the BENCH artifact's
+    phase breakdown.  The study runs inside ``tracing.recording(timings)``,
+    so the dict also receives every span and counter of the layers below
+    (``study.*``, ``engine.*``, ``flexion.*``, ``design.*``, ``jax:*``; see
+    :mod:`repro.core.tracing`).
 
     ``flexion`` (optional dict) adds the H-F column: it is filled with
     ``{row_name: hf}`` for every table row, estimated through one
@@ -214,109 +218,116 @@ def future_proofing_study(base_model: str = "alexnet",
         results if results is not None else {}
     t_acc: Dict[str, float] = timings if timings is not None else {}
 
-    def tick(phase: str, t0: float) -> None:
-        t_acc[phase] = round(t_acc.get(phase, 0.0) + time.time() - t0, 6)
+    @contextlib.contextmanager
+    def phase(name: str):
+        with tracing.span("study." + name) as s:
+            yield
+        t_acc[name] = round(t_acc.get(name, 0.0) + s.seconds, 6)
 
-    designs: Dict[str, Tuple[np.ndarray, ModelResult]] = {}
-    t0 = time.time()
-    if campaign:
-        hw_ = hw or HWConfig()
-        names = list(dict.fromkeys([base_model, *future_models]))
-        designs = dict(zip(names, search_fixed_configs(
-            [(get_model(m), FlexSpec(name=f"probe-{m}", hw=hw_))
-             for m in names], cfg)))
-        genome, _ = designs[base_model]
-        frozen = freeze_spec_from_genome(
-            FlexSpec(name=f"probe-{base_model}", hw=hw_),
-            get_model(base_model), genome,
-            name=f"InFlex0000-{base_model}-Opt")
-    else:
-        frozen, genome, _ = design_fixed_accelerator(base_model, hw, cfg)
-    tick("design_fixed", t0)
-
-    table: Dict[str, Dict[str, float]] = {}
-    baseline_rt: Dict[str, float] = {}
-
-    # row 1: the frozen 2014 accelerator on every model
-    t0 = time.time()
-    if campaign:
-        replays = evaluate_fixed_genome_many(
-            [(get_model(m), frozen, genome) for m in future_models])
-    else:
-        replays = [evaluate_fixed_genome(get_model(m), frozen, genome)
-                   for m in future_models]
-    row = {m: res.runtime for m, res in zip(future_models, replays)}
-    cells.update({(frozen.name, m): (frozen, res)
-                  for m, res in zip(future_models, replays)})
-    baseline_rt.update(row)
-    table[f"InFlex0000-{base_model}-Opt"] = row
-    tick("replay_frozen", t0)
-
-    # row 2: a fixed accelerator re-optimized per future model (already
-    # designed above in campaign mode)
-    t0 = time.time()
-    row = {}
-    for m in future_models:
-        if m == base_model:
-            cells["InFlex0000-X-Opt", m] = cells[frozen.name, m]
-        elif campaign:
-            cells["InFlex0000-X-Opt", m] = (
-                FlexSpec(name=f"probe-{m}", hw=frozen.hw), designs[m][1])
-        else:
-            spec_m, _, res = design_fixed_accelerator(m, hw, cfg)
-            cells["InFlex0000-X-Opt", m] = (
-                FlexSpec(name=f"probe-{m}", hw=spec_m.hw), res)
-        row[m] = cells["InFlex0000-X-Opt", m][1].runtime
-    table["InFlex0000-X-Opt"] = row
-    tick("design_fixed", t0)
-
-    # flexible variants of the 2014 design; with the batched engine, each
-    # model's whole spec sweep is a few chunked engine dispatches — and the
-    # campaign packs ALL models' sweeps into one chunk-pipelined row set
-    flex_specs = [open_axes(frozen, cs, FULLFLEX) for cs in class_strs]
-    if include_partflex_1111:
-        flex_specs.append(open_axes(frozen, "1111", PARTFLEX))
-
-    if flexion is not None or wflexion is not None:
-        t0 = time.time()
-        fx_specs = [frozen, *flex_specs]
-        if flexion is not None:
-            reports = flexion_campaign([(s, None, 0) for s in fx_specs],
-                                       mc_samples=flexion_samples, seed=0)
-            flexion.update({s.name: r.hf for s, r in zip(fx_specs, reports)})
-            flexion["InFlex0000-X-Opt"] = flexion[frozen.name]
-        if wflexion is not None:
-            future_layers = [l for m in future_models for l in get_model(m)]
-            wreports = model_flexion_campaign(
-                [(s, future_layers) for s in fx_specs], flexion_samples)
-            wflexion.update(
-                {s.name: r.wf for s, r in zip(fx_specs, wreports)})
-            wflexion["InFlex0000-X-Opt"] = wflexion[frozen.name]
-        tick("flexion", t0)
-    for spec in flex_specs:
-        table[spec.name] = {}
-    t0 = time.time()
-    if campaign:
-        all_res = iter(search_campaign(
-            [(get_model(m), spec) for m in future_models
-             for spec in flex_specs], cfg))
-        for m in future_models:
-            for spec in flex_specs:
-                cells[spec.name, m] = (spec, next(all_res))
-    else:
-        for m in future_models:
-            layers = get_model(m)
-            if cfg.engine == "batched":
-                model_res = search_specs_batched(layers, flex_specs, cfg)
+    with tracing.recording(t_acc):
+        designs: Dict[str, Tuple[np.ndarray, ModelResult]] = {}
+        with phase("design_fixed"):
+            if campaign:
+                hw_ = hw or HWConfig()
+                names = list(dict.fromkeys([base_model, *future_models]))
+                designs = dict(zip(names, search_fixed_configs(
+                    [(get_model(m), FlexSpec(name=f"probe-{m}", hw=hw_))
+                     for m in names], cfg)))
+                genome, _ = designs[base_model]
+                frozen = freeze_spec_from_genome(
+                    FlexSpec(name=f"probe-{base_model}", hw=hw_),
+                    get_model(base_model), genome,
+                    name=f"InFlex0000-{base_model}-Opt")
             else:
-                model_res = [search_model(layers, spec, cfg)
-                             for spec in flex_specs]
-            for spec, mres in zip(flex_specs, model_res):
-                cells[spec.name, m] = (spec, mres)
-    for m in future_models:
+                frozen, genome, _ = design_fixed_accelerator(base_model, hw,
+                                                             cfg)
+
+        table: Dict[str, Dict[str, float]] = {}
+        baseline_rt: Dict[str, float] = {}
+
+        # row 1: the frozen 2014 accelerator on every model
+        with phase("replay_frozen"):
+            if campaign:
+                replays = evaluate_fixed_genome_many(
+                    [(get_model(m), frozen, genome) for m in future_models])
+            else:
+                replays = [evaluate_fixed_genome(get_model(m), frozen, genome)
+                           for m in future_models]
+            row = {m: res.runtime for m, res in zip(future_models, replays)}
+            cells.update({(frozen.name, m): (frozen, res)
+                          for m, res in zip(future_models, replays)})
+            baseline_rt.update(row)
+            table[f"InFlex0000-{base_model}-Opt"] = row
+
+        # row 2: a fixed accelerator re-optimized per future model (already
+        # designed above in campaign mode)
+        with phase("design_fixed"):
+            row = {}
+            for m in future_models:
+                if m == base_model:
+                    cells["InFlex0000-X-Opt", m] = cells[frozen.name, m]
+                elif campaign:
+                    cells["InFlex0000-X-Opt", m] = (
+                        FlexSpec(name=f"probe-{m}", hw=frozen.hw),
+                        designs[m][1])
+                else:
+                    spec_m, _, res = design_fixed_accelerator(m, hw, cfg)
+                    cells["InFlex0000-X-Opt", m] = (
+                        FlexSpec(name=f"probe-{m}", hw=spec_m.hw), res)
+                row[m] = cells["InFlex0000-X-Opt", m][1].runtime
+            table["InFlex0000-X-Opt"] = row
+
+        # flexible variants of the 2014 design; with the batched engine, each
+        # model's whole spec sweep is a few chunked engine dispatches — and
+        # the campaign packs ALL models' sweeps into one chunk-pipelined row
+        # set
+        flex_specs = [open_axes(frozen, cs, FULLFLEX) for cs in class_strs]
+        if include_partflex_1111:
+            flex_specs.append(open_axes(frozen, "1111", PARTFLEX))
+
+        if flexion is not None or wflexion is not None:
+            with phase("flexion"):
+                fx_specs = [frozen, *flex_specs]
+                if flexion is not None:
+                    reports = flexion_campaign(
+                        [(s, None, 0) for s in fx_specs],
+                        mc_samples=flexion_samples, seed=0)
+                    flexion.update({s.name: r.hf
+                                    for s, r in zip(fx_specs, reports)})
+                    flexion["InFlex0000-X-Opt"] = flexion[frozen.name]
+                if wflexion is not None:
+                    future_layers = [l for m in future_models
+                                     for l in get_model(m)]
+                    wreports = model_flexion_campaign(
+                        [(s, future_layers) for s in fx_specs],
+                        flexion_samples)
+                    wflexion.update(
+                        {s.name: r.wf for s, r in zip(fx_specs, wreports)})
+                    wflexion["InFlex0000-X-Opt"] = wflexion[frozen.name]
         for spec in flex_specs:
-            table[spec.name][m] = cells[spec.name, m][1].runtime
-    tick("flex_sweep", t0)
+            table[spec.name] = {}
+        with phase("flex_sweep"):
+            if campaign:
+                all_res = iter(search_campaign(
+                    [(get_model(m), spec) for m in future_models
+                     for spec in flex_specs], cfg))
+                for m in future_models:
+                    for spec in flex_specs:
+                        cells[spec.name, m] = (spec, next(all_res))
+            else:
+                for m in future_models:
+                    layers = get_model(m)
+                    if cfg.engine == "batched":
+                        model_res = search_specs_batched(layers, flex_specs,
+                                                         cfg)
+                    else:
+                        model_res = [search_model(layers, spec, cfg)
+                                     for spec in flex_specs]
+                    for spec, mres in zip(flex_specs, model_res):
+                        cells[spec.name, m] = (spec, mres)
+            for m in future_models:
+                for spec in flex_specs:
+                    table[spec.name][m] = cells[spec.name, m][1].runtime
 
     # normalize by the frozen baseline per column
     base_row = table[f"InFlex0000-{base_model}-Opt"]

@@ -46,7 +46,7 @@ import numpy as np
 
 from repro.dist.pool import InFlightQueue
 
-from . import device_pool, ga_ops
+from . import device_pool, ga_ops, tracing
 from .cost_model import CostResult, evaluate_mapping_impl
 from .ga_ops import GENOME_LEN, GenDraws
 from .mapspace import mapspace_for, padded_tables
@@ -142,34 +142,39 @@ def _ga_program(dims, stride, depthwise, tile_lo, tile_hi, hard_partition,
     def body(i, carry):
         pop, best_obj, best_g, best_res, hist = carry
         d = jax.tree_util.tree_map(lambda x: x[i], draws)
-        res = evaluate(pop)
-        obj = getattr(res, objective)                          # (L, P)
-        order_idx = jnp.argsort(obj, axis=1, stable=True)
-        gen_best = order_idx[:, 0]
-        gen_obj = jnp.take_along_axis(obj, gen_best[:, None], axis=1)[:, 0]
-        improved = gen_obj < best_obj
-        best_obj = jnp.where(improved, gen_obj, best_obj)
-        gen_g = jnp.take_along_axis(pop, gen_best[:, None, None],
-                                    axis=1)[:, 0]
-        best_g = jnp.where(improved[:, None], gen_g, best_g)
-        # carry the winner's full cost breakdown (cheaper than a second
-        # evaluate instance after the loop)
-        best_res = CostResult(*(
-            jnp.where(improved,
-                      jnp.take_along_axis(f, gen_best[:, None], axis=1)[:, 0],
-                      bf)
-            for f, bf in zip(res, best_res)))
-        hist = hist.at[i].set(best_obj)
+        with jax.named_scope("evaluate"):
+            res = evaluate(pop)
+        with jax.named_scope("select"):
+            obj = getattr(res, objective)                      # (L, P)
+            order_idx = jnp.argsort(obj, axis=1, stable=True)
+            gen_best = order_idx[:, 0]
+            gen_obj = jnp.take_along_axis(obj, gen_best[:, None],
+                                          axis=1)[:, 0]
+            improved = gen_obj < best_obj
+            best_obj = jnp.where(improved, gen_obj, best_obj)
+            gen_g = jnp.take_along_axis(pop, gen_best[:, None, None],
+                                        axis=1)[:, 0]
+            best_g = jnp.where(improved[:, None], gen_g, best_g)
+            # carry the winner's full cost breakdown (cheaper than a second
+            # evaluate instance after the loop)
+            best_res = CostResult(*(
+                jnp.where(improved,
+                          jnp.take_along_axis(f, gen_best[:, None],
+                                              axis=1)[:, 0],
+                          bf)
+                for f, bf in zip(res, best_res)))
+            hist = hist.at[i].set(best_obj)
 
-        elites = jnp.take_along_axis(pop, order_idx[:, :n_elite, None],
-                                     axis=1)
-        parent_idx = jnp.take_along_axis(order_idx, d.ranks, axis=1)
-        parents = jnp.take_along_axis(pop, parent_idx[..., None], axis=1)
-        children = ga_ops.apply_crossover(parents, d, jnp)
-        children = ga_ops.clip_genomes(children, lo_b, hi_b, lens_b, jnp)
-        children = ga_ops.apply_mutation(children, d, lo_b, hi_b, lens_b,
-                                         jnp)
-        pop = jnp.concatenate([elites, children], axis=1)
+        with jax.named_scope("breed"):
+            elites = jnp.take_along_axis(pop, order_idx[:, :n_elite, None],
+                                         axis=1)
+            parent_idx = jnp.take_along_axis(order_idx, d.ranks, axis=1)
+            parents = jnp.take_along_axis(pop, parent_idx[..., None], axis=1)
+            children = ga_ops.apply_crossover(parents, d, jnp)
+            children = ga_ops.clip_genomes(children, lo_b, hi_b, lens_b, jnp)
+            children = ga_ops.apply_mutation(children, d, lo_b, hi_b, lens_b,
+                                             jnp)
+            pop = jnp.concatenate([elites, children], axis=1)
         return pop, best_obj, best_g, best_res, hist
 
     gens_pad = draws.step.shape[0]
@@ -373,57 +378,62 @@ def _prepare_chunk(rows: Sequence[EngineRow], cfg, hw: HWConfig
     """Assemble one chunk's padded host arrays (tables, populations, draw
     streams).  Pure host work — under ``cfg.pipeline`` it overlaps the
     previous chunk's device compute."""
-    population = cfg.population
-    n_children = population - ga_ops.n_elite(cfg)
-    gens = cfg.generations
-    gens_pad = _bucket(max(gens, 1), GEN_BUCKET)
-    n_pad = ROW_BUCKET
+    with tracing.span("engine.prepare", rows=len(rows), chunks=1):
+        population = cfg.population
+        n_children = population - ga_ops.n_elite(cfg)
+        gens = cfg.generations
+        gens_pad = _bucket(max(gens, 1), GEN_BUCKET)
+        n_pad = ROW_BUCKET
 
-    # -- distinct padded table sets + per-row table id ----------------------
-    # The table axis is padded to TABLE_BUCKET so that any number of distinct
-    # specs (1..bucket) presents the same shapes — no recompile per spec-set.
-    spec_ids = {}
-    tables = []
-    table_id = np.zeros(n_pad, np.int32)
-    for i, row in enumerate(rows):
-        if row.spec not in spec_ids:
-            spec_ids[row.spec] = len(tables)
-            tables.append(padded_tables(row.spec))
-        table_id[i] = spec_ids[row.spec]
-    t_pad = _bucket(len(tables), TABLE_BUCKET)
-    orders = np.zeros((t_pad,) + tables[0].orders.shape, np.int32)
-    pairs = np.zeros((t_pad,) + tables[0].pairs.shape, np.int32)
-    shapes = np.zeros((t_pad,) + tables[0].shapes.shape, np.int32)
-    # inert table slots decode to the native width (bits index 0 via lens=1)
-    reprs = np.full((t_pad,) + tables[0].reprs.shape,
-                    8 * hw.bytes_per_elem, np.int32)
-    lens = np.ones((t_pad, 4), np.int32)
-    for ti, t in enumerate(tables):
-        orders[ti], pairs[ti], shapes[ti], reprs[ti], lens[ti] = (
-            t.orders, t.pairs, t.shapes, t.reprs, t.lens)
+        # -- distinct padded table sets + per-row table id ------------------
+        # The table axis is padded to TABLE_BUCKET so that any number of
+        # distinct specs (1..bucket) presents the same shapes — no recompile
+        # per spec-set.
+        with tracing.span("engine.prepare.tables"):
+            spec_ids = {}
+            tables = []
+            table_id = np.zeros(n_pad, np.int32)
+            for i, row in enumerate(rows):
+                if row.spec not in spec_ids:
+                    spec_ids[row.spec] = len(tables)
+                    tables.append(padded_tables(row.spec))
+                table_id[i] = spec_ids[row.spec]
+            t_pad = _bucket(len(tables), TABLE_BUCKET)
+            orders = np.zeros((t_pad,) + tables[0].orders.shape, np.int32)
+            pairs = np.zeros((t_pad,) + tables[0].pairs.shape, np.int32)
+            shapes = np.zeros((t_pad,) + tables[0].shapes.shape, np.int32)
+            # inert table slots decode to the native width (bits index 0 via
+            # lens=1)
+            reprs = np.full((t_pad,) + tables[0].reprs.shape,
+                            8 * hw.bytes_per_elem, np.int32)
+            lens = np.ones((t_pad, 4), np.int32)
+            for ti, t in enumerate(tables):
+                orders[ti], pairs[ti], shapes[ti], reprs[ti], lens[ti] = (
+                    t.orders, t.pairs, t.shapes, t.reprs, t.lens)
 
-    # -- per-row state + draws, inert-padded to the buckets -----------------
-    dims = np.ones((n_pad, 6), np.int32)
-    stride = np.ones(n_pad, np.int32)
-    depthwise = np.zeros(n_pad, np.bool_)
-    tile_lo = np.ones((n_pad, 6), np.int32)
-    tile_hi = np.ones((n_pad, 6), np.int32)
-    hard_partition = np.zeros(n_pad, np.bool_)
-    pop0 = np.ones((n_pad, population, GENOME_LEN), np.int32)
-    draw_stack = ga_ops.empty_draw_stack(gens_pad, n_pad, n_children)
-    for i, row in enumerate(rows):
-        space = mapspace_for(row.layer, row.spec)
-        rng = np.random.default_rng(row.seed)
-        pop0[i] = ga_ops.initial_population(rng, space, cfg)
-        row_draws = ga_ops.draw_run(rng, space, cfg, gens, n_children)
-        for field, stacked in zip(row_draws, draw_stack):
-            stacked[:gens, i] = field
-        dims[i] = space.dims
-        stride[i] = row.layer.stride
-        depthwise[i] = row.layer.depthwise
-        tile_lo[i] = space.tile_lo
-        tile_hi[i] = space.tile_hi
-        hard_partition[i] = space.hard_partition
+        # -- per-row state + draws, inert-padded to the buckets -------------
+        with tracing.span("engine.prepare.draws"):
+            dims = np.ones((n_pad, 6), np.int32)
+            stride = np.ones(n_pad, np.int32)
+            depthwise = np.zeros(n_pad, np.bool_)
+            tile_lo = np.ones((n_pad, 6), np.int32)
+            tile_hi = np.ones((n_pad, 6), np.int32)
+            hard_partition = np.zeros(n_pad, np.bool_)
+            pop0 = np.ones((n_pad, population, GENOME_LEN), np.int32)
+            draw_stack = ga_ops.empty_draw_stack(gens_pad, n_pad, n_children)
+            for i, row in enumerate(rows):
+                space = mapspace_for(row.layer, row.spec)
+                rng = np.random.default_rng(row.seed)
+                pop0[i] = ga_ops.initial_population(rng, space, cfg)
+                row_draws = ga_ops.draw_run(rng, space, cfg, gens, n_children)
+                for field, stacked in zip(row_draws, draw_stack):
+                    stacked[:gens, i] = field
+                dims[i] = space.dims
+                stride[i] = row.layer.stride
+                depthwise[i] = row.layer.depthwise
+                tile_lo[i] = space.tile_lo
+                tile_hi[i] = space.tile_hi
+                hard_partition[i] = space.hard_partition
 
     return ChunkInputs(dims=dims, stride=stride, depthwise=depthwise,
                        tile_lo=tile_lo, tile_hi=tile_hi,
@@ -440,45 +450,49 @@ def _dispatch_chunk(c: ChunkInputs, cfg, hw: HWConfig, device=None):
     With ``device`` the chunk's arrays are committed there first, so the
     program executes on that device (jit follows committed inputs); the
     program and inputs are otherwise identical, hence identical outputs."""
-    # native-pinned chunks run the pre-R program (bit parity with v4);
-    # only a chunk with an open or off-native R table pays the scaled graph
-    native = 8 * hw.bytes_per_elem
-    with_repr = any(
-        int(l) > 1 or (r[:max(int(l), 1)] != native).any()
-        for r, l in zip(c.reprs, c.lens[:, 3]))
-    args = (c.dims, c.stride, c.depthwise, c.tile_lo, c.tile_hi,
-            c.hard_partition, c.table_id, c.orders, c.pairs, c.shapes,
-            c.reprs, c.lens, c.pop0, c.draws)
-    if device is not None:
-        args = jax.device_put(args, device)
-    return _ga_program(
-        *args, np.int32(c.gens),
-        hw=hw, n_elite=ga_ops.n_elite(cfg), objective=cfg.objective,
-        with_repr=with_repr)
+    with tracing.span("engine.dispatch"):
+        # native-pinned chunks run the pre-R program (bit parity with v4);
+        # only a chunk with an open or off-native R table pays the scaled
+        # graph
+        native = 8 * hw.bytes_per_elem
+        with_repr = any(
+            int(l) > 1 or (r[:max(int(l), 1)] != native).any()
+            for r, l in zip(c.reprs, c.lens[:, 3]))
+        args = (c.dims, c.stride, c.depthwise, c.tile_lo, c.tile_hi,
+                c.hard_partition, c.table_id, c.orders, c.pairs, c.shapes,
+                c.reprs, c.lens, c.pop0, c.draws)
+        if device is not None:
+            args = jax.device_put(args, device)
+        return _ga_program(
+            *args, np.int32(c.gens),
+            hw=hw, n_elite=ga_ops.n_elite(cfg), objective=cfg.objective,
+            with_repr=with_repr)
 
 
 def _collect_chunk(n_rows: int, gens: int, outputs) -> List[RowResult]:
     """Materialize a dispatched chunk (blocks on the device) and unpack the
     live rows."""
-    best_g, best_obj, hist, best = outputs
-    best_g = np.asarray(best_g)
-    best_obj = np.asarray(best_obj)
-    hist = np.asarray(hist)
-    best = CostResult(*(np.asarray(f) for f in best))
-
-    out = []
-    for i in range(n_rows):
-        out.append(RowResult(
-            best_genome=best_g[i],
-            best_obj=float(best_obj[i]),
-            history=[float(v) for v in hist[:gens, i]],
-            runtime=float(best.runtime[i]),
-            energy=float(best.energy[i]),
-            edp=float(best.edp[i]),
-            util=float(best.util[i]),
-            dram_elems=float(best.dram_elems[i]),
-            feasible=bool(best.feasible[i]),
-        ))
+    with tracing.span("engine.wait"):
+        jax.block_until_ready(outputs)
+    with tracing.span("engine.unpack"):
+        best_g, best_obj, hist, best = outputs
+        best_g = np.asarray(best_g)
+        best_obj = np.asarray(best_obj)
+        hist = np.asarray(hist)
+        best = CostResult(*(np.asarray(f) for f in best))
+        out = []
+        for i in range(n_rows):
+            out.append(RowResult(
+                best_genome=best_g[i],
+                best_obj=float(best_obj[i]),
+                history=[float(v) for v in hist[:gens, i]],
+                runtime=float(best.runtime[i]),
+                energy=float(best.energy[i]),
+                edp=float(best.edp[i]),
+                util=float(best.util[i]),
+                dram_elems=float(best.dram_elems[i]),
+                feasible=bool(best.feasible[i]),
+            ))
     return out
 
 

@@ -43,6 +43,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import tracing
 from .envvars import get_env
 from .result_cache import ResultCache
 from .spec import (FULLFLEX, FlexSpec, HWConfig, INFLEX, PARTFLEX,
@@ -222,8 +223,11 @@ def _jax_eval():
             if _JAX_EVAL is None:
                 import jax
                 import jax.numpy as jnp
-                _JAX_EVAL = jax.jit(
-                    lambda t, s, d, b: _pair_fractions(t, s, d, b, jnp))
+                def fractions(t, s, d, b):
+                    with jax.named_scope("flexion_fractions"):
+                        return _pair_fractions(t, s, d, b, jnp)
+
+                _JAX_EVAL = jax.jit(fractions)
     return _JAX_EVAL
 
 
@@ -352,20 +356,24 @@ class _Jobs:
         for ci, dstart in enumerate(range(0, len(self.draw_dims),
                                          draws_per_chunk)):
             dstop = min(dstart + draws_per_chunk, len(self.draw_dims))
-            t = np.empty((dstop - dstart, NUM_DIMS, self.n), np.float64)
-            for d in range(dstart, dstop):
-                _draw_tiles(self.draw_dims[d],
-                            np.random.default_rng(self.draw_seed[d]),
-                            self.n, out=t[d - dstart])
+            with tracing.span("flexion.draw",
+                              samples=(dstop - dstart) * self.n):
+                t = np.empty((dstop - dstart, NUM_DIMS, self.n), np.float64)
+                for d in range(dstart, dstop):
+                    _draw_tiles(self.draw_dims[d],
+                                np.random.default_rng(self.draw_seed[d]),
+                                self.n, out=t[d - dstart])
             sel = [i for i in range(j)
                    if dstart <= self.draw_id[i] < dstop]
-            soft, hard = _eval_jobs(
-                t,
-                np.asarray([self.draw_id[i] - dstart for i in sel], np.int64),
-                np.asarray([self.stride[i] for i in sel], np.float64),
-                np.asarray([self.depthwise[i] for i in sel]),
-                np.asarray([self.buf[i] for i in sel], np.float64),
-                chunk=ci, pool=pool)
+            with tracing.span("flexion.eval"):
+                soft, hard = _eval_jobs(
+                    t,
+                    np.asarray([self.draw_id[i] - dstart for i in sel],
+                               np.int64),
+                    np.asarray([self.stride[i] for i in sel], np.float64),
+                    np.asarray([self.depthwise[i] for i in sel]),
+                    np.asarray([self.buf[i] for i in sel], np.float64),
+                    chunk=ci, pool=pool)
             queue.push(sel, soft, hard)
         queue.drain()
         return p_soft, p_hard

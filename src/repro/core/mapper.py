@@ -35,7 +35,7 @@ import numpy as np
 
 from repro.dist.pool import InFlightQueue, parse_device_spec
 
-from . import device_pool, ga_ops
+from . import device_pool, ga_ops, tracing
 from .cost_model import (CostResult, evaluate_mapping_impl,
                          evaluate_population, evaluate_rows)
 from .engine import ROW_BUCKET, EngineRow, _bucket, run_batched_ga
@@ -594,8 +594,9 @@ def _fixed_configs_objective(dims, strides, dws, mask, tiles, orders, pairs,
         return _fixed_config_objective_impl(d, s, w, m, t, o, p, sh, r, hw,
                                             hard_partition, objective)
 
-    return jax.vmap(one)(dims, strides, dws, mask, tiles, orders, pairs,
-                         shapes, reprs)
+    with jax.named_scope("fixed_configs_objective"):
+        return jax.vmap(one)(dims, strides, dws, mask, tiles, orders, pairs,
+                             shapes, reprs)
 
 
 @dataclasses.dataclass
@@ -692,27 +693,30 @@ def search_fixed_configs(
         tiles_b, orders_b, pairs_b, shapes_b, reprs_b = _inert_mapping_rows(
             (m_pad, cfg.population), 8 * hw.bytes_per_elem)
         for _ in range(cfg.generations):
-            for mi, s in enumerate(group):
-                (tiles_b[mi], orders_b[mi], pairs_b[mi],
-                 shapes_b[mi], reprs_b[mi]) = s.space.decode_batch(s.pop)
-            r_live = bool((reprs_b != 8 * hw.bytes_per_elem).any())
-            obj_b = np.asarray(_fixed_configs_objective(
-                dims_b, strides_b, dws_b, mask_b,
-                jnp.asarray(tiles_b), jnp.asarray(orders_b),
-                jnp.asarray(pairs_b), jnp.asarray(shapes_b),
-                jnp.asarray(reprs_b) if r_live else None,
-                hw=hw, hard_partition=hard, objective=cfg.objective))
-            for s, obj in zip(group, obj_b):
-                order_idx = np.argsort(obj, kind="stable")
-                if obj[order_idx[0]] < s.best_obj:
-                    s.best_obj = float(obj[order_idx[0]])
-                    s.best_g = s.pop[order_idx[0]].copy()
-                elites = s.pop[order_idx[:n_elite]]
-                ranks = s.rng.choice(cfg.population, n_children,
-                                     p=ga_ops.rank_probs(cfg.population))
-                children = s.ops.mutate(s.ops.crossover(
-                    s.pop[order_idx[ranks]]))
-                s.pop = np.concatenate([elites, children], axis=0)
+            with tracing.span("design.decode"):
+                for mi, s in enumerate(group):
+                    (tiles_b[mi], orders_b[mi], pairs_b[mi],
+                     shapes_b[mi], reprs_b[mi]) = s.space.decode_batch(s.pop)
+                r_live = bool((reprs_b != 8 * hw.bytes_per_elem).any())
+            with tracing.span("design.objective", dispatches=1):
+                obj_b = np.asarray(_fixed_configs_objective(
+                    dims_b, strides_b, dws_b, mask_b,
+                    jnp.asarray(tiles_b), jnp.asarray(orders_b),
+                    jnp.asarray(pairs_b), jnp.asarray(shapes_b),
+                    jnp.asarray(reprs_b) if r_live else None,
+                    hw=hw, hard_partition=hard, objective=cfg.objective))
+            with tracing.span("design.breed"):
+                for s, obj in zip(group, obj_b):
+                    order_idx = np.argsort(obj, kind="stable")
+                    if obj[order_idx[0]] < s.best_obj:
+                        s.best_obj = float(obj[order_idx[0]])
+                        s.best_g = s.pop[order_idx[0]].copy()
+                    elites = s.pop[order_idx[:n_elite]]
+                    ranks = s.rng.choice(cfg.population, n_children,
+                                         p=ga_ops.rank_probs(cfg.population))
+                    children = s.ops.mutate(s.ops.crossover(
+                        s.pop[order_idx[ranks]]))
+                    s.pop = np.concatenate([elites, children], axis=0)
 
     assert all(s.best_g is not None for s in states)
     replays = evaluate_fixed_genome_many(
