@@ -37,6 +37,9 @@ argsort, strict-improve best tracking) — see tests/test_batched_engine.py.
 from __future__ import annotations
 
 import dataclasses
+import os
+import threading
+from concurrent.futures import ThreadPoolExecutor, wait
 from functools import partial
 from typing import List, NamedTuple, Optional, Sequence
 
@@ -56,6 +59,7 @@ from .workloads import Layer
 ROW_BUCKET = 64     # rows per program; larger row sets run in chunks
 GEN_BUCKET = 16     # draw arrays padded to a multiple of this
 TABLE_BUCKET = 8    # distinct spec table-sets per chunk, padded (shape-stable)
+DRAW_WORKERS = 4    # most threads drawing one chunk's rows (see _draw_workers)
 
 
 def _bucket(n: int, base: int) -> int:
@@ -289,8 +293,9 @@ def run_batched_ga(rows: Sequence[EngineRow], cfg,
     :class:`~repro.dist.pool.InFlightQueue`: chunk ``i`` is dispatched (JAX
     dispatch is asynchronous) and while the device crunches it, the host
     assembles the next chunks' draw streams — the host-side hot path of a
-    campaign-sized row set — keeping up to one chunk in flight *per pool
-    device* before blocking on the oldest.  Scheduling only; per-chunk
+    campaign-sized row set, drawn row by row on ``_prepare_chunk``'s draw
+    threads — keeping up to one chunk in flight *per pool device* before
+    blocking on the oldest.  Scheduling only; per-chunk
     inputs and outputs are unchanged, so results stay bit-identical to the
     unpipelined loop.  If preparing or dispatching a later chunk raises, the
     already-dispatched in-flight chunks are still collected (never abandoned
@@ -373,11 +378,48 @@ def run_batched_ga(rows: Sequence[EngineRow], cfg,
     return out
 
 
+def _host_cores() -> int:
+    """CPU cores this process may run on."""
+    if hasattr(os, "sched_getaffinity"):
+        return len(os.sched_getaffinity(0))
+    return os.cpu_count() or 1
+
+
+def _draw_workers(n_rows: int) -> int:
+    """Threads that draw a chunk of ``n_rows`` live rows: one per row, up
+    to the host's cores and ``DRAW_WORKERS``.  A one-row chunk is drawn
+    inline on the calling thread."""
+    return max(1, min(n_rows, _host_cores(), DRAW_WORKERS))
+
+
+_DRAW_POOL_LOCK = threading.Lock()
+_draw_pool_executor: Optional[ThreadPoolExecutor] = None
+
+
+def _draw_pool() -> ThreadPoolExecutor:
+    """The process's draw threads, made on first use and kept for every
+    later chunk.  numpy releases the GIL in the Generator's bulk fills and
+    in its array loops, which is most of a row's draw time."""
+    global _draw_pool_executor
+    with _DRAW_POOL_LOCK:
+        if _draw_pool_executor is None:
+            _draw_pool_executor = ThreadPoolExecutor(
+                max_workers=DRAW_WORKERS - 1,
+                thread_name_prefix="engine-draws")
+        return _draw_pool_executor
+
+
 def _prepare_chunk(rows: Sequence[EngineRow], cfg, hw: HWConfig
                    ) -> ChunkInputs:
     """Assemble one chunk's padded host arrays (tables, populations, draw
     streams).  Pure host work — under ``cfg.pipeline`` it overlaps the
-    previous chunk's device compute."""
+    previous chunk's device compute.
+
+    The rows' populations and draw streams are drawn on
+    :func:`_draw_workers` threads: the caller and the draw pool's threads
+    each take every k-th row.  Each row still draws from its own Generator
+    in the same call order, so the chunk is bit-identical to a one-thread
+    draw.  An error in any row is raised here once every thread is done."""
     with tracing.span("engine.prepare", rows=len(rows), chunks=1):
         population = cfg.population
         n_children = population - ga_ops.n_elite(cfg)
@@ -412,7 +454,8 @@ def _prepare_chunk(rows: Sequence[EngineRow], cfg, hw: HWConfig
                     t.orders, t.pairs, t.shapes, t.reprs, t.lens)
 
         # -- per-row state + draws, inert-padded to the buckets -------------
-        with tracing.span("engine.prepare.draws"):
+        workers = _draw_workers(len(rows))
+        with tracing.span("engine.prepare.draws", workers=workers):
             dims = np.ones((n_pad, 6), np.int32)
             stride = np.ones(n_pad, np.int32)
             depthwise = np.zeros(n_pad, np.bool_)
@@ -421,19 +464,35 @@ def _prepare_chunk(rows: Sequence[EngineRow], cfg, hw: HWConfig
             hard_partition = np.zeros(n_pad, np.bool_)
             pop0 = np.ones((n_pad, population, GENOME_LEN), np.int32)
             draw_stack = ga_ops.empty_draw_stack(gens_pad, n_pad, n_children)
-            for i, row in enumerate(rows):
-                space = mapspace_for(row.layer, row.spec)
-                rng = np.random.default_rng(row.seed)
-                pop0[i] = ga_ops.initial_population(rng, space, cfg)
-                row_draws = ga_ops.draw_run(rng, space, cfg, gens, n_children)
-                for field, stacked in zip(row_draws, draw_stack):
-                    stacked[:gens, i] = field
-                dims[i] = space.dims
-                stride[i] = row.layer.stride
-                depthwise[i] = row.layer.depthwise
-                tile_lo[i] = space.tile_lo
-                tile_hi[i] = space.tile_hi
-                hard_partition[i] = space.hard_partition
+
+            def draw_rows(first: int) -> None:
+                for i in range(first, len(rows), workers):
+                    row = rows[i]
+                    space = mapspace_for(row.layer, row.spec)
+                    rng = np.random.default_rng(row.seed)
+                    pop0[i] = ga_ops.initial_population(rng, space, cfg)
+                    row_draws = ga_ops.draw_run(rng, space, cfg, gens,
+                                                n_children)
+                    for field, stacked in zip(row_draws, draw_stack):
+                        stacked[:gens, i] = field
+                    dims[i] = space.dims
+                    stride[i] = row.layer.stride
+                    depthwise[i] = row.layer.depthwise
+                    tile_lo[i] = space.tile_lo
+                    tile_hi[i] = space.tile_hi
+                    hard_partition[i] = space.hard_partition
+
+            # rows write disjoint slices, so no lock.  Every thread finishes
+            # before the chunk is returned or an error leaves, so nothing
+            # writes into a chunk after it is abandoned.
+            helpers = [_draw_pool().submit(draw_rows, w)
+                       for w in range(1, workers)]
+            try:
+                draw_rows(0)
+            finally:
+                wait(helpers)
+            for h in helpers:
+                h.result()
 
     return ChunkInputs(dims=dims, stride=stride, depthwise=depthwise,
                        tile_lo=tile_lo, tile_hi=tile_hi,

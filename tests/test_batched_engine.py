@@ -13,7 +13,9 @@ import pytest
 from repro.core import (FULLFLEX, GAConfig, PARTFLEX, inflex_baseline,
                         make_variant, run_dse, search, search_model,
                         search_model_batched, search_specs_batched)
+from repro.core import engine
 from repro.core import mapper as mapper_mod
+from repro.core.engine import ROW_BUCKET, EngineRow, run_batched_ga
 from repro.core.workloads import Layer, get_model
 
 # the paper's quoted MnasNet layers 1 and 29
@@ -124,3 +126,81 @@ def test_dedup_off_matches_dedup_on_for_unique_layers():
     a = search_model_batched(layers, spec, CFG, dedup=True)
     b = search_model_batched(layers, spec, CFG, dedup=False)
     assert a.runtime == b.runtime
+
+
+# -- the chunk's row draws on the draw pool ---------------------------------
+
+# R-pinned and R-open, InFlex, PartFlex and FullFlex specs in one chunk
+CHUNK_SPECS = [inflex_baseline(), make_variant("1111", FULLFLEX),
+               make_variant("11111", FULLFLEX),
+               make_variant("11111", PARTFLEX)]
+
+
+def _chunk_rows(n):
+    layers = get_model("mnasnet")
+    return [EngineRow(layers[i % len(layers)],
+                      CHUNK_SPECS[i % len(CHUNK_SPECS)], seed=7 + 1000 * i)
+            for i in range(n)]
+
+
+@pytest.mark.parametrize("n_rows", [1, 7, ROW_BUCKET])
+def test_pooled_draws_match_inline_draws(monkeypatch, n_rows):
+    """Every array of a chunk drawn on several threads equals the one a
+    single thread draws: each row keeps its own Generator stream."""
+    rows = _chunk_rows(n_rows)
+    hw = rows[0].spec.hw
+    monkeypatch.setattr(engine, "_draw_workers",
+                        lambda n: min(n, engine.DRAW_WORKERS))
+    pooled = engine._prepare_chunk(rows, CFG, hw)
+    monkeypatch.setattr(engine, "_draw_workers", lambda n: 1)
+    inline = engine._prepare_chunk(rows, CFG, hw)
+    assert pooled.gens == inline.gens
+    for name in engine.ChunkInputs._fields:
+        if name == "gens":
+            continue
+        a, b = getattr(pooled, name), getattr(inline, name)
+        if name == "draws":
+            for field in a._fields:
+                assert np.array_equal(getattr(a, field),
+                                      getattr(b, field)), field
+        else:
+            assert np.array_equal(a, b), name
+
+
+@pytest.mark.parametrize("bad_row", [5, ROW_BUCKET + 5])
+def test_draw_error_reaches_the_chunk_handler(monkeypatch, bad_row):
+    """A row whose map space raises on a draw thread fails its chunk with
+    the chunk's context, after every dispatched chunk was collected."""
+    rows = [EngineRow(Layer(f"l{i}", (8, 4, 6, 6, 3, 3)),
+                      make_variant("1111"), seed=i)
+            for i in range(ROW_BUCKET + 6)]
+    real_mapspace = engine.mapspace_for
+
+    def failing_mapspace(layer, spec):
+        if layer.name == f"l{bad_row}":
+            raise ValueError("bad map space")
+        return real_mapspace(layer, spec)
+
+    queues, collected = [], []
+    real_queue, real_collect = engine.InFlightQueue, engine._collect_chunk
+
+    def spy_queue(*args, **kwargs):
+        queues.append(real_queue(*args, **kwargs))
+        return queues[-1]
+
+    def spy_collect(n_rows, gens, outputs):
+        collected.append(n_rows)
+        return real_collect(n_rows, gens, outputs)
+
+    monkeypatch.setattr(engine, "mapspace_for", failing_mapspace)
+    monkeypatch.setattr(engine, "InFlightQueue", spy_queue)
+    monkeypatch.setattr(engine, "_collect_chunk", spy_collect)
+    cfg = GAConfig(population=8, generations=4, seed=3, pipeline=True)
+    idx = bad_row // ROW_BUCKET
+    with pytest.raises(RuntimeError,
+                       match=f"engine chunk {idx}/2 .* failed during "
+                             f"prepare/dispatch") as err:
+        run_batched_ga(rows, cfg)
+    assert isinstance(err.value.__cause__, ValueError)
+    assert len(queues) == 1 and len(queues[0]) == 0
+    assert collected == [ROW_BUCKET] * idx

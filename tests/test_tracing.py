@@ -66,6 +66,23 @@ def test_no_recorder_changes_nothing(recorded):
             (b.best_obj, b.history, b.runtime, b.energy, b.feasible)
 
 
+@pytest.mark.parametrize("n_rows", [1, ROW_BUCKET])
+def test_draw_workers_counter(n_rows):
+    """``engine.prepare.draws:workers`` counts the threads that drew the
+    chunk: the calling thread alone for one row, up to the host's cores
+    for a full chunk."""
+    sink = {}
+    with tracing.recording(sink):
+        engine._prepare_chunk(_rows(n_rows), CFG, HWConfig())
+    workers = sink["engine.prepare.draws:workers"]
+    if n_rows == 1:
+        assert workers == 1
+    else:
+        assert 1 <= workers <= engine._host_cores()
+        assert workers == min(n_rows, engine._host_cores(),
+                              engine.DRAW_WORKERS)
+
+
 def test_recorders_nest():
     outer, inner = {}, {}
     with tracing.recording(outer):
