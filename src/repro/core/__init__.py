@@ -22,7 +22,7 @@ from .kernel_bridge import (KernelConfig, KernelWorkload, MeasuredRunner,
                             lower_genome, lower_mapping, mamba_workload,
                             matmul_workload, parity_check,
                             predicted_runtime, rank_correlation_study,
-                            spearman, tune_kernel)
+                            spearman, tune_kernel, workload_for_layer)
 from .mapper import (GAConfig, MapperResult, ModelResult,
                      assemble_model_result, evaluate_fixed_genome,
                      evaluate_fixed_genome_many, plan_model_rows,
@@ -37,7 +37,8 @@ from .precision import (FULL_BITS, PART_BITS, bytes_of, element_scale,
 from .spec import (FULLFLEX, INFLEX, PARTFLEX, FlexSpec, HWConfig, OrderSpec,
                    ParallelSpec, RepresentationSpec, ShapeSpec, TileSpec,
                    inflex_baseline, make_variant)
-from .workloads import MODEL_ZOO, Layer, conv, dwconv, gemm, get_model
+from .workloads import (MODEL_ZOO, Layer, conv, dwconv, gemm, get_model,
+                        grouped_gemm, ragged_gemm)
 
 __all__ = [
     "AreaReport", "area_of", "ALL_CLASSES", "ALL_CLASSES_5", "PRIOR_WORK",
@@ -54,7 +55,7 @@ __all__ = [
     "attention_workload", "bridge_tile_feasible", "config_legal",
     "lower_genome", "lower_mapping", "mamba_workload", "matmul_workload",
     "parity_check", "predicted_runtime", "rank_correlation_study",
-    "spearman", "tune_kernel",
+    "spearman", "tune_kernel", "workload_for_layer",
     "GAConfig", "MapperResult", "ModelResult", "assemble_model_result",
     "evaluate_fixed_genome",
     "evaluate_fixed_genome_many", "plan_model_rows", "raw_tile_feasibility",
@@ -68,5 +69,6 @@ __all__ = [
     "ParallelSpec", "RepresentationSpec", "ShapeSpec", "TileSpec",
     "inflex_baseline",
     "make_variant", "MODEL_ZOO", "Layer", "conv", "dwconv", "gemm",
+    "grouped_gemm", "ragged_gemm",
     "get_model",
 ]

@@ -33,12 +33,14 @@ from .workloads import C, K, NUM_DIMS, R, S, X, Y
 
 BIG = jnp.float32(1e30)
 
-# Dependency masks over (K, C, Y, X, R, S); depthwise swaps K-dependence for C.
+# Dependency masks over (K, C, Y, X, R, S); depthwise swaps K-dependence for
+# C, a grouped layer's weight also depends on X (docs/mapper.md "Layer kinds").
 _DEP_IN = np.array([0, 1, 1, 1, 1, 1], np.bool_)       # input
 _DEP_W = np.array([1, 1, 0, 0, 1, 1], np.bool_)        # weight
 _DEP_O = np.array([1, 0, 1, 1, 0, 0], np.bool_)        # output
 _DEP_W_DW = np.array([0, 1, 0, 0, 1, 1], np.bool_)     # depthwise weight
 _DEP_O_DW = np.array([0, 1, 1, 1, 0, 0], np.bool_)     # depthwise output
+_DEP_W_G = np.array([1, 1, 0, 1, 1, 1], np.bool_)      # grouped weight
 
 
 class CostResult(NamedTuple):
@@ -92,7 +94,7 @@ def evaluate_mapping_impl(dims: jnp.ndarray, stride: jnp.ndarray,
                           tiles: jnp.ndarray, order: jnp.ndarray,
                           par: jnp.ndarray, shape_rc: jnp.ndarray,
                           hw: HWConfig, hard_partition,
-                          repr_bits=None) -> CostResult:
+                          repr_bits=None, grouped=None) -> CostResult:
     """Cost one mapping of one layer.  All args are arrays => vmap-friendly.
 
     dims: (6,) int   layer (K, C, Y, X, R, S)
@@ -111,6 +113,9 @@ def evaluate_mapping_impl(dims: jnp.ndarray, stride: jnp.ndarray,
         SIMD below native, bit-serial above); MAC energy quadratically.  At
         the native width every scale is exactly 1.0 — an IEEE-exact identity,
         so pinned-R results are bit-identical to the pre-R model.
+    grouped: () bool, or None when no row of the batch is grouped (the
+        program then holds no grouped term).  A grouped layer's weight
+        depends on X: its tile volume gains t_X and its reuse the X loop.
     """
     if repr_bits is None:
         bscale = jnp.float32(1.0)
@@ -126,6 +131,8 @@ def evaluate_mapping_impl(dims: jnp.ndarray, stride: jnp.ndarray,
     stride = stride.astype(jnp.float32)
 
     dep_w = jnp.where(depthwise, jnp.asarray(_DEP_W_DW), jnp.asarray(_DEP_W))
+    if grouped is not None:
+        dep_w = jnp.where(grouped, jnp.asarray(_DEP_W_G), dep_w)
     dep_o = jnp.where(depthwise, jnp.asarray(_DEP_O_DW), jnp.asarray(_DEP_O))
     dep_i = jnp.asarray(_DEP_IN)
 
@@ -134,6 +141,9 @@ def evaluate_mapping_impl(dims: jnp.ndarray, stride: jnp.ndarray,
     in_x = (t[X] - 1.0) * stride + t[S]
     vol_in = t[C] * in_y * in_x
     vol_w = jnp.where(depthwise, 1.0, t[K]) * t[C] * t[R] * t[S]
+    if grouped is not None:
+        # a select, not a multiply by 1.0: plain rows keep the plain value
+        vol_w = jnp.where(grouped, vol_w * t[X], vol_w)
     vol_out = jnp.where(depthwise, t[C], t[K]) * t[Y] * t[X]
 
     buf = jnp.float32(hw.buffer_elems)
@@ -213,16 +223,74 @@ def evaluate_mapping_impl(dims: jnp.ndarray, stride: jnp.ndarray,
     )
 
 
+def evaluate_groups_impl(group_dims: jnp.ndarray, group_live: jnp.ndarray,
+                         stride: jnp.ndarray, depthwise: jnp.ndarray,
+                         tiles: jnp.ndarray, order: jnp.ndarray,
+                         par: jnp.ndarray, shape_rc: jnp.ndarray,
+                         hw: HWConfig, hard_partition, repr_bits=None,
+                         grouped=None) -> CostResult:
+    """Cost one mapping of a layer as the sum over its groups (the ragged
+    kind, docs/mapper.md "Layer kinds"): ``group_dims`` (G, 6) nests and
+    their ``group_live`` (G,) mask; every live group is costed under the
+    same mapping, its tiles clipped to its own dims.  Runtime, energy and
+    traffic add up; the layer is feasible where its largest group is (the
+    tile volumes grow with the rows, so that is where every group is);
+    utilization is the groups' average weighted by their rows.  A layer of
+    one live group costs what ``evaluate_mapping_impl`` gives it, up to
+    how XLA fuses the larger program (float32 rounding)."""
+
+    def one(d_):
+        return evaluate_mapping_impl(d_, stride, depthwise, tiles, order,
+                                     par, shape_rc, hw, hard_partition,
+                                     repr_bits, grouped)
+
+    with jax.named_scope("evaluate_ragged"):
+        res = jax.vmap(one)(group_dims)
+
+        def total(f):
+            return jnp.sum(jnp.where(group_live, f, 0.0))
+
+        feasible = jnp.all(res.feasible | ~group_live)
+        rows = jnp.where(group_live, group_dims[:, Y], 0).astype(jnp.float32)
+        runtime = jnp.where(feasible, total(res.runtime), BIG)
+        energy = jnp.where(feasible, total(res.energy), BIG)
+        util = jnp.sum(res.util * (rows / jnp.maximum(jnp.sum(rows), 1.0)))
+        return CostResult(
+            runtime=runtime, energy=energy, feasible=feasible,
+            util=jnp.where(feasible, util, 0.0),
+            dram_elems=total(res.dram_elems), l2_elems=total(res.l2_elems),
+            edp=jnp.where(feasible, runtime * energy, BIG))
+
+
+def evaluate_kinds_impl(dims, stride, depthwise, tiles, order, par,
+                        shape_rc, hw: HWConfig, hard_partition,
+                        repr_bits=None, grouped=None,
+                        groups=None) -> CostResult:
+    """One mapping of one layer of any kind: ``groups`` is ``None`` (the
+    plain, depthwise and grouped kinds) or the layer's ``(group_dims,
+    group_live)`` pair, the ragged program variant."""
+    if groups is None:
+        return evaluate_mapping_impl(dims, stride, depthwise, tiles, order,
+                                     par, shape_rc, hw, hard_partition,
+                                     repr_bits, grouped)
+    return evaluate_groups_impl(groups[0], groups[1], stride, depthwise,
+                                tiles, order, par, shape_rc, hw,
+                                hard_partition, repr_bits, grouped)
+
+
 @partial(jax.jit, static_argnames=("hw", "hard_partition"))
 def evaluate_mapping(dims: jnp.ndarray, stride: jnp.ndarray,
                      depthwise: jnp.ndarray,
                      tiles: jnp.ndarray, order: jnp.ndarray,
                      par: jnp.ndarray, shape_rc: jnp.ndarray,
                      hw: HWConfig, hard_partition: bool = False,
-                     repr_bits=None) -> CostResult:
-    """Jitted single-mapping entry point (static hard_partition)."""
-    return evaluate_mapping_impl(dims, stride, depthwise, tiles, order, par,
-                                 shape_rc, hw, hard_partition, repr_bits)
+                     repr_bits=None, grouped=None,
+                     groups=None) -> CostResult:
+    """Jitted single-mapping entry point (static hard_partition);
+    ``grouped`` and ``groups`` as :func:`evaluate_kinds_impl` takes them."""
+    return evaluate_kinds_impl(dims, stride, depthwise, tiles, order, par,
+                               shape_rc, hw, hard_partition, repr_bits,
+                               grouped, groups)
 
 
 @partial(jax.jit, static_argnames=("hw", "hard_partition"))
@@ -231,21 +299,16 @@ def evaluate_population(dims: jnp.ndarray, stride: jnp.ndarray,
                         tiles: jnp.ndarray, order: jnp.ndarray,
                         par: jnp.ndarray, shape_rc: jnp.ndarray,
                         hw: HWConfig, hard_partition: bool = False,
-                        reprs=None) -> CostResult:
-    """vmap of evaluate_mapping over a (P, ...) population of mappings."""
+                        reprs=None, grouped=None,
+                        groups=None) -> CostResult:
+    """vmap of evaluate_mapping over a (P, ...) population of mappings;
+    ``grouped`` and ``groups`` as :func:`evaluate_kinds_impl` takes them."""
 
-    if reprs is None:
-        def one(t_, o_, p_, s_):
-            return evaluate_mapping_impl(dims, stride, depthwise, t_, o_, p_,
-                                         s_, hw, hard_partition)
+    def one(t_, o_, p_, s_, r_):
+        return evaluate_kinds_impl(dims, stride, depthwise, t_, o_, p_, s_,
+                                   hw, hard_partition, r_, grouped, groups)
 
-        return jax.vmap(one)(tiles, order, par, shape_rc)
-
-    def one_r(t_, o_, p_, s_, r_):
-        return evaluate_mapping_impl(dims, stride, depthwise, t_, o_, p_, s_,
-                                     hw, hard_partition, r_)
-
-    return jax.vmap(one_r)(tiles, order, par, shape_rc, reprs)
+    return jax.vmap(one)(tiles, order, par, shape_rc, reprs)
 
 
 @partial(jax.jit, static_argnames=("hw",))
@@ -254,27 +317,21 @@ def evaluate_rows(dims: jnp.ndarray, stride: jnp.ndarray,
                   tiles: jnp.ndarray, order: jnp.ndarray,
                   par: jnp.ndarray, shape_rc: jnp.ndarray,
                   hard_partition: jnp.ndarray, hw: HWConfig,
-                  reprs=None) -> CostResult:
+                  reprs=None, grouped=None, groups=None) -> CostResult:
     """Batch-axis plumbing for the MSE engine: one mapping per *row*, where a
     row is a (layer, spec) pair — every array carries a leading (L,) axis,
     including the (traced) per-row hard-partition flag (and, when given, the
-    per-row operand bit-width)."""
+    per-row operand bit-width, grouped flag and ``(group_dims, group_live)``
+    table of the ragged variant)."""
+
+    def one(d_, s_, w_, t_, o_, p_, sh_, hp_, r_, g_, gr_):
+        return evaluate_kinds_impl(d_, s_, w_, t_, o_, p_, sh_, hw, hp_, r_,
+                                   g_, gr_)
 
     with jax.named_scope("evaluate_rows"):
-        if reprs is None:
-            def one(d_, s_, w_, t_, o_, p_, sh_, hp_):
-                return evaluate_mapping_impl(d_, s_, w_, t_, o_, p_, sh_, hw,
-                                             hp_)
-
-            return jax.vmap(one)(dims, stride, depthwise, tiles, order, par,
-                                 shape_rc, hard_partition)
-
-        def one_r(d_, s_, w_, t_, o_, p_, sh_, hp_, r_):
-            return evaluate_mapping_impl(d_, s_, w_, t_, o_, p_, sh_, hw,
-                                         hp_, r_)
-
-        return jax.vmap(one_r)(dims, stride, depthwise, tiles, order, par,
-                               shape_rc, hard_partition, reprs)
+        return jax.vmap(one)(dims, stride, depthwise, tiles, order, par,
+                             shape_rc, hard_partition, reprs, grouped,
+                             groups)
 
 
 def lower_bound_cycles(dims: np.ndarray, depthwise: bool,

@@ -50,11 +50,11 @@ import numpy as np
 from repro.dist.pool import InFlightQueue
 
 from . import device_pool, ga_ops, tracing
-from .cost_model import CostResult, evaluate_mapping_impl
+from .cost_model import CostResult, evaluate_kinds_impl
 from .ga_ops import GENOME_LEN, GenDraws
 from .mapspace import mapspace_for, padded_tables
 from .spec import FlexSpec, HWConfig
-from .workloads import Layer
+from .workloads import Layer, group_table
 
 ROW_BUCKET = 64     # rows per program; larger row sets run in chunks
 GEN_BUCKET = 16     # draw arrays padded to a multiple of this
@@ -83,18 +83,21 @@ class RowResult(NamedTuple):
     feasible: bool
 
 
-@partial(jax.jit,
-         static_argnames=("hw", "n_elite", "objective", "with_repr"))
+_GA_STATICS = ("hw", "n_elite", "objective", "with_repr")
+
+
+@partial(jax.jit, static_argnames=_GA_STATICS)
 def _ga_program(dims, stride, depthwise, tile_lo, tile_hi, hard_partition,
                 table_id, orders, pairs, shapes, reprs, lens, pop0, draws,
-                n_gens, hw: HWConfig, n_elite: int, objective: str,
-                with_repr: bool = False):
+                n_gens, grouped=None, *, hw: HWConfig, n_elite: int,
+                objective: str, with_repr: bool = False):
     """The whole GA for all rows in one program.
 
     Shapes: dims (L,6) stride (L,) depthwise (L,) tile_lo/hi (L,6)
     hard_partition (L,) table_id (L,) orders (T,720,6) pairs (T,30,2)
     shapes (T,S,2) reprs (T,R_PAD) lens (T,4) pop0 (L,P,10) draws leaves
-    (Gp,L,Pc,...) n_gens () traced.
+    (Gp,L,Pc,...) n_gens () traced; grouped (L,) or None when no row of
+    the chunk is grouped (the program then holds no grouped term).
 
     ``with_repr`` (static) selects the cost-model program: False traces the
     pre-R graph (no width-scaling ops — XLA's FMA fusion then matches the
@@ -102,6 +105,33 @@ def _ga_program(dims, stride, depthwise, tile_lo, tile_hi, hard_partition,
     rows; ``reprs`` is dead code and DCE'd); True threads each mapping's
     decoded bit-width into the width-scaled cost model.
     """
+    return _ga_run(dims, stride, depthwise, tile_lo, tile_hi, hard_partition,
+                   table_id, orders, pairs, shapes, reprs, lens, pop0, draws,
+                   n_gens, grouped, None, hw, n_elite, objective, with_repr)
+
+
+@partial(jax.jit, static_argnames=_GA_STATICS)
+def _ga_program_ragged(dims, stride, depthwise, tile_lo, tile_hi,
+                       hard_partition, table_id, orders, pairs, shapes, reprs,
+                       lens, pop0, draws, n_gens, grouped, group_dims,
+                       group_live, *, hw: HWConfig, n_elite: int,
+                       objective: str, with_repr: bool = False):
+    """:func:`_ga_program`'s ragged variant: every row costs the sum over
+    its ``group_dims`` (L, G, 6) nests where ``group_live`` (L, G), under
+    the row's one mapping (docs/mapper.md "Layer kinds").  A program of its
+    own, chosen per chunk like ``with_repr``: the engine packs ragged rows
+    into chunks of their own, so a chunk with none never runs it."""
+    return _ga_run(dims, stride, depthwise, tile_lo, tile_hi, hard_partition,
+                   table_id, orders, pairs, shapes, reprs, lens, pop0, draws,
+                   n_gens, grouped, (group_dims, group_live), hw, n_elite,
+                   objective, with_repr)
+
+
+def _ga_run(dims, stride, depthwise, tile_lo, tile_hi, hard_partition,
+            table_id, orders, pairs, shapes, reprs, lens, pop0, draws, n_gens,
+            grouped, groups, hw: HWConfig, n_elite: int, objective: str,
+            with_repr: bool):
+    """Body of both GA programs (traced inside their jit)."""
     n_rows, population, _ = pop0.shape
     row_lens = lens[table_id]                        # (L, 4)
     lo_b = tile_lo[:, None, :]
@@ -124,24 +154,15 @@ def _ga_program(dims, stride, depthwise, tile_lo, tile_hi, hard_partition,
     def evaluate(pop) -> CostResult:
         tiles, order, par, shape_rc, bits = decode(pop)
 
-        if with_repr:
-            def per_row(d_, s_, w_, hp_, t_, o_, p_, sh_, b_):
-                def per_mapping(t1, o1, p1, s1, b1):
-                    return evaluate_mapping_impl(d_, s_, w_, t1, o1, p1, s1,
-                                                 hw, hp_, b1)
-                return jax.vmap(per_mapping)(t_, o_, p_, sh_, b_)
-
-            return jax.vmap(per_row)(dims, stride, depthwise, hard_partition,
-                                     tiles, order, par, shape_rc, bits)
-
-        def per_row(d_, s_, w_, hp_, t_, o_, p_, sh_):
-            def per_mapping(t1, o1, p1, s1):
-                return evaluate_mapping_impl(d_, s_, w_, t1, o1, p1, s1,
-                                             hw, hp_)
-            return jax.vmap(per_mapping)(t_, o_, p_, sh_)
+        def per_row(d_, s_, w_, hp_, g_, gr_, t_, o_, p_, sh_, b_):
+            def per_mapping(t1, o1, p1, s1, b1):
+                return evaluate_kinds_impl(d_, s_, w_, t1, o1, p1, s1, hw,
+                                           hp_, b1, g_, gr_)
+            return jax.vmap(per_mapping)(t_, o_, p_, sh_, b_)
 
         return jax.vmap(per_row)(dims, stride, depthwise, hard_partition,
-                                 tiles, order, par, shape_rc)
+                                 grouped, groups, tiles, order, par,
+                                 shape_rc, bits)
 
     def body(i, carry):
         pop, best_obj, best_g, best_res, hist = carry
@@ -224,6 +245,9 @@ class ChunkInputs(NamedTuple):
     pop0: np.ndarray
     draws: GenDraws
     gens: int
+    grouped: Optional[np.ndarray] = None     # (L,) when a row is grouped
+    group_dims: Optional[np.ndarray] = None  # (L, G, 6) when a row is ragged
+    group_live: Optional[np.ndarray] = None  # (L, G)
 
 
 # GAConfig fields deliberately NOT folded into ga_params_key, with why each
@@ -253,13 +277,15 @@ def ga_params_key(cfg) -> tuple:
 
 def row_cache_key(row: EngineRow, cfg) -> tuple:
     """Canonical persistent-cache key of one engine row: GA params + spec +
-    the spec-relevant layer fields + the row seed.  Layer *names* are
+    the spec-relevant layer fields (dims, stride, kind, group rows) + the
+    row seed.  Layer *names* are
     excluded (the ``mapper._dedup_key`` discipline), so equal shapes from
     different models/clients share one cached result."""
     layer = row.layer
     return ("mapper-row", ga_params_key(cfg), row.spec,
             tuple(int(d) for d in layer.dims), int(layer.stride),
-            bool(layer.depthwise), int(row.seed))
+            bool(layer.depthwise), layer.kind, layer.group_rows,
+            int(row.seed))
 
 
 def run_batched_ga(rows: Sequence[EngineRow], cfg,
@@ -278,7 +304,10 @@ def run_batched_ga(rows: Sequence[EngineRow], cfg,
 
     Row sets larger than ``ROW_BUCKET`` run in bucket-sized chunks so that
     *every* call — any model, any number of specs — reuses the same compiled
-    program instead of forcing a bigger-shape recompile.
+    program instead of forcing a bigger-shape recompile.  Ragged rows are
+    packed into chunks of their own, after the others: only those chunks
+    run the ragged program variant (``_ga_program_ragged``).  Rows are
+    independent, so the packing changes no result.
 
     Chunks are independent, so they can run anywhere: with a device pool
     (``cfg.devices`` or ``REPRO_DEVICES``, see ``repro.core.device_pool``)
@@ -325,8 +354,8 @@ def run_batched_ga(rows: Sequence[EngineRow], cfg,
     assert all(r.spec.hw == hw for r in rows), \
         "batched rows must share an HWConfig"
     pool = device_pool.pool_for(cfg)
-    chunks = [rows[start:start + ROW_BUCKET]
-              for start in range(0, len(rows), ROW_BUCKET)]
+    chunk_pos = pack_chunks([r.layer for r in rows])
+    chunks = [[rows[i] for i in pos] for pos in chunk_pos]
     out: List[RowResult] = []
     if getattr(cfg, "pipeline", False):
         n_chunks = len(chunks)
@@ -351,8 +380,7 @@ def run_batched_ga(rows: Sequence[EngineRow], cfg,
                 except Exception as e:
                     raise RuntimeError(
                         f"engine chunk {idx}/{n_chunks} (rows "
-                        f"{idx * ROW_BUCKET}.."
-                        f"{idx * ROW_BUCKET + len(chunk) - 1}"
+                        f"{chunk_pos[idx][0]}..{chunk_pos[idx][-1]}"
                         f") failed during prepare/dispatch") from e
                 out.extend(queue.push(idx, len(chunk), inputs.gens, outputs))
             out.extend(queue.drain())
@@ -375,7 +403,20 @@ def run_batched_ga(rows: Sequence[EngineRow], cfg,
                 _dispatch_chunk(inputs, cfg, hw,
                                 device=pool.device_for(idx) if pool
                                 else None)))
-    return out
+    by_row: List[Optional[RowResult]] = [None] * len(rows)
+    for i, res in zip((i for pos in chunk_pos for i in pos), out):
+        by_row[i] = res
+    return by_row
+
+
+def pack_chunks(layers: Sequence[Layer]) -> List[List[int]]:
+    """Positions of ``layers`` in ``ROW_BUCKET``-sized chunks: the
+    non-ragged rows in order, then the ragged ones in chunks of their own,
+    so only those run the ragged program variant."""
+    parts = ([i for i, l in enumerate(layers) if not l.ragged],
+             [i for i, l in enumerate(layers) if l.ragged])
+    return [part[start:start + ROW_BUCKET] for part in parts
+            for start in range(0, len(part), ROW_BUCKET)]
 
 
 def _host_cores() -> int:
@@ -419,8 +460,16 @@ def _prepare_chunk(rows: Sequence[EngineRow], cfg, hw: HWConfig
     :func:`_draw_workers` threads: the caller and the draw pool's threads
     each take every k-th row.  Each row still draws from its own Generator
     in the same call order, so the chunk is bit-identical to a one-thread
-    draw.  An error in any row is raised here once every thread is done."""
-    with tracing.span("engine.prepare", rows=len(rows), chunks=1):
+    draw.  An error in any row is raised here once every thread is done.
+
+    Grouped rows add the traced ``grouped`` flags and ragged rows the
+    group tables of the ragged program variant (``ChunkInputs``)."""
+    kinds = [row.layer.kind for row in rows]
+    ragged = [row.layer for row in rows if row.layer.ragged]
+    with tracing.span("engine.prepare", rows=len(rows), chunks=1,
+                      grouped_rows=kinds.count("grouped"),
+                      ragged_rows=len(ragged),
+                      groups=sum(len(l.group_dims()) for l in ragged)):
         population = cfg.population
         n_children = population - ga_ops.n_elite(cfg)
         gens = cfg.generations
@@ -462,6 +511,7 @@ def _prepare_chunk(rows: Sequence[EngineRow], cfg, hw: HWConfig
             tile_lo = np.ones((n_pad, 6), np.int32)
             tile_hi = np.ones((n_pad, 6), np.int32)
             hard_partition = np.zeros(n_pad, np.bool_)
+            grouped = np.zeros(n_pad, np.bool_)
             pop0 = np.ones((n_pad, population, GENOME_LEN), np.int32)
             draw_stack = ga_ops.empty_draw_stack(gens_pad, n_pad, n_children)
 
@@ -478,6 +528,7 @@ def _prepare_chunk(rows: Sequence[EngineRow], cfg, hw: HWConfig
                     dims[i] = space.dims
                     stride[i] = row.layer.stride
                     depthwise[i] = row.layer.depthwise
+                    grouped[i] = row.layer.grouped
                     tile_lo[i] = space.tile_lo
                     tile_hi[i] = space.tile_hi
                     hard_partition[i] = space.hard_partition
@@ -494,12 +545,20 @@ def _prepare_chunk(rows: Sequence[EngineRow], cfg, hw: HWConfig
             for h in helpers:
                 h.result()
 
+        group_dims = group_live = None
+        if ragged:
+            with tracing.span("engine.prepare.groups"):
+                group_dims, group_live = group_table(
+                    [row.layer for row in rows], n_pad)
+
     return ChunkInputs(dims=dims, stride=stride, depthwise=depthwise,
                        tile_lo=tile_lo, tile_hi=tile_hi,
                        hard_partition=hard_partition, table_id=table_id,
                        orders=orders, pairs=pairs, shapes=shapes,
                        reprs=reprs, lens=lens, pop0=pop0, draws=draw_stack,
-                       gens=gens)
+                       gens=gens,
+                       grouped=grouped if grouped.any() else None,
+                       group_dims=group_dims, group_live=group_live)
 
 
 def _dispatch_chunk(c: ChunkInputs, cfg, hw: HWConfig, device=None):
@@ -519,13 +578,18 @@ def _dispatch_chunk(c: ChunkInputs, cfg, hw: HWConfig, device=None):
             for r, l in zip(c.reprs, c.lens[:, 3]))
         args = (c.dims, c.stride, c.depthwise, c.tile_lo, c.tile_hi,
                 c.hard_partition, c.table_id, c.orders, c.pairs, c.shapes,
-                c.reprs, c.lens, c.pop0, c.draws)
+                c.reprs, c.lens, c.pop0, c.draws, np.int32(c.gens),
+                c.grouped)
+        # ragged chunks run their own program variant
+        program = _ga_program
+        if c.group_dims is not None:
+            program = _ga_program_ragged
+            args += (c.group_dims, c.group_live)
         if device is not None:
             args = jax.device_put(args, device)
-        return _ga_program(
-            *args, np.int32(c.gens),
-            hw=hw, n_elite=ga_ops.n_elite(cfg), objective=cfg.objective,
-            with_repr=with_repr)
+        return program(
+            *args, hw=hw, n_elite=ga_ops.n_elite(cfg),
+            objective=cfg.objective, with_repr=with_repr)
 
 
 def _collect_chunk(n_rows: int, gens: int, outputs) -> List[RowResult]:

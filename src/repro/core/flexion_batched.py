@@ -10,8 +10,8 @@ all requested estimates the way ``search_campaign`` packs MSE rows:
     (host-side numpy Generators, the PR 2 measurement discipline:
     device-side draws were measured slower on CPU) into a dim-major
     ``(D, 6, N)`` tensor, and every distinct ``(draw, stride, depthwise,
-    buf)`` **evaluation job** runs once over its draw — fig8's six buffer
-    sizes sample each probe layer a single time;
+    grouped, buf)`` **evaluation job** runs once over its draw — fig8's six
+    buffer sizes sample each probe layer a single time;
   * both buffer predicates (hard-partitioned and soft) are evaluated on the
     **same** samples in one vectorized pass — jax on accelerators, numpy on
     CPU (``REPRO_FLEXION_BACKEND=numpy|jax`` forces a backend);
@@ -186,13 +186,15 @@ def _draw_tiles(dims: np.ndarray, rng: np.random.Generator, n: int,
     return t
 
 
-def _pair_fractions(t, stride, depthwise, buf, xp):
+def _pair_fractions(t, stride, depthwise, buf, xp, grouped=None):
     """Soft and hard buffer-fit fractions of each row's samples, (J,) each.
 
     ``t`` (J, 6, N) dim-major tile draws (each ``t[:, dim]`` slice is
-    contiguous); ``stride`` / ``depthwise`` / ``buf`` (J,).  Both predicates
-    are evaluated on the SAME samples: per draw, the hard predicate implies
-    the soft one, which is what keeps the PartFlex H-F ratio inside [0, 1].
+    contiguous); ``stride`` / ``depthwise`` / ``buf`` (J,); ``grouped``
+    (J,), or None when no row is grouped: a grouped layer's weight tile
+    also spans t_X.  Both predicates are evaluated on the SAME samples: per
+    draw, the hard predicate implies the soft one, which is what keeps the
+    PartFlex H-F ratio inside [0, 1].
     """
     stride_b = stride[:, None]
     dw_b = depthwise[:, None]
@@ -202,6 +204,8 @@ def _pair_fractions(t, stride, depthwise, buf, xp):
     vol_in = t[:, C] * in_y * in_x
     k_eff = xp.where(dw_b, xp.ones_like(t[:, K]), t[:, K])
     vol_w = k_eff * t[:, C] * t[:, R] * t[:, S]
+    if grouped is not None:
+        vol_w = xp.where(grouped[:, None], vol_w * t[:, X], vol_w)
     c_out = xp.where(dw_b, t[:, C], t[:, K])
     vol_out = c_out * t[:, Y] * t[:, X]
     soft = (vol_in + vol_w + vol_out) <= buf_b
@@ -223,17 +227,17 @@ def _jax_eval():
             if _JAX_EVAL is None:
                 import jax
                 import jax.numpy as jnp
-                def fractions(t, s, d, b):
+                def fractions(t, s, d, b, g=None):
                     with jax.named_scope("flexion_fractions"):
-                        return _pair_fractions(t, s, d, b, jnp)
+                        return _pair_fractions(t, s, d, b, jnp, g)
 
                 _JAX_EVAL = jax.jit(fractions)
     return _JAX_EVAL
 
 
 def _eval_jobs(t: np.ndarray, draw_idx: np.ndarray, stride: np.ndarray,
-               depthwise: np.ndarray, buf: np.ndarray, chunk: int = 0,
-               pool=None) -> Tuple[np.ndarray, np.ndarray]:
+               depthwise: np.ndarray, grouped: np.ndarray, buf: np.ndarray,
+               chunk: int = 0, pool=None) -> Tuple[np.ndarray, np.ndarray]:
     """Evaluate each job's predicates over its draw slice of the stacked
     (D, 6, N) sample tensor (``draw_idx`` maps jobs to draws).  ``chunk``
     indexes the caller's chunk loop: on the jax backend, with a ``pool``
@@ -255,9 +259,12 @@ def _eval_jobs(t: np.ndarray, draw_idx: np.ndarray, stride: np.ndarray,
             stride = np.concatenate([stride, np.ones(jp - j, stride.dtype)])
             depthwise = np.concatenate([depthwise,
                                         np.zeros(jp - j, depthwise.dtype)])
+            grouped = np.concatenate([grouped,
+                                      np.zeros(jp - j, grouped.dtype)])
             buf = np.concatenate([buf, np.ones(jp - j, buf.dtype)])
         args = (np.asarray(tj, np.float32), np.asarray(stride, np.float32),
-                np.asarray(depthwise), np.asarray(buf, np.float32))
+                np.asarray(depthwise), np.asarray(buf, np.float32),
+                np.asarray(grouped) if grouped.any() else None)
         if pool is not None:
             args = pool.place(args, chunk)
         soft, hard = _jax_eval()(*args)
@@ -270,10 +277,12 @@ def _eval_jobs(t: np.ndarray, draw_idx: np.ndarray, stride: np.ndarray,
     soft = np.empty(j, np.float64)
     hard = np.empty(j, np.float64)
     dw = depthwise.astype(bool)
+    gr = grouped.astype(bool)
     for i in range(j):
         d = draw_idx[i]
         s_i, h_i = _pair_fractions(t[d:d + 1], stride[i:i + 1], dw[i:i + 1],
-                                   buf[i:i + 1], np)
+                                   buf[i:i + 1], np,
+                                   gr[i:i + 1] if gr[i] else None)
         soft[i], hard[i] = s_i[0], h_i[0]
     return soft, hard
 
@@ -285,9 +294,10 @@ class _Jobs:
     ``(dims, seed)`` sample stream (shared by every buffer size and stride
     that samples the same domain — e.g. fig8's six HWConfigs draw each probe
     layer once); an **evaluation job** is one
-    ``(draw, stride, depthwise, buf)`` predicate pass over a draw.  Rows
-    that share all of it (every flex level of a spec on a layer, a whole
-    INFLEX sweep needing only the C_X reference) share one job.
+    ``(draw, stride, depthwise, grouped, buf)`` predicate pass over a
+    draw.  Rows that share all of it (every flex level of a spec on a
+    layer, a whole INFLEX sweep needing only the C_X reference) share one
+    job.
     """
 
     def __init__(self, n: int):
@@ -299,22 +309,24 @@ class _Jobs:
         self.draw_id: List[int] = []
         self.stride: List[int] = []
         self.depthwise: List[bool] = []
+        self.grouped: List[bool] = []
         self.buf: List[float] = []
 
     def add(self, dims: np.ndarray, stride: int, depthwise: bool,
-            buf: float, seed: int) -> int:
+            buf: float, seed: int, grouped: bool = False) -> int:
         dkey = (tuple(int(d) for d in dims), int(seed))
         if dkey not in self._draw_index:
             self._draw_index[dkey] = len(self.draw_dims)
             self.draw_dims.append(np.asarray(dims, np.int64))
             self.draw_seed.append(int(seed))
         di = self._draw_index[dkey]
-        ekey = (di, int(stride), bool(depthwise), float(buf))
+        ekey = (di, int(stride), bool(depthwise), bool(grouped), float(buf))
         if ekey not in self._eval_index:
             self._eval_index[ekey] = len(self.draw_id)
             self.draw_id.append(di)
             self.stride.append(int(stride))
             self.depthwise.append(bool(depthwise))
+            self.grouped.append(bool(grouped))
             self.buf.append(float(buf))
         return self._eval_index[ekey]
 
@@ -372,6 +384,7 @@ class _Jobs:
                                np.int64),
                     np.asarray([self.stride[i] for i in sel], np.float64),
                     np.asarray([self.depthwise[i] for i in sel]),
+                    np.asarray([self.grouped[i] for i in sel]),
                     np.asarray([self.buf[i] for i in sel], np.float64),
                     chunk=ci, pool=pool)
             queue.push(sel, soft, hard)
@@ -415,9 +428,11 @@ def _campaign(rows: Sequence[Tuple[FlexSpec, Optional[Layer], int,
             ref_jobs.append(jobs.add(agn, 1, False,
                                      float(hw.buffer_elems), ref_seed))
         if layer is not None and spec.tile.flex != INFLEX:
-            wl_jobs.append(jobs.add(layer.as_array(), layer.stride,
-                                    layer.depthwise,
-                                    float(hw.buffer_elems), wseed))
+            # the layer's tile space: a ragged layer's at its largest group
+            wl_jobs.append(jobs.add(np.asarray(layer.tile_dims, np.int64),
+                                    layer.stride, layer.depthwise,
+                                    float(hw.buffer_elems), wseed,
+                                    layer.grouped))
         else:
             wl_jobs.append(None)
 
@@ -466,7 +481,7 @@ def _campaign(rows: Sequence[Tuple[FlexSpec, Optional[Layer], int,
             # A supports exactly 1 tile point.
             hf["T"] = 1.0 / max(ref_soft * _agnostic_volume(), 1.0)
             if layer is not None:
-                wf["T"] = 1.0 / float(np.prod(np.asarray(layer.dims,
+                wf["T"] = 1.0 / float(np.prod(np.asarray(layer.tile_dims,
                                                          np.float64)))
             else:
                 wf["T"] = hf["T"]

@@ -103,6 +103,24 @@ def matmul_workload(m: int, n: int, k: int) -> KernelWorkload:
     return KernelWorkload("matmul", (m, n, k))
 
 
+def workload_for_layer(layer: Layer) -> KernelWorkload:
+    """The matmul kernel of a plain GEMM layer ``(M, Kg, N, 1, 1, 1)``.
+    Any other layer is refused: a grouped or ragged GEMM lowered as one
+    plain GEMM would share one weight operand across its groups (and a
+    ragged one would pad every group to the largest), which is not the
+    layer the cost model ranked; convolutions have no kernel here."""
+    k, c, y, x, r, s = layer.dims
+    if layer.kind in ("grouped", "ragged"):
+        raise ValueError(
+            f"layer {layer.name!r} is {layer.kind}: its groups each have "
+            f"their own weights, and no kernel here lowers a {layer.kind} "
+            f"GEMM (lowering it as one plain GEMM would share them)")
+    if layer.kind != "plain" or (x, r, s) != (1, 1, 1):
+        raise ValueError(f"layer {layer.name!r} is not a plain GEMM "
+                         f"(dims {layer.dims}, {layer.kind})")
+    return matmul_workload(k, y, c)
+
+
 def attention_workload(heads: int, seq: int, head_dim: int
                        ) -> KernelWorkload:
     return KernelWorkload("attention", (heads, seq, head_dim))

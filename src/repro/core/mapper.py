@@ -36,12 +36,13 @@ import numpy as np
 from repro.dist.pool import InFlightQueue, parse_device_spec
 
 from . import device_pool, ga_ops, tracing
-from .cost_model import (CostResult, evaluate_mapping_impl,
+from .cost_model import (CostResult, evaluate_kinds_impl,
                          evaluate_population, evaluate_rows)
-from .engine import ROW_BUCKET, EngineRow, _bucket, run_batched_ga
+from .engine import (ROW_BUCKET, EngineRow, _bucket, pack_chunks,
+                     run_batched_ga)
 from .mapspace import Mapping, MapSpace, mapspace_for
 from .spec import FlexSpec
-from .workloads import Layer, NUM_DIMS, layers_as_array
+from .workloads import Layer, NUM_DIMS, group_table, layers_as_array
 
 ENGINES = ("batched", "serial")
 
@@ -189,6 +190,9 @@ def _search_serial(layer: Layer, spec: FlexSpec, cfg: GAConfig
     # native-pinned R runs the pre-R cost program (bit parity with v4)
     r_live = (len(space.repr_table) > 1
               or int(space.repr_table[0]) != 8 * spec.hw.bytes_per_elem)
+    grouped, groups = _kind_args([layer])
+    grouped = None if grouped is None else grouped[0]
+    groups = None if groups is None else (groups[0][0], groups[1][0])
 
     best_hist: List[float] = []
     best_g: Optional[np.ndarray] = None
@@ -201,7 +205,7 @@ def _search_serial(layer: Layer, spec: FlexSpec, cfg: GAConfig
             dims, stride, dw, jnp.asarray(tiles), jnp.asarray(orders),
             jnp.asarray(pairs), jnp.asarray(shapes), spec.hw,
             space.hard_partition,
-            jnp.asarray(reprs) if r_live else None)
+            jnp.asarray(reprs) if r_live else None, grouped, groups)
         obj = _objective_values(res, cfg.objective)
         order_idx = np.argsort(obj, kind="stable")
         if obj[order_idx[0]] < best_obj:
@@ -259,9 +263,28 @@ class ModelResult:
 
 
 def _dedup_key(layer: Layer) -> tuple:
-    """The spec-relevant layer fields — exactly what the cost model reads.
-    Layer *names* (and any future metadata) must never enter this key."""
-    return (layer.dims, layer.stride, layer.depthwise)
+    """The spec-relevant layer fields — exactly what the cost model reads:
+    dims, stride, kind and a ragged layer's group rows.  Layer *names* (and
+    any future metadata) must never enter this key."""
+    return (layer.dims, layer.stride, layer.depthwise, layer.kind,
+            layer.group_rows)
+
+
+def _kind_args(layers: Sequence[Layer], n_rows: Optional[int] = None):
+    """``(grouped, groups)`` for a batch of ``layers`` padded to ``n_rows``
+    rows: the grouped flags, or None when no layer is grouped, and the
+    ``(group_dims, group_live)`` table of the ragged program variant, or
+    None when no layer is ragged — None keeps the program the plain kinds
+    ran before."""
+    n_rows = len(layers) if n_rows is None else n_rows
+    grouped = None
+    if any(l.grouped for l in layers):
+        grouped = np.zeros(n_rows, np.bool_)
+        grouped[:len(layers)] = [l.grouped for l in layers]
+    groups = None
+    if any(l.ragged for l in layers):
+        groups = group_table(layers, n_rows)
+    return grouped, groups
 
 
 def plan_model_rows(layers: Sequence[Layer], dedup: bool = True
@@ -322,7 +345,7 @@ def search_model(layers: Sequence[Layer], spec: FlexSpec,
 
     Dedup cache: identical layer *shapes* share one search — ResNet-style
     nets repeat blocks heavily.  The cache key is :func:`_dedup_key`, i.e.
-    only the spec-relevant fields ``(dims, stride, depthwise)``; layer names
+    only the spec-relevant fields (dims, stride, kind); layer names
     are deliberately excluded, so two differently-named layers with equal
     shapes resolve to the same (shared) MapperResult object.  Per-layer GA
     seeds derive from the *first occurrence* index (``seed + 1000*i``), so
@@ -435,6 +458,13 @@ def _inert_mapping_rows(shape: Tuple[int, ...], native_bits: int = 8
     return tiles, orders, pairs, shapes, reprs
 
 
+def _unpack(values: np.ndarray, packed: Sequence[int]) -> np.ndarray:
+    """Undo a packing: ``values[k]`` belongs to row ``packed[k]``."""
+    out = np.empty_like(values)
+    out[np.asarray(packed, np.int64)] = values
+    return out
+
+
 def evaluate_fixed_genome_many(
         requests: Sequence[Tuple[Sequence[Layer], FlexSpec, np.ndarray]]
         ) -> List[ModelResult]:
@@ -460,6 +490,7 @@ def evaluate_fixed_genome_many(
         "replay requests must share an HWConfig"
 
     row_data = []          # per-row decoded arrays
+    row_layers: List[Layer] = []
     mappings = []
     bounds: List[Tuple[int, int]] = []
     for layers, spec, genome in reqs:
@@ -471,6 +502,7 @@ def evaluate_fixed_genome_many(
             row_data.append((space.dims, layer.stride, layer.depthwise,
                              t[0], o[0], p[0], s[0], space.hard_partition,
                              r[0]))
+            row_layers.append(layer)
             mappings.append(space.decode(g[0]))
         bounds.append((start, len(row_data)))
 
@@ -486,8 +518,10 @@ def evaluate_fixed_genome_many(
     # stays at ~pool-depth chunks however large the replay is
     queue = InFlightQueue(depth=len(pool) if pool else 1,
                           collect=_materialize)
-    for ci, c0 in enumerate(range(0, len(row_data), ROW_BUCKET)):
-        chunk = row_data[c0:c0 + ROW_BUCKET]
+    # ragged rows replay in chunks of their own, through the ragged variant
+    chunk_pos = pack_chunks(row_layers)
+    for ci, pos in enumerate(chunk_pos):
+        chunk = [row_data[i] for i in pos]
         n_pad = ROW_BUCKET
         dims = np.ones((n_pad, 6), np.int32)
         stride = np.ones(n_pad, np.int32)
@@ -501,17 +535,21 @@ def evaluate_fixed_genome_many(
             reprs[i] = r
         # all-native chunks replay through the pre-R program (v4 bit parity)
         r_live = bool((reprs != 8 * hw.bytes_per_elem).any())
-        args = (dims, stride, dw, tiles, orders, pairs, shapes, hp, reprs)
+        grouped, groups = _kind_args([row_layers[i] for i in pos], n_pad)
+        args = (dims, stride, dw, tiles, orders, pairs, shapes, hp, reprs,
+                grouped, groups)
         if pool is not None:
             args = pool.place(args, ci)
         queue.push(len(chunk),
                    evaluate_rows(*args[:8], hw,
-                                 args[8] if r_live else None))
+                                 args[8] if r_live else None, *args[9:]))
     queue.drain()
 
     out: List[ModelResult] = []
     if pieces:
-        res = CostResult(*(np.concatenate([p[f] for p in pieces])
+        packed = [i for pos in chunk_pos for i in pos]
+        res = CostResult(*(_unpack(np.concatenate([p[f] for p in pieces]),
+                                   packed)
                            for f in range(len(CostResult._fields))))
     for (start, end), _req in zip(bounds, reqs):
         per_layer = [MapperResult(
@@ -549,24 +587,23 @@ def raw_tile_feasibility(tiles: jnp.ndarray,
 
 def _fixed_config_objective_impl(dims, strides, dws, mask, tiles, orders,
                                  pairs, shapes, reprs, hw,
-                                 hard_partition: bool, objective: str):
+                                 hard_partition: bool, objective: str,
+                                 grouped=None, groups=None):
     """Whole-model objective of one shared mapping population — layer sweep,
     buffer-feasibility penalty and reduction all inside one jit (the serial
-    version round-tripped raw tiles through host numpy every generation)."""
+    version round-tripped raw tiles through host numpy every generation).
+    ``grouped`` / ``groups``: the layers' kinds, as ``_kind_args`` gives
+    them."""
 
-    def per_layer(d, s, w):
-        if reprs is None:       # native-pinned: pre-R program (v4 parity)
-            def per_mapping(t1, o1, p1, s1):
-                return evaluate_mapping_impl(d, s, w, t1, o1, p1, s1, hw,
-                                             hard_partition)
-            return jax.vmap(per_mapping)(tiles, orders, pairs, shapes)
-
+    def per_layer(d, s, w, g, gr):
+        # reprs None (native-pinned R) traces the pre-R program (v4 parity)
         def per_mapping(t1, o1, p1, s1, r1):
-            return evaluate_mapping_impl(d, s, w, t1, o1, p1, s1, hw,
-                                         hard_partition, r1)
+            return evaluate_kinds_impl(d, s, w, t1, o1, p1, s1, hw,
+                                       hard_partition, r1, g, gr)
         return jax.vmap(per_mapping)(tiles, orders, pairs, shapes, reprs)
 
-    res = jax.vmap(per_layer)(dims, strides, dws)        # (L, P) fields
+    res = jax.vmap(per_layer)(dims, strides, dws, grouped,
+                              groups)                    # (L, P) fields
     m = mask[:, None].astype(jnp.float32)
     runtime = jnp.sum(res.runtime * m, axis=0)
     energy = jnp.sum(res.energy * m, axis=0)
@@ -581,7 +618,7 @@ def _fixed_config_objective_impl(dims, strides, dws, mask, tiles, orders,
 @partial(jax.jit, static_argnames=("hw", "hard_partition", "objective"))
 def _fixed_configs_objective(dims, strides, dws, mask, tiles, orders, pairs,
                              shapes, reprs, hw, hard_partition: bool,
-                             objective: str):
+                             objective: str, grouped=None, groups=None):
     """Model-stacked fixed-config objective: every array gains a leading
     model axis (one genome tensor per shape bucket), so a whole campaign of
     InFlex-0000-X-Opt designs evaluates in ONE dispatch per generation.
@@ -590,13 +627,13 @@ def _fixed_configs_objective(dims, strides, dws, mask, tiles, orders, pairs,
     bit-identical to a per-model dispatch of that body (and results are
     independent of how many models share the stack)."""
 
-    def one(d, s, w, m, t, o, p, sh, r):
+    def one(d, s, w, m, t, o, p, sh, r, g, gr):
         return _fixed_config_objective_impl(d, s, w, m, t, o, p, sh, r, hw,
-                                            hard_partition, objective)
+                                            hard_partition, objective, g, gr)
 
     with jax.named_scope("fixed_configs_objective"):
         return jax.vmap(one)(dims, strides, dws, mask, tiles, orders, pairs,
-                             shapes, reprs)
+                             shapes, reprs, grouped, groups)
 
 
 @dataclasses.dataclass
@@ -613,6 +650,8 @@ class _FixedConfigState:
     dws: np.ndarray
     mask: np.ndarray
     pop: np.ndarray
+    grouped: Optional[np.ndarray]
+    groups: Optional[Tuple[np.ndarray, np.ndarray]]
     best_obj: float = np.inf
     best_g: Optional[np.ndarray] = None
 
@@ -640,9 +679,11 @@ def _fixed_config_state(layers: Sequence[Layer], spec: FlexSpec,
     mask = np.zeros(n_pad, np.bool_)
     mask[:n] = True
     pop = space.sample(rng, cfg.population)
+    grouped, groups = _kind_args(layers, n_pad)
     return _FixedConfigState(layers=list(layers), spec=spec, space=space,
                              ops=ops, rng=rng, dims=dims, strides=strides,
-                             dws=dws, mask=mask, pop=pop)
+                             dws=dws, mask=mask, pop=pop, grouped=grouped,
+                             groups=groups)
 
 
 def search_fixed_configs(
@@ -653,9 +694,11 @@ def search_fixed_configs(
     row as one campaign).
 
     Models are grouped into shape buckets — same padded layer count, same
-    hard-partition flag — and each bucket's populations are stacked into one
-    (M, P, 10) genome tensor: each generation is ONE ``_fixed_configs_objective``
-    dispatch for the whole bucket instead of one per model.  Selection,
+    hard-partition flag, same program variant (plain, grouped, or ragged
+    with the same group axis) — and each bucket's populations are stacked
+    into one (M, P, 10) genome tensor: each generation is ONE
+    ``_fixed_configs_objective`` dispatch for the whole bucket instead of
+    one per model.  Selection,
     crossover and mutation stay host-side per model with each model's own
     Generator (seeded ``cfg.seed``, the single-model convention), so every
     model's genome trajectory — and therefore the returned design — is
@@ -673,10 +716,12 @@ def search_fixed_configs(
     n_children = cfg.population - n_elite
     groups: Dict[tuple, List[_FixedConfigState]] = {}
     for st in states:
-        key = (st.dims.shape[0], st.space.hard_partition)
+        key = (st.dims.shape[0], st.space.hard_partition,
+               st.grouped is not None,
+               None if st.groups is None else st.groups[0].shape)
         groups.setdefault(key, []).append(st)
 
-    for (n_pad, hard), group in groups.items():
+    for (n_pad, hard, with_grouped, group_shape), group in groups.items():
         # the model axis is padded to a power of two so any campaign size
         # (1 model .. the full fig13 sweep) reuses a few compiled shapes;
         # pad slots hold inert feasible rows with an all-zero layer mask
@@ -690,6 +735,17 @@ def search_fixed_configs(
         strides_b[:m] = [s.strides for s in group]
         dws_b[:m] = [s.dws for s in group]
         mask_b[:m] = [s.mask for s in group]
+        grouped_b = groups_b = None
+        if with_grouped:
+            grouped_b = np.zeros((m_pad, n_pad), np.bool_)
+            grouped_b[:m] = [s.grouped for s in group]
+        if group_shape is not None:
+            gd_b = np.ones((m_pad,) + group_shape, np.int32)
+            gl_b = np.zeros((m_pad,) + group_shape[:2], np.bool_)
+            gl_b[:, :, 0] = True
+            gd_b[:m] = [s.groups[0] for s in group]
+            gl_b[:m] = [s.groups[1] for s in group]
+            groups_b = (gd_b, gl_b)
         tiles_b, orders_b, pairs_b, shapes_b, reprs_b = _inert_mapping_rows(
             (m_pad, cfg.population), 8 * hw.bytes_per_elem)
         for _ in range(cfg.generations):
@@ -704,7 +760,8 @@ def search_fixed_configs(
                     jnp.asarray(tiles_b), jnp.asarray(orders_b),
                     jnp.asarray(pairs_b), jnp.asarray(shapes_b),
                     jnp.asarray(reprs_b) if r_live else None,
-                    hw=hw, hard_partition=hard, objective=cfg.objective))
+                    hw=hw, hard_partition=hard, objective=cfg.objective,
+                    grouped=grouped_b, groups=groups_b))
             with tracing.span("design.breed"):
                 for s, obj in zip(group, obj_b):
                     order_idx = np.argsort(obj, kind="stable")

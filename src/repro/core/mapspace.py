@@ -86,14 +86,20 @@ class MapSpace:
         self.shape_table = _shape_table(spec.shape, spec.hw.num_pes)
         self.repr_table = _repr_table(spec.representation,
                                       8 * spec.hw.bytes_per_elem)
+        # tile genes range over the layer's tile dims: its dims, except a
+        # ragged layer's, which runs one group (t_X = 1) of at most its
+        # largest group's rows (t_Y) at a time.  Any parallel pair stays
+        # legal: a grouped layer's weight depends on X, so the cost model
+        # multicasts no weight across groups on a parallel X.
+        tile_dims = np.asarray(layer.tile_dims, dtype=np.int32)
         if spec.tile.flex == INFLEX:
             fixed = np.minimum(np.asarray(spec.tile.fixed_tile, np.int32),
-                               self.dims)
+                               tile_dims)
             self.tile_lo = fixed.copy()
             self.tile_hi = fixed.copy()
         else:
             self.tile_lo = np.ones(NUM_DIMS, np.int32)
-            self.tile_hi = self.dims.copy()
+            self.tile_hi = tile_dims
         self.hard_partition = spec.tile.flex == "part"
 
     # -- encode / decode ----------------------------------------------------

@@ -11,6 +11,11 @@ normalized to the 6-dim CONV loop nest (K, C, Y, X, R, S):
 GEMM (M, N, Kg) maps to (K=M, C=Kg, Y=N, X=1, R=1, S=1), matching the paper's
 Sec 7 observation that BERT's (M,N,K) land on (K_conv, C, Y).  Depthwise conv
 is expressed with K=1 per the paper's Layer-29 example "(1, 480, 14, 14, 5, 5)".
+
+Two more kinds hold what a GEMM cannot (docs/mapper.md "Layer kinds"): a
+*grouped* GEMM puts G independent GEMMs on X, each with its own weights (a
+head's projection, a sequence's KV cache), and a *ragged* one is grouped
+with its own row count Y per group (routed experts with uneven load).
 """
 from __future__ import annotations
 
@@ -26,20 +31,69 @@ K, C, Y, X, R, S = range(NUM_DIMS)
 
 @dataclasses.dataclass(frozen=True)
 class Layer:
-    """One DNN layer as a 6-dim loop nest (paper Fig 1)."""
+    """One DNN layer as a 6-dim loop nest (paper Fig 1).
+
+    ``grouped``: X counts groups whose weight operand differs per group (the
+    weight depends on X).  ``group_rows``: a ragged layer's rows (Y) per
+    group; Y is then the largest group's and X the number of groups."""
 
     name: str
     dims: Tuple[int, int, int, int, int, int]  # (K, C, Y, X, R, S)
     stride: int = 1
     depthwise: bool = False
+    grouped: bool = False
+    group_rows: Tuple[int, ...] = ()
+
+    def __post_init__(self):
+        if self.grouped and self.depthwise:
+            raise ValueError(f"{self.name}: a layer is grouped or depthwise")
+        if self.group_rows:
+            rows = self.group_rows
+            if (not self.grouped or len(rows) != self.dims[X]
+                    or min(rows) < 0 or max(rows) != self.dims[Y]):
+                raise ValueError(
+                    f"{self.name}: a ragged layer is grouped, with one row "
+                    f"count per group (X={self.dims[X]}) and Y their "
+                    f"largest; got rows {rows}")
+
+    @property
+    def ragged(self) -> bool:
+        return bool(self.group_rows)
+
+    @property
+    def kind(self) -> str:
+        """``plain``, ``depthwise``, ``grouped`` or ``ragged``."""
+        if self.ragged:
+            return "ragged"
+        if self.grouped:
+            return "grouped"
+        return "depthwise" if self.depthwise else "plain"
 
     @property
     def macs(self) -> int:
         k, c, y, x, r, s = self.dims
+        if self.ragged:
+            return k * c * sum(self.group_rows) * r * s
         if self.depthwise:
             # K==1 in the paper's notation: one output channel per input channel.
             return c * y * x * r * s
         return k * c * y * x * r * s
+
+    @property
+    def tile_dims(self) -> Tuple[int, ...]:
+        """Upper bound of each tile gene: the dims, except that a ragged
+        layer runs one group at a time (t_X = 1)."""
+        if self.ragged:
+            return self.dims[:X] + (1,) + self.dims[X + 1:]
+        return self.dims
+
+    def group_dims(self) -> Tuple[Tuple[int, ...], ...]:
+        """The nests whose costs sum to the layer's: a ragged layer's groups
+        ``(K, C, n_g, 1, R, S)`` with rows, any other layer itself."""
+        if not self.ragged:
+            return (self.dims,)
+        k, c, _, _, r, s = self.dims
+        return tuple((k, c, n, 1, r, s) for n in self.group_rows if n > 0)
 
     def dim(self, i: int) -> int:
         return self.dims[i]
@@ -62,6 +116,20 @@ def dwconv(name: str, c: int, y: int, x: int, r: int, s: int,
 def gemm(name: str, m: int, n: int, kg: int) -> Layer:
     """GEMM (M,N,K) -> CONV (K=M, C=Kg, Y=N, X=1, R=1, S=1)."""
     return Layer(name, (m, kg, n, 1, 1, 1))
+
+
+def grouped_gemm(name: str, g: int, m: int, n: int, kg: int) -> Layer:
+    """G independent GEMMs (M,N,K), each with its own weights -> (K=M, C=Kg,
+    Y=N, X=G, R=1, S=1), weight dependent on X."""
+    return Layer(name, (m, kg, n, g, 1, 1), grouped=True)
+
+
+def ragged_gemm(name: str, m: int, rows: Sequence[int], kg: int) -> Layer:
+    """One GEMM (M, rows[g], Kg) per group under one mapping -> (K=M, C=Kg,
+    Y=max rows, X=G, R=1, S=1) with the per-group rows."""
+    rows = tuple(int(n) for n in rows)
+    return Layer(name, (m, kg, max(rows), len(rows), 1, 1), grouped=True,
+                 group_rows=rows)
 
 
 # --------------------------------------------------------------------------
@@ -227,6 +295,98 @@ def ncf() -> List[Layer]:
             for i in range(len(widths) - 1)]
 
 
+# Kimi-K2-Instruct (moonshotai/Kimi-K2-Instruct config.json): the published
+# widths the decode step below is built from.
+KIMI_K2 = dict(hidden_size=7168, intermediate_size=18432,
+               num_attention_heads=64, q_lora_rank=1536, kv_lora_rank=512,
+               qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+               n_routed_experts=384, moe_intermediate_size=2048,
+               n_shared_experts=1, first_k_dense_replace=1)
+
+# Tokens each held expert receives in a decode step, per MoE layer kept
+# (1..4), on device 0: ``expert_loads(layer, 0)`` below, written out.
+KIMI_K2_EXPERT_LOADS = (
+    (63, 379, 172, 67, 138, 65, 86, 54),
+    (57, 61, 43, 128, 96, 91, 357, 191),
+    (58, 42, 121, 198, 94, 67, 85, 359),
+    (202, 130, 371, 55, 67, 50, 90, 59),
+)
+
+
+def expert_loads(layer: int, device: int, experts: int = 8,
+                 tokens: int = 1024, s: float = 1.0) -> Tuple[int, ...]:
+    """How the loads were drawn: the ``tokens`` (experts x 128) routed
+    assignments a device's ``experts`` receive in one decode step, split
+    by a multinomial draw over Zipf(``s``) popularity, ranked by a
+    permutation seeded by ``(layer, device)``.  Every assignment lands on
+    an expert: none is dropped."""
+    rng = np.random.default_rng([layer, device])
+    rank = rng.permutation(experts) + 1.0
+    p = rank ** -s
+    return tuple(int(v) for v in rng.multinomial(tokens, p / p.sum()))
+
+
+def kimi_k2_decode(batch: int = 128, cache: int = 32768) -> List[Layer]:
+    """Kimi-K2-Instruct's decode step on one device of a 48-way expert-
+    parallel deployment: ``batch`` sequences, each attending over its own
+    ``cache``-token latent cache, through layer 0 (dense FFN) and 4 MoE
+    layers, at the published widths (``KIMI_K2``).
+
+    Attention is MLA on the absorbed decode path (DeepSeek-V2 Sec 2.1):
+    W_UK is absorbed into the query (``q_absorb``, per head) and W_UV into
+    the output (``v_absorb``, per head); ``scores`` multiply each sequence's
+    64 x 576 ``[q_abs; q_rope]`` against its own cache (576 = kv_lora_rank
+    + rope dim) and ``context`` the probabilities against the cache's
+    512-wide latent part.  Those four are grouped GEMMs: per head (64) or
+    per sequence (128), no group reusing another's weights or cache.
+    Attention is data-parallel over the device's sequences.
+
+    The MoE layers hold 8 of the 384 routed experts (48 devices x 8); a
+    step routes 48 x ``batch`` tokens x top-8 over them, ``batch`` tokens
+    per expert on average, with the uneven loads ``KIMI_K2_EXPERT_LOADS``
+    (ragged GEMMs under one mapping).  The router and the shared expert
+    run on the device's own ``batch`` tokens.
+
+    Left out, as the other zoo models leave them out: the embedding and the
+    output head (on the first and last pipeline stage; the other 56 layers
+    would lie on further stages), and softmax, norms, RoPE, SiLU and top-k
+    selection (no MACs)."""
+    w = KIMI_K2
+    d, heads = w["hidden_size"], w["num_attention_heads"]
+    nope, rope, vd = (w["qk_nope_head_dim"], w["qk_rope_head_dim"],
+                      w["v_head_dim"])
+    kv, qr = w["kv_lora_rank"], w["q_lora_rank"]
+    layers: List[Layer] = []
+    for i in range(1 + len(KIMI_K2_EXPERT_LOADS)):
+        p = f"L{i}."
+        layers += [
+            gemm(p + "q_a", qr, batch, d),
+            gemm(p + "q_b", heads * (nope + rope), batch, qr),
+            gemm(p + "kv_a", kv + rope, batch, d),
+            grouped_gemm(p + "q_absorb", heads, kv, batch, nope),
+            grouped_gemm(p + "scores", batch, cache, heads, kv + rope),
+            grouped_gemm(p + "context", batch, kv, heads, cache),
+            grouped_gemm(p + "v_absorb", heads, vd, batch, kv),
+            gemm(p + "o", d, batch, heads * vd),
+        ]
+        if i < w["first_k_dense_replace"]:
+            layers += [gemm(p + "gate_up", 2 * w["intermediate_size"],
+                            batch, d),
+                       gemm(p + "down", d, batch, w["intermediate_size"])]
+            continue
+        ffn = w["moe_intermediate_size"]
+        loads = KIMI_K2_EXPERT_LOADS[i - 1]
+        layers += [
+            gemm(p + "router", w["n_routed_experts"], batch, d),
+            gemm(p + "shared.gate_up", 2 * ffn * w["n_shared_experts"],
+                 batch, d),
+            gemm(p + "shared.down", d, batch, ffn * w["n_shared_experts"]),
+            ragged_gemm(p + "experts.gate_up", 2 * ffn, loads, d),
+            ragged_gemm(p + "experts.down", d, loads, ffn),
+        ]
+    return layers
+
+
 MODEL_ZOO = {
     "alexnet": alexnet,
     "resnet50": resnet50,
@@ -235,6 +395,7 @@ MODEL_ZOO = {
     "bert": bert_base,
     "dlrm": dlrm,
     "ncf": ncf,
+    "kimi-k2-decode32k": kimi_k2_decode,
 }
 
 
@@ -245,3 +406,22 @@ def get_model(name: str) -> List[Layer]:
 def layers_as_array(layers: Sequence[Layer]) -> np.ndarray:
     """(L, 6) int64 dim matrix for vectorized cost evaluation."""
     return np.stack([l.as_array() for l in layers])
+
+
+def group_table(layers: Sequence[Layer], n_rows: int
+                ) -> Tuple[np.ndarray, np.ndarray]:
+    """Group nests of ``layers`` padded to ``n_rows`` rows and a bucketed
+    group axis: ``(n_rows, G, 6)`` int32 dims and ``(n_rows, G)`` bool live
+    mask, row ``i`` holding ``layers[i].group_dims()``.  Padding rows hold
+    one unit group, like the inert rows they pad."""
+    groups = [layer.group_dims() for layer in layers]
+    g_pad = 8
+    while g_pad < max((len(g) for g in groups), default=1):
+        g_pad *= 2
+    dims = np.ones((n_rows, g_pad, NUM_DIMS), np.int32)
+    live = np.zeros((n_rows, g_pad), np.bool_)
+    live[:, 0] = True
+    for i, g in enumerate(groups):
+        dims[i, :len(g)] = g
+        live[i, :len(g)] = True
+    return dims, live

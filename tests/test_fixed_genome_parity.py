@@ -15,6 +15,7 @@ from repro.core import (FULLFLEX, MODEL_ZOO, PARTFLEX, evaluate_fixed_genome,
                         evaluate_fixed_genome_many, evaluate_mapping,
                         get_model, make_variant)
 from repro.core.mapspace import mapspace_for
+from repro.core.workloads import group_table
 
 # raw genome: baseline-ish tiles + arbitrary (mod-table) O/P/S/R indices
 GENOME = np.asarray([64, 16, 3, 3, 3, 3, 5, 7, 11, 0], np.int32)
@@ -36,13 +37,18 @@ def test_batched_replay_matches_per_layer_cost_model(model):
             assert np.array_equal(g, space.clip(GENOME_V4[None, :]))
             t, o, p, s, rbits = space.decode_batch(g)
             # native-pinned R replays through the pre-R program, so the
-            # bit-exact reference is the legacy (repr_bits=None) jit
+            # bit-exact reference is the legacy (repr_bits=None) jit; a
+            # grouped or ragged layer also passes its kind (plain and
+            # depthwise layers pass none, the pre-kinds program)
             assert rbits[0] == 8 * spec.hw.bytes_per_elem
+            gd, gl = group_table([layer], 1)
             ref = evaluate_mapping(
                 jnp.asarray(space.dims), jnp.asarray(layer.stride),
                 jnp.asarray(layer.depthwise), jnp.asarray(t[0]),
                 jnp.asarray(o[0]), jnp.asarray(p[0]), jnp.asarray(s[0]),
-                hw=spec.hw, hard_partition=space.hard_partition)
+                hw=spec.hw, hard_partition=space.hard_partition,
+                grouped=jnp.asarray(True) if layer.grouped else None,
+                groups=(gd[0], gl[0]) if layer.ragged else None)
             assert r.runtime == float(ref.runtime)
             assert r.energy == float(ref.energy)
             assert r.edp == float(ref.edp)

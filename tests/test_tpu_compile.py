@@ -163,3 +163,32 @@ def test_ga_program_compiles(one_chip, with_repr):
         *args, hw=hw, n_elite=ga_ops.n_elite(cfg), objective=cfg.objective,
         with_repr=with_repr).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 30
+
+
+@pytest.mark.parametrize("ragged", [False, True])
+def test_ga_program_kinds_compile(one_chip, ragged):
+    """The GA program of a chunk of Kimi-K2 decode rows: grouped rows (the
+    traced grouped flags) or ragged rows (the ragged program variant, its
+    group tables), at a small population."""
+    from repro.core import GAConfig, get_model
+    from repro.core import engine, ga_ops
+    from repro.core.mapper import plan_model_rows, request_rows
+
+    cfg, hw = GAConfig(population=8, generations=2), HWConfig()
+    layers = [l for l in get_model("kimi-k2-decode32k") if l.ragged == ragged]
+    row_index, _ = plan_model_rows(layers)
+    rows = request_rows(layers, make_variant("1111", hw=hw), cfg, row_index)
+    c = engine._prepare_chunk(rows, cfg, hw)
+    assert c.grouped is not None
+    assert (c.group_dims is not None) == ragged
+    program = engine._ga_program_ragged if ragged else engine._ga_program
+    tail = (c.grouped, c.group_dims, c.group_live) if ragged else (c.grouped,)
+    args = jax.tree_util.tree_map(
+        lambda a: _sds(np.shape(a), np.asarray(a).dtype, one_chip),
+        (c.dims, c.stride, c.depthwise, c.tile_lo, c.tile_hi,
+         c.hard_partition, c.table_id, c.orders, c.pairs, c.shapes, c.reprs,
+         c.lens, c.pop0, c.draws, np.int32(c.gens)) + tail)
+    compiled = program.lower(
+        *args, hw=hw, n_elite=ga_ops.n_elite(cfg), objective=cfg.objective,
+        with_repr=False).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 30
