@@ -57,6 +57,19 @@ def _ceil_div(a, b):
     return (a + b - 1) // b
 
 
+def _pick(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``x[idx]`` for a per-dimension ``(6,)`` vector ``x`` at a traced index
+    array ``idx`` of any shape, as a compare against the dimension axis and a
+    masked reduction: no gather, which the TPU lowers per index once ``vmap``
+    batches it (docs/mapper.md "Batched engine dataflow").  Exact: one term
+    of the sum is ``x[idx]`` and the other five are exact zeros (only a
+    -0.0 would read +0.0; trip counts and tile sizes are at least 1)."""
+    hit = idx[..., None] == jnp.arange(NUM_DIMS)
+    if x.dtype == jnp.bool_:
+        return jnp.any(hit & x, axis=-1)
+    return jnp.sum(jnp.where(hit, x, 0), axis=-1)
+
+
 def _reuse_multiplier(order: jnp.ndarray, trips: jnp.ndarray,
                       dep: jnp.ndarray) -> jnp.ndarray:
     """prod of trip counts of loops at-or-outside the innermost dependent loop.
@@ -65,10 +78,10 @@ def _reuse_multiplier(order: jnp.ndarray, trips: jnp.ndarray,
     trips: (6,) per-dim trip count
     dep:   (6,) per-dim bool dependency
     """
-    dep_in_order = dep[order]                       # (6,) by position
+    dep_in_order = _pick(dep, order)                # (6,) by position
     pos = jnp.arange(NUM_DIMS)
     # innermost position whose dim is relevant AND actually iterates (>1 trips)
-    trips_in_order = trips[order]
+    trips_in_order = _pick(trips, order)
     relevant = dep_in_order & (trips_in_order > 1)
     p_last = jnp.max(jnp.where(relevant, pos, -1))
     mult = jnp.prod(jnp.where(pos <= p_last, trips_in_order, 1))
@@ -80,9 +93,9 @@ def _stationary_reuse(order: jnp.ndarray, tile: jnp.ndarray,
     """Temporal reuse of a tensor inside the PE (L1) = product of tile sizes of
     loops strictly inside its innermost dependent loop, capped by register
     capacity.  This is what the O axis buys at the L2-access level."""
-    dep_in_order = dep[order]
+    dep_in_order = _pick(dep, order)
     pos = jnp.arange(NUM_DIMS)
-    tile_in_order = tile[order]
+    tile_in_order = _pick(tile, order)
     relevant = dep_in_order & (tile_in_order > 1)
     p_last = jnp.max(jnp.where(relevant, pos, -1))
     reuse = jnp.prod(jnp.where(pos > p_last, tile_in_order, 1))
@@ -164,8 +177,8 @@ def evaluate_mapping_impl(dims: jnp.ndarray, stride: jnp.ndarray,
     tile_macs = jnp.prod(t) / jnp.where(depthwise, t[K], 1.0)
     total_macs = num_tiles * tile_macs              # padded (folded) MACs
 
-    tp1 = t[par[0]]
-    tp2 = t[par[1]]
+    tp1 = _pick(t, par[0])
+    tp2 = _pick(t, par[1])
     folds = _ceil_div(tp1, rows) * _ceil_div(tp2, cols)
     serial_iters = folds * tile_macs / (tp1 * tp2)  # cycles per tile
     # throughput scales with operand width (subword SIMD / bit-serial)
@@ -187,8 +200,8 @@ def evaluate_mapping_impl(dims: jnp.ndarray, stride: jnp.ndarray,
 
     # ---- L2 traffic: spatial multicast + PE-level stationarity ------------
     def mcast(dep):
-        f1 = jnp.where(dep[par[0]], 1.0, jnp.minimum(tp1, rows))
-        f2 = jnp.where(dep[par[1]], 1.0, jnp.minimum(tp2, cols))
+        f1 = jnp.where(_pick(dep, par[0]), 1.0, jnp.minimum(tp1, rows))
+        f2 = jnp.where(_pick(dep, par[1]), 1.0, jnp.minimum(tp2, cols))
         return f1 * f2
 
     l2_in = total_macs / (mcast(dep_i) * _stationary_reuse(order, t, dep_i))

@@ -6,6 +6,7 @@ best objectives per layer — any silent cost-model or operator drift during
 the engine refactor trips these tests.
 """
 import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -13,7 +14,7 @@ import pytest
 from repro.core import (FULLFLEX, GAConfig, PARTFLEX, inflex_baseline,
                         make_variant, run_dse, search, search_model,
                         search_model_batched, search_specs_batched)
-from repro.core import engine
+from repro.core import engine, ga_ops
 from repro.core import mapper as mapper_mod
 from repro.core.engine import ROW_BUCKET, EngineRow, run_batched_ga
 from repro.core.workloads import Layer, get_model
@@ -204,3 +205,59 @@ def test_draw_error_reaches_the_chunk_handler(monkeypatch, bad_row):
     assert isinstance(err.value.__cause__, ValueError)
     assert len(queues) == 1 and len(queues[0]) == 0
     assert collected == [ROW_BUCKET] * idx
+
+
+def _evaluate_gathers(hlo: str):
+    """``(operand shape, indexed axes)`` of every gather in the ``evaluate``
+    (and ``evaluate_ragged``) scope of an HLO module's text."""
+    shapes = {name: tuple(int(d) for d in dims.split(",") if d)
+              for name, dims in re.findall(
+                  r"^\s*(?:ROOT\s+)?([\w.\-]+) = \w+\[([\d,]*)\]", hlo,
+                  re.M)}
+    out = []
+    for line in hlo.splitlines():
+        if " gather(" in line and re.search(
+                r'op_name="[^"]*/evaluate(_ragged)?/', line):
+            operand = re.search(r" gather\(([^,\s]+)", line).group(1)
+            axes = re.search(r"start_index_map=\{([\d,]*)\}", line).group(1)
+            out.append((shapes[operand],
+                        tuple(int(a) for a in axes.split(","))))
+    return out
+
+
+@pytest.mark.parametrize("kind", ["plain", "repr", "grouped", "ragged"])
+def test_evaluate_gathers_only_the_decode_tables(kind):
+    """The cost model reads its per-dimension vectors at traced indices
+    (``order``, ``par``) by compare and select: the lowered GA programs'
+    ``evaluate`` scope gathers from the chunk's decode tables and from no
+    length-6 per-dimension operand (docs/mapper.md "Batched engine
+    dataflow")."""
+    from repro.core import HWConfig
+    from repro.core.mapper import plan_model_rows, request_rows
+    cfg, hw = GAConfig(population=8, generations=2), HWConfig()
+    if kind in ("plain", "repr"):
+        layers = get_model("mnasnet")[:4]
+        spec = make_variant("11111" if kind == "repr" else "1111", hw=hw)
+    else:
+        layers = [l for l in get_model("kimi-k2-decode32k")
+                  if l.ragged == (kind == "ragged")]
+        spec = make_variant("1111", hw=hw)
+    row_index, _ = plan_model_rows(layers)
+    c = engine._prepare_chunk(request_rows(layers, spec, cfg, row_index),
+                              cfg, hw)
+    assert (c.grouped is not None) == (kind in ("grouped", "ragged"))
+    program = engine._ga_program_ragged if kind == "ragged" \
+        else engine._ga_program
+    tail = (c.group_dims, c.group_live) if kind == "ragged" else ()
+    hlo = program.lower(
+        c.dims, c.stride, c.depthwise, c.tile_lo, c.tile_hi,
+        c.hard_partition, c.table_id, c.orders, c.pairs, c.shapes, c.reprs,
+        c.lens, c.pop0, c.draws, np.int32(c.gens), c.grouped, *tail, hw=hw,
+        n_elite=ga_ops.n_elite(cfg), objective=cfg.objective,
+        with_repr=kind == "repr").as_text(dialect="hlo", debug_info=True)
+    gathers = _evaluate_gathers(hlo)
+    per_dim = [g for g in gathers if any(g[0][a] == 6 for a in g[1])]
+    assert not per_dim, per_dim
+    tables = [c.orders, c.pairs, c.shapes] + [c.reprs] * (kind == "repr")
+    assert sorted(shape for shape, _ in gathers) == \
+        sorted(t.shape for t in tables)
