@@ -71,7 +71,7 @@ def run(print_fn=print):
     n_samples = N_SAMPLES[mode]
     pop, gens = TUNE_POP_GENS[mode]
     tune_cfg = dataclasses.replace(BUDGETS[mode], population=pop,
-                                   generations=gens, engine="serial")
+                                   generations=gens)
 
     derived = {
         "parity_ok": False, "tuned_legal_ok": False,

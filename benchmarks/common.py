@@ -21,38 +21,12 @@ def bench_mode() -> str:
     return get_env("REPRO_BENCH_MODE", "default")
 
 
-def campaign_mode() -> bool:
-    """True when REPRO_CAMPAIGN is set: benches with a cross-model campaign
-    path (fig7, fig13) batch their whole sweep into one engine row set —
-    ``benchmarks.run --campaign`` runs a pass with this on."""
-    return get_env("REPRO_CAMPAIGN", "") not in ("", "0")
-
-
 def ga_budget(scale: float = 1.0) -> GAConfig:
-    """The GA budget for the current REPRO_BENCH_MODE; REPRO_ENGINE
-    (batched | serial) overrides the MSE engine, which is how
-    ``benchmarks.run --engines`` A/B-times the two engines.  Campaign mode
-    requires the batched engine and turns on chunk pipelining (host draw
-    prep overlapped with device compute).
-
-    ``REPRO_ENGINE=serial`` together with ``REPRO_CAMPAIGN=1`` is a
-    contradiction — the campaign path is batched-only, and silently forcing
-    ``engine="batched"`` (the old behavior) let an A/B run record a pass
-    labeled *serial* that actually measured the batched engine.  It now
-    raises instead of mislabeling."""
+    """The GA budget for the current REPRO_BENCH_MODE, with chunk pipelining
+    on (host draw prep overlapped with device compute) — the campaign path
+    every bench runs."""
     import dataclasses
-    base = BUDGETS[bench_mode()]
-    engine = get_env("REPRO_ENGINE")
-    if engine:
-        base = dataclasses.replace(base, engine=engine)
-    if campaign_mode():
-        if engine and engine != "batched":
-            raise RuntimeError(
-                f"REPRO_ENGINE={engine!r} conflicts with REPRO_CAMPAIGN=1: "
-                f"the campaign path is batched-only, and honoring the "
-                f"campaign flag would mislabel this pass; unset one of the "
-                f"two variables")
-        base = dataclasses.replace(base, engine="batched", pipeline=True)
+    base = dataclasses.replace(BUDGETS[bench_mode()], pipeline=True)
     if scale != 1.0:
         base = dataclasses.replace(
             base, generations=max(4, int(base.generations * scale)))
@@ -62,23 +36,16 @@ def ga_budget(scale: float = 1.0) -> GAConfig:
 def flexion_reports(pairs, mc_samples: int,
                     timings: Optional[Dict[str, float]] = None,
                     phase: str = "flexion"):
-    """Flexion reports for ``(spec, layer)`` pairs, in input order.
-
-    One batched ``flexion_campaign`` call in campaign mode, the per-pair
-    serial ``compute_flexion`` loop otherwise — bit-identical either way
-    (every row uses seed 0, the single-call default).  Starts cache-cold so
-    the recorded phase timing compares fairly across benchmark passes.
+    """Flexion reports for ``(spec, layer)`` pairs, in input order, from one
+    batched ``flexion_campaign`` call (every row uses seed 0, the
+    single-call default).  Starts cache-cold so the recorded phase timing
+    does not depend on what ran before it.
     """
-    from repro.core import (clear_flexion_reference_cache, compute_flexion,
-                            flexion_campaign)
+    from repro.core import clear_flexion_reference_cache, flexion_campaign
     clear_flexion_reference_cache()
     t0 = time.time()
-    if campaign_mode():
-        reports = flexion_campaign([(spec, layer, 0) for spec, layer in pairs],
-                                   mc_samples=mc_samples, seed=0)
-    else:
-        reports = [compute_flexion(spec, layer, mc_samples=mc_samples)
-                   for spec, layer in pairs]
+    reports = flexion_campaign([(spec, layer, 0) for spec, layer in pairs],
+                               mc_samples=mc_samples, seed=0)
     if timings is not None:
         timings[phase] = round(time.time() - t0, 6)
     return reports

@@ -46,8 +46,7 @@ def run(print_fn=print):
               for lname, dims in LAYERS.items()]
     timings = {}
 
-    # flexion column: one batched campaign over all (layer, accel) pairs in
-    # campaign mode, the per-pair serial loop otherwise — bit-identical.
+    # flexion column: one batched campaign over all (layer, accel) pairs.
     # (The displayed H-F(S) fractions are exact; 20K MC samples match fig7's
     # budget so the phase timing reflects a real estimator workload.)
     keys, pairs = zip(*[((aname, lname), (spec, layer))

@@ -4,26 +4,23 @@ FullFlex-1111), with H-F / W-F flexion quantification.
 Paper reference points: PartFlex-1000 H-F ~0.22 (1:1:1 hard partition);
 FullFlex-1000 ~4.8x over InFlex end-to-end; PartFlex strictly between.
 
-With the batched engine, each per-layer column and the end-to-end model
-sweep run as chunked (layer, spec) rows through one compiled GA program.
+The per-layer columns and the end-to-end model sweep run as one campaign
+row set through the compiled GA program.
 """
 from __future__ import annotations
 
-import dataclasses
 import time
 
 from repro.core import (FULLFLEX, PARTFLEX, get_model, inflex_baseline,
-                        make_variant, search, search_campaign, search_model,
-                        search_specs_batched)
+                        make_variant, search_campaign)
 
-from .common import (MNASNET_LAYERS, Table, campaign_mode, find_layer,
-                     flexion_reports, ga_budget)
+from .common import (MNASNET_LAYERS, Table, find_layer, flexion_reports,
+                     ga_budget)
 
 
 def run(print_fn=print):
     layers = get_model("mnasnet")
     cfg = ga_budget()
-    campaign = campaign_mode()
     accels = [
         ("InFlex1000", inflex_baseline()),
         ("PartFlex1000", make_variant("1000", PARTFLEX)),
@@ -41,37 +38,20 @@ def run(print_fn=print):
     derived = {}
     timings = {}
 
-    # per-layer columns: one batched MSE over all (layer, accel) rows; the
-    # campaign packs them AND the end-to-end model sweep into one row set
+    # per-layer columns and the end-to-end model sweep: one campaign row set
     quoted_layers = [find_layer("mnasnet", dims) for _, dims in quoted]
     t0 = time.time()
-    if campaign:
-        reqs = ([(quoted_layers, spec) for spec in specs]
-                + [(layers, spec) for spec in specs])
-        all_res = search_campaign(reqs, cfg)
-        per_spec = all_res[:len(specs)]
-        model_res = dict(zip((a for a, _ in accels), all_res[len(specs):]))
-        results = {(a, ln): per_spec[ai].per_layer[li]
-                   for ai, (a, _) in enumerate(accels)
-                   for li, (ln, _) in enumerate(quoted)}
-    elif cfg.engine == "batched":
-        per_spec = search_specs_batched(quoted_layers, specs, cfg)
-        results = {(a, ln): per_spec[ai].per_layer[li]
-                   for ai, (a, _) in enumerate(accels)
-                   for li, (ln, _) in enumerate(quoted)}
-    else:
-        # same per-layer seed convention as the batched branch
-        # (cfg.seed + 1000 * layer index), so both engines print
-        # identical per-layer columns
-        results = {(a, ln): search(
-            layer, spec, dataclasses.replace(cfg, seed=cfg.seed + 1000 * li))
-            for a, spec in accels
-            for li, ((ln, _), layer) in enumerate(zip(quoted, quoted_layers))}
-    timings["mse_campaign" if campaign else "mse_quoted"] = round(
-        time.time() - t0, 6)
+    reqs = ([(quoted_layers, spec) for spec in specs]
+            + [(layers, spec) for spec in specs])
+    all_res = search_campaign(reqs, cfg)
+    per_spec = all_res[:len(specs)]
+    model_res = dict(zip((a for a, _ in accels), all_res[len(specs):]))
+    results = {(a, ln): per_spec[ai].per_layer[li]
+               for ai, (a, _) in enumerate(accels)
+               for li, (ln, _) in enumerate(quoted)}
+    timings["mse_campaign"] = round(time.time() - t0, 6)
     # flexion columns: one batched campaign over all (layer, accel) pairs
-    # in campaign mode (shared C_X reference + deduped workload draws), the
-    # per-pair serial loop otherwise — bit-identical either way
+    # (shared C_X reference + deduped workload draws)
     keys, pairs = zip(*[((aname, lname), (spec, quoted_layers[li]))
                         for li, (lname, _) in enumerate(quoted)
                         for aname, spec in accels])
@@ -87,17 +67,6 @@ def run(print_fn=print):
                   str(r.mapping.tiles))
 
     # end-to-end model (already searched by the campaign row set above)
-    t0 = time.time()
-    if campaign:
-        pass
-    elif cfg.engine == "batched":
-        model_res = dict(zip((a for a, _ in accels),
-                             search_specs_batched(layers, specs, cfg)))
-    else:
-        model_res = {a: search_model(layers, spec, cfg)
-                     for a, spec in accels}
-    if not campaign:
-        timings["mse_model"] = round(time.time() - t0, 6)
     model_rt = {}
     for aname, _ in accels:
         res = model_res[aname]
@@ -116,7 +85,7 @@ def run(print_fn=print):
                               <= model_rt["PartFlex1000"] * 1.001
                               and model_rt["PartFlex1000"]
                               <= model_rt["InFlex1000"] * 1.001)
-    # phases ride along in every pass so the BENCH artifact records the
-    # serial-vs-campaign flexion timing side by side
+    # phases ride along so the BENCH artifact records the MSE and flexion
+    # timings
     derived["_phases"] = timings
     return derived

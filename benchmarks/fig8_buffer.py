@@ -24,9 +24,8 @@ def run(print_fn=print):
     timings = {}
 
     # W-F of the T axis (the flexible axis in this isolation study): one
-    # campaign over all (buffer size, probe layer) rows in campaign mode —
-    # each HWConfig samples its C_X reference once — or the per-pair serial
-    # loop; bit-identical either way
+    # campaign over all (buffer size, probe layer) rows — each HWConfig
+    # samples its C_X reference once
     reports = flexion_reports([(spec, l) for spec in specs
                                for l in probe_layers], 5_000, timings)
     wf_t = {spec.hw.buffer_bytes: float(np.mean(

@@ -40,8 +40,7 @@ def run(print_fn=print):
               ("layer29", find_layer("mnasnet", MNASNET_LAYERS["layer29"]))]
     timings = {}
 
-    # flexion column: batched campaign over all (layer, accel) pairs in
-    # campaign mode, per-pair serial loop otherwise — bit-identical
+    # flexion column: batched campaign over all (layer, accel) pairs
     keys, pairs = zip(*[((aname, lname), (spec, layer))
                         for lname, layer in quoted
                         for aname, spec in accels])

@@ -6,34 +6,30 @@ of the whole table/figure reproduction; derived = its headline metric).
   PYTHONPATH=src python -m benchmarks.run                 # everything
   PYTHONPATH=src python -m benchmarks.run table3 fig7     # a subset
   REPRO_BENCH_MODE=fast|default|full                      # GA budgets
-  REPRO_ENGINE=batched|serial                             # MSE engine
-  REPRO_CAMPAIGN=1                                        # campaign batching
   REPRO_DEVICES=N|all|i,j                                 # device pool
 
 Machine-readable perf trajectory:
 
   XLA_FLAGS=--xla_force_host_platform_device_count=4 \
   python -m benchmarks.run fig7 fig11 fig13 flexion \
-      --engines serial,batched --campaign --devices 4 --service 4 \
-      --autotune --json BENCH_mapper.json
+      --devices 4 --service 4 --autotune --json BENCH_mapper.json
 
-runs every selected bench once per engine — ``--campaign`` adds a pass
-through the cross-model campaign path (batched engine + chunk pipelining +
-whole-sweep row sets, with per-phase timings), ``--devices N`` adds a
-``campaign-dN`` pass with the campaign's chunks round-robin sharded over a
-device pool of N (simulated host devices on CPU via the ``XLA_FLAGS`` line
-above; real accelerators otherwise), and ``--service N`` adds the DSE
-service bench (N concurrent clients vs N sequential campaigns — see
-docs/serving.md), and ``--autotune`` adds ONE post-loop pass of the
-measured kernel-autotune bench (predicted-vs-measured rank correlation +
-golden parity + measured GA tuning — see docs/kernels.md) under its own
-``autotune`` label — and writes a BENCH JSON artifact (per-bench
-``us_per_call`` + derived metrics + phases + speedups + a
-``device_scaling`` block) so future PRs can diff mapper performance
-instead of guessing.
+runs every selected bench in one ``campaign`` pass (the engine with chunk
+pipelining and whole-sweep row sets, with per-phase timings).
+``--devices N`` adds a ``campaign-dN`` pass with the campaign's chunks
+round-robin sharded over a device pool of N (simulated host devices on CPU
+via the ``XLA_FLAGS`` line above; real accelerators otherwise),
+``--service N`` adds the DSE service bench (N concurrent clients vs N
+sequential campaigns — see docs/serving.md), and ``--autotune`` adds ONE
+post-loop pass of the measured kernel-autotune bench (predicted-vs-measured
+rank correlation + golden parity + measured GA tuning — see
+docs/kernels.md) under its own ``autotune`` label.  ``--json`` writes a
+BENCH JSON artifact (per-bench ``us_per_call`` + derived metrics + phases +
+a ``device_scaling`` block) so future PRs can diff the derived metrics.
 
-All passes must agree on every derived metric (the engines' golden-parity
-contract); any mismatch makes the run exit nonzero so CI gates on it.
+All passes must agree on every derived metric (sharded results are
+bit-identical to single-device ones); any mismatch makes the run exit
+nonzero so CI gates on it.
 """
 from __future__ import annotations
 
@@ -71,40 +67,38 @@ def _module(name: str):
 
 BENCH_SCHEMA = "repro-bench-mapper/v7"
 
-# benches whose derived metrics are pure functions of the MSE engines or the
+# benches whose derived metrics are pure functions of the MSE engine or the
 # (seed-deterministic) flexion estimators (the golden-parity gate only
 # covers these; roofline/bridge read external artifacts, table3 never
 # touches the mapper, and autotune measures wall-clock so it runs ONCE
-# after the engine passes, never per-engine).  "service" qualifies: its gated keys (client/query
-# counts, parity/cache flags, unique row count) are load- and
-# placement-independent by the service's bit-parity contract.
+# after the campaign passes, never per pass).  "service" qualifies: its
+# gated keys (client/query counts, parity/cache flags, unique row count)
+# are load- and placement-independent by the service's bit-parity
+# contract.
 PARITY_BENCHES = {"fig7", "fig8", "fig9", "fig10", "fig11", "fig12",
                   "fig13", "flexion", "service"}
 
 
-def _warm_engine(engine: str) -> None:
+def _warm_engine() -> None:
     """Compile the engine's programs for the current GA budget outside the
     timed region — us_per_call reports steady-state per-figure cost, not the
     one-time jit (which the persistent XLA cache amortizes anyway).
 
-    Warms every jit family a bench can hit: the engine program (or the
-    serial evaluate_population, in both hard-partition variants) plus the
-    engine-independent fixed-config objective and fixed-genome evaluator, so
-    neither engine pass times compiles the other pass already paid for.
-    Device-pool passes (``campaign-dN``) warm each pool device: the engine
-    program via ``warmup_engine`` and the replay evaluator via a pool-sized
-    ``evaluate_fixed_genome`` call."""
+    Warms every jit family a bench can hit: the engine program, the
+    fixed-config objective (at the campaign's padded model-axis shape) and
+    the fixed-genome evaluator.  Device-pool passes (``campaign-dN``) warm
+    each pool device: the engine program via ``warmup_engine`` and the
+    replay evaluator via a pool-sized ``evaluate_fixed_genome`` call."""
     import dataclasses
 
     from repro.core import (Layer, PARTFLEX, evaluate_fixed_genome,
-                            make_variant, search, search_fixed_config,
+                            make_variant, search_fixed_config,
                             search_fixed_configs)
     from repro.core.engine import ROW_BUCKET, warmup_engine
 
     from .common import bench_mode, ga_budget
 
     cfg = ga_budget()
-    is_campaign = engine.startswith("campaign")
     tiny = Layer("warmup", (4, 4, 4, 4, 1, 1))
     # the flexion estimators are engine-independent numpy; one draw at the
     # mode's sample budget pays the first-touch (allocator, code paths)
@@ -116,29 +110,23 @@ def _warm_engine(engine: str) -> None:
     compute_flexion(make_variant("1111", PARTFLEX), tiny,
                     mc_samples=MC_BY_MODE[bench_mode()])
     clear_flexion_reference_cache()
-    if engine == "batched" or is_campaign:
-        warmup_engine(cfg)    # dispatches to every pool device
-    else:
-        scfg = dataclasses.replace(cfg, engine="serial", generations=2)
-        search(tiny, make_variant("1111"), scfg)
-        search(tiny, make_variant("1111", PARTFLEX), scfg)
+    warmup_engine(cfg)    # dispatches to every pool device
     # shared jits (fixed-config objective + batched fixed-genome eval)
     wcfg = dataclasses.replace(cfg, generations=2)
     genome, _ = search_fixed_config([tiny], make_variant("1111"), wcfg)
-    if is_campaign:
-        # the model-stacked fixed-config program at the campaign's padded
-        # model-axis shape: fig13 designs its whole model set in one call,
-        # so warm with the same request count (same power-of-two bucket)
-        from .fig13_futureproof import MODELS
-        search_fixed_configs([([tiny], make_variant("1111"))] * len(MODELS),
-                             wcfg)
-        from repro.core.device_pool import default_pool
-        pool = default_pool()
-        if pool is not None and len(pool) > 1:
-            # replay chunks round-robin over the pool: one ROW_BUCKET chunk
-            # per device warms each device's evaluate_rows executable
-            evaluate_fixed_genome([tiny] * (ROW_BUCKET * len(pool)),
-                                  make_variant("1111"), genome)
+    # the model-stacked fixed-config program at the campaign's padded
+    # model-axis shape: fig13 designs its whole model set in one call, so
+    # warm with the same request count (same power-of-two bucket)
+    from .fig13_futureproof import MODELS
+    search_fixed_configs([([tiny], make_variant("1111"))] * len(MODELS),
+                         wcfg)
+    from repro.core.device_pool import default_pool
+    pool = default_pool()
+    if pool is not None and len(pool) > 1:
+        # replay chunks round-robin over the pool: one ROW_BUCKET chunk per
+        # device warms each device's evaluate_rows executable
+        evaluate_fixed_genome([tiny] * (ROW_BUCKET * len(pool)),
+                              make_variant("1111"), genome)
 
 
 def _run_once(names):
@@ -174,16 +162,16 @@ def _speedup_row(rows_a, rows_b):
 
 
 def _bench_json(engine_rows, engine_results, devices=None):
-    """BENCH artifact (schema v6): per-pass per-bench us_per_call + derived
-    metrics (+ campaign phase timings), pairwise speedups between passes,
-    and — when a ``--devices`` pass ran — a ``device_scaling`` block
-    recording the pool size and the campaign → sharded-campaign speedup."""
+    """BENCH artifact: per-pass per-bench us_per_call + derived metrics
+    (+ campaign phase timings) under ``engines``, and — when a
+    ``--devices`` pass ran — a ``device_scaling`` block recording the pool
+    size and the campaign → sharded-campaign speedup."""
     from .common import bench_mode
     doc = {
         "schema": BENCH_SCHEMA,
         "bench_mode": bench_mode(),
         "created_unix": int(time.time()),
-        "warmup": True,   # per-engine jit warmup runs before the timed loop
+        "warmup": True,   # per-pass jit warmup runs before the timed loop
         "engines": {},
     }
     for engine, rows in engine_rows.items():
@@ -211,12 +199,6 @@ def _bench_json(engine_rows, engine_results, devices=None):
                                     if k.startswith("_")}
             entry[name] = cell
         doc["engines"][engine] = entry
-    for a, b, key in (("serial", "batched", "speedup_serial_over_batched"),
-                      ("batched", "campaign",
-                       "speedup_batched_over_campaign"),
-                      ("serial", "campaign", "speedup_serial_over_campaign")):
-        if {a, b} <= set(engine_rows):
-            doc[key] = _speedup_row(engine_rows[a], engine_rows[b])
     if devices:
         label = f"campaign-d{devices}"
         import jax
@@ -230,9 +212,6 @@ def _bench_json(engine_rows, engine_results, devices=None):
         if {label, "campaign"} <= set(engine_rows):
             scaling["speedup_campaign_over_devices"] = _speedup_row(
                 engine_rows["campaign"], engine_rows[label])
-        if {label, "serial"} <= set(engine_rows):
-            scaling["speedup_serial_over_devices"] = _speedup_row(
-                engine_rows["serial"], engine_rows[label])
         doc["device_scaling"] = scaling
     return doc
 
@@ -245,18 +224,15 @@ def main(argv=None) -> int:
         _module("roofline").ensure_some_records()
     from repro.launch.compile_cache import enable_compile_cache
 
-    from .common import bench_mode, campaign_mode
     enable_compile_cache()
     json_path = None
-    engines = None
-    campaign = False
     autotune = False
     devices = None
     service_clients = None
     rest = []
     it = iter(argv)
     for a in it:
-        if a in ("--json", "--engines", "--devices", "--service"):
+        if a in ("--json", "--devices", "--service"):
             value = next(it, None)
             if value is None:
                 print(f"error: {a} expects a value", file=sys.stderr)
@@ -274,7 +250,7 @@ def main(argv=None) -> int:
                     print(f"error: --service expects a positive client "
                           f"count, got {value!r}", file=sys.stderr)
                     return 2
-            elif a == "--devices":
+            else:
                 # same grammar as REPRO_DEVICES: count | "all" | i,j indices
                 from repro.dist.pool import parse_device_spec
                 try:
@@ -285,10 +261,6 @@ def main(argv=None) -> int:
                           file=sys.stderr)
                     return 2
                 devices = value.strip()
-            else:
-                engines = [e.strip() for e in value.split(",") if e.strip()]
-        elif a == "--campaign":
-            campaign = True
         elif a == "--autotune":
             autotune = True
         else:
@@ -304,59 +276,40 @@ def main(argv=None) -> int:
         os.environ["REPRO_SERVICE_CLIENTS"] = str(service_clients)
         if "service" not in names:
             names.append("service")
-    if engines is None:
-        # a plain `REPRO_CAMPAIGN=1 python -m benchmarks.run` IS a campaign
-        # run (the per-pass env setup below would otherwise clear the flag),
-        # and REPRO_DEVICES makes it a sharded one
-        if campaign_mode():
-            dev_env = os.environ.get("REPRO_DEVICES")
-            engines = [f"campaign-d{dev_env}" if dev_env else "campaign"]
-            if dev_env and devices is None:
-                devices = dev_env.strip()   # device_scaling block rides along
-        else:
-            engines = [os.environ.get("REPRO_ENGINE", "batched")]
-    if campaign and "campaign" not in engines:
-        engines.append("campaign")
-    if devices is not None and f"campaign-d{devices}" not in engines:
-        engines.append(f"campaign-d{devices}")
+    prev_devices = os.environ.get("REPRO_DEVICES")
+    passes = ["campaign"]
+    if prev_devices and devices is None:
+        # a plain `REPRO_DEVICES=N python -m benchmarks.run` IS a sharded
+        # run (the per-pass env setup below would otherwise clear the pool)
+        passes = [f"campaign-d{prev_devices}"]
+        devices = prev_devices.strip()   # device_scaling block rides along
+    elif devices is not None:
+        passes.append(f"campaign-d{devices}")
 
     engine_rows = {}
     engine_results = {}
     failed = 0
-    prev_engine = os.environ.get("REPRO_ENGINE")
-    prev_campaign = os.environ.get("REPRO_CAMPAIGN")
-    prev_devices = os.environ.get("REPRO_DEVICES")
-    for engine in engines:
-        if engine.startswith("campaign"):
-            os.environ["REPRO_ENGINE"] = "batched"
-            os.environ["REPRO_CAMPAIGN"] = "1"
-            if "-d" in engine:    # campaign-dN: shard chunks over N devices
-                os.environ["REPRO_DEVICES"] = engine.split("-d", 1)[1]
-            else:
-                os.environ.pop("REPRO_DEVICES", None)
+    for label in passes:
+        if "-d" in label:    # campaign-dN: shard chunks over N devices
+            os.environ["REPRO_DEVICES"] = label.split("-d", 1)[1]
         else:
-            os.environ["REPRO_ENGINE"] = engine
-            os.environ.pop("REPRO_CAMPAIGN", None)
             os.environ.pop("REPRO_DEVICES", None)
         # a warmup that fails would leave the compile inside the timed
         # pass, so it fails the run
-        _warm_engine(engine)
+        _warm_engine()
         rows, results, nfail = _run_once(names)
-        engine_rows[engine] = rows
-        engine_results[engine] = results
+        engine_rows[label] = rows
+        engine_results[label] = results
         failed += nfail
-    for var, prev in (("REPRO_ENGINE", prev_engine),
-                      ("REPRO_CAMPAIGN", prev_campaign),
-                      ("REPRO_DEVICES", prev_devices)):
-        if prev is None:
-            os.environ.pop(var, None)
-        else:
-            os.environ[var] = prev
+    if prev_devices is None:
+        os.environ.pop("REPRO_DEVICES", None)
+    else:
+        os.environ["REPRO_DEVICES"] = prev_devices
 
     # measured-runtime autotune pass: runs ONCE under its own label after
-    # the engine loop (wall-clock objective — engine choice is irrelevant
-    # and per-engine repeats would just re-measure), so the engines list,
-    # parity gate, and results/bench_results.json are untouched
+    # the campaign passes (wall-clock objective — per-pass repeats would
+    # just re-measure), so the pass list, parity gate, and
+    # results/bench_results.json are untouched
     if autotune:
         rows, results, nfail = _run_once(["autotune"])
         engine_rows["autotune"] = rows
@@ -364,28 +317,28 @@ def main(argv=None) -> int:
         failed += nfail
 
     # golden-parity gate: every pass must derive identical metrics on the
-    # engine-driven benches.  A mismatch is a real engine bug (the batched/
-    # campaign paths promise bit-identical results), so it must fail the
-    # run, not just print.
-    base = engines[0]
-    for engine in engines[1:]:
+    # engine-driven benches.  A mismatch is a real engine bug (sharded
+    # results promise to be bit-identical), so it must fail the run, not
+    # just print.
+    base = passes[0]
+    for label in passes[1:]:
         for name in names:
             if name not in PARITY_BENCHES:
                 continue
             if (name not in engine_results[base]
-                    or name not in engine_results[engine]):
+                    or name not in engine_results[label]):
                 continue   # the pass crashed — already counted, not a
                            # parity bug
             da = public_derived(engine_results[base][name])
-            db = public_derived(engine_results[engine][name])
+            db = public_derived(engine_results[label][name])
             if not derived_equal(da, db):
                 failed += 1
                 print(f"PARITY MISMATCH {name}: [{base}] {da} != "
-                      f"[{engine}] {db}", file=sys.stderr)
+                      f"[{label}] {db}", file=sys.stderr)
 
     os.makedirs("results", exist_ok=True)
     with open("results/bench_results.json", "w") as f:
-        json.dump(engine_results[engines[-1]], f, indent=2, default=str)
+        json.dump(engine_results[passes[-1]], f, indent=2, default=str)
     if json_path:
         with open(json_path, "w") as f:
             json.dump(_bench_json(engine_rows, engine_results,

@@ -64,11 +64,10 @@ def _bit_equal(a, b) -> bool:
 
 def run():
     n_clients = int(os.environ.get("REPRO_SERVICE_CLIENTS", "4"))
-    # both sides run the batched engine (placement comes from REPRO_DEVICES
-    # as usual) so the speedup measures the SERVICE — dedup, cross-request
-    # packing, cache — not an engine A/B
-    cfg = dataclasses.replace(BUDGETS[bench_mode()], engine="batched",
-                              pipeline=True)
+    # both sides run the same pipelined engine (placement comes from
+    # REPRO_DEVICES as usual) so the speedup measures the SERVICE — dedup,
+    # cross-request packing, cache
+    cfg = dataclasses.replace(BUDGETS[bench_mode()], pipeline=True)
     sessions = _queries(n_clients)
 
     # the deterministic contract: the union of row-cache keys is exactly

@@ -416,7 +416,7 @@ def phase_kernels(state):
     spec = make_variant("1100", hw=HWConfig(), fixed_bits=32)
     pop, gens = TUNE_POP_GENS["full"]
     tune_cfg = dataclasses.replace(BUDGETS["full"], population=pop,
-                                   generations=gens, engine="serial")
+                                   generations=gens)
     shapes = SHAPES["full"]
     for kind, wl in (("matmul", matmul_workload(*shapes["matmul"])),
                      ("attention", attention_workload(*shapes["attention"])),

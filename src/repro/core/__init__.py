@@ -28,8 +28,7 @@ from .mapper import (GAConfig, MapperResult, ModelResult,
                      evaluate_fixed_genome_many, plan_model_rows,
                      raw_tile_feasibility, request_rows, search,
                      search_campaign, search_fixed_config,
-                     search_fixed_configs, search_model,
-                     search_model_batched, search_specs_batched)
+                     search_fixed_configs, search_model)
 from .result_cache import ResultCache
 from .mapspace import Mapping, MapSpace, mapspace_for, workload_space_size
 from .precision import (FULL_BITS, PART_BITS, bytes_of, element_scale,
@@ -61,7 +60,7 @@ __all__ = [
     "evaluate_fixed_genome_many", "plan_model_rows", "raw_tile_feasibility",
     "request_rows", "search",
     "search_campaign", "search_fixed_config", "search_fixed_configs",
-    "search_model", "search_model_batched", "search_specs_batched",
+    "search_model",
     "Mapping", "MapSpace", "mapspace_for", "workload_space_size",
     "FULL_BITS", "PART_BITS", "bytes_of", "element_scale", "mac_scale",
     "native_bits",

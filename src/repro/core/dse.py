@@ -25,8 +25,7 @@ from .flexion import FlexionReport
 from .flexion_batched import flexion_campaign, model_flexion_campaign
 from .mapper import (GAConfig, ModelResult, evaluate_fixed_genome,
                      evaluate_fixed_genome_many, search_campaign,
-                     search_fixed_config, search_fixed_configs,
-                     search_model, search_specs_batched)
+                     search_fixed_config, search_fixed_configs)
 from .mapspace import MapSpace
 from .spec import (FULLFLEX, INFLEX, PARTFLEX, FlexSpec, HWConfig, OrderSpec,
                    ParallelSpec, RepresentationSpec, ShapeSpec, TileSpec,
@@ -60,22 +59,25 @@ def run_dse(layers: Sequence[Layer], candidates: Sequence[FlexSpec],
     """Evaluate candidate accelerators; every DSE step includes a full MSE
     per benchmark layer (paper Sec 2.4).
 
-    With the batched engine, candidates sharing an HWConfig are searched in
-    ONE jitted dispatch (rows = specs x unique layers); results are
-    bit-identical to per-spec ``search_model`` calls.  ``with_flexion``
-    likewise estimates every candidate's flexion through one
-    ``model_flexion_campaign`` batch (bit-identical to per-spec
-    ``model_flexion`` calls, with the C_X reference sampled once per
-    HWConfig)."""
+    Candidates sharing an HWConfig are searched as ONE campaign row set
+    (rows = specs x unique layers; the engine takes one HWConfig per call);
+    results come back in candidate order and are bit-identical to per-spec
+    ``search_model`` calls.  ``with_flexion`` likewise estimates every
+    candidate's flexion through one ``model_flexion_campaign`` batch
+    (bit-identical to per-spec ``model_flexion`` calls, with the C_X
+    reference sampled once per HWConfig)."""
     cfg = cfg or GAConfig()
     candidates = list(candidates)
     if not candidates:
         return []      # an empty candidate set is a valid (empty) DSE
-    if (cfg.engine == "batched" and len(candidates) > 1
-            and all(s.hw == candidates[0].hw for s in candidates)):
-        mres_list = search_specs_batched(layers, candidates, cfg)
-    else:
-        mres_list = [search_model(layers, spec, cfg) for spec in candidates]
+    by_hw: Dict[HWConfig, List[int]] = {}
+    for i, spec in enumerate(candidates):
+        by_hw.setdefault(spec.hw, []).append(i)
+    mres_list: List[Optional[ModelResult]] = [None] * len(candidates)
+    for idx in by_hw.values():
+        found = search_campaign([(layers, candidates[i]) for i in idx], cfg)
+        for i, mres in zip(idx, found):
+            mres_list[i] = mres
     if with_flexion:
         flex_list = model_flexion_campaign(
             [(spec, layers) for spec in candidates], flexion_samples)
@@ -277,10 +279,9 @@ def future_proofing_study(base_model: str = "alexnet",
                 row[m] = cells["InFlex0000-X-Opt", m][1].runtime
             table["InFlex0000-X-Opt"] = row
 
-        # flexible variants of the 2014 design; with the batched engine, each
-        # model's whole spec sweep is a few chunked engine dispatches — and
-        # the campaign packs ALL models' sweeps into one chunk-pipelined row
-        # set
+        # flexible variants of the 2014 design: each model's whole spec
+        # sweep is a few chunked engine dispatches — and the campaign packs
+        # ALL models' sweeps into one chunk-pipelined row set
         flex_specs = [open_axes(frozen, cs, FULLFLEX) for cs in class_strs]
         if include_partflex_1111:
             flex_specs.append(open_axes(frozen, "1111", PARTFLEX))
@@ -317,12 +318,8 @@ def future_proofing_study(base_model: str = "alexnet",
             else:
                 for m in future_models:
                     layers = get_model(m)
-                    if cfg.engine == "batched":
-                        model_res = search_specs_batched(layers, flex_specs,
-                                                         cfg)
-                    else:
-                        model_res = [search_model(layers, spec, cfg)
-                                     for spec in flex_specs]
+                    model_res = search_campaign(
+                        [(layers, spec) for spec in flex_specs], cfg)
                     for spec, mres in zip(flex_specs, model_res):
                         cells[spec.name, m] = (spec, mres)
             for m in future_models:

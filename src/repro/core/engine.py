@@ -1,7 +1,7 @@
 """Batched multi-layer MSE engine: one jitted XLA program per model search.
 
 The paper's DSE loop (Sec 2.4 / Fig 6) runs a full map-space exploration per
-benchmark layer at *every* DSE step.  The serial mapper dispatches one
+benchmark layer at *every* DSE step.  A per-layer Python GA dispatches one
 ``evaluate_population`` per layer per generation plus host-side numpy GA
 operators — ``L x generations`` device round-trips.  This engine stacks the
 GA state of all rows (a row = one (layer, spec) pair) into an ``(L, P, 10)``
@@ -24,15 +24,15 @@ Compile-once design (the whole fig7+fig13 suite shares one program):
     bound).
 
 Randomness is drawn host-side (``ga_ops.draw_run``, one numpy Generator per
-row seeded with the serial mapper's convention) and shipped as scan inputs.
+row seeded with the mapper's convention) and shipped as scan inputs.
 A fully device-side ``jax.random`` variant was measured and rejected: on the
 CPU backend the threefry key derivation tripled both compile time and
 steady-state latency (see docs/mapper.md).
 
-Golden parity with ``mapper.search_model(engine="serial")`` is by
-construction: both engines consume the same per-row draw streams and apply
-the same ``ga_ops`` operator arithmetic (float32 mutate steps, stable
-argsort, strict-improve best tracking) — see tests/test_batched_engine.py.
+Golden parity with the per-layer reference GA of tests/_reference_ga.py is
+by construction: both consume the same per-row draw streams and apply the
+same ``ga_ops`` operator arithmetic (float32 mutate steps, stable argsort,
+strict-improve best tracking) — see tests/test_batched_engine.py.
 """
 from __future__ import annotations
 
@@ -219,8 +219,8 @@ def _ga_run(dims, stride, depthwise, tile_lo, tile_hi, hard_partition,
 
 @dataclasses.dataclass(frozen=True)
 class EngineRow:
-    """One (layer, spec, seed) search request; seeds follow the serial
-    mapper's convention (``cfg.seed + 1000 * first_occurrence_index``)."""
+    """One (layer, spec, seed) search request; seeds follow the mapper's
+    convention (``cfg.seed + 1000 * first_occurrence_index``)."""
 
     layer: Layer
     spec: FlexSpec
@@ -255,7 +255,6 @@ class ChunkInputs(NamedTuple):
 # the key against the fields the dispatch path actually reads: adding a
 # GAConfig field fails lint until it is classified here or keyed.
 GA_KEY_EXCLUDED_FIELDS = {
-    "engine": "serial/batched produce bit-identical rows (golden parity)",
     "pipeline": "scheduling only; per-chunk inputs/outputs unchanged",
     "devices": "placement only; sharded results are bit-identical",
     "seed": "keyed per-row: row_cache_key folds EngineRow.seed instead",
@@ -264,7 +263,7 @@ GA_KEY_EXCLUDED_FIELDS = {
 
 def ga_params_key(cfg) -> tuple:
     """The GAConfig fields a row's search RESULT depends on, as a hashable
-    key.  Placement/scheduling knobs (``engine``, ``pipeline``, ``devices``)
+    key.  Placement/scheduling knobs (``pipeline``, ``devices``)
     are deliberately absent — they never change results (the golden-parity
     contract) — and ``seed`` lives on each :class:`EngineRow`, not here.
     Two configs with equal keys produce bit-identical rows, which is what

@@ -39,18 +39,6 @@ REGISTRY: Tuple[EnvVar, ...] = (
         "full = the paper's 100x100 sweep).",
         ("benchmarks.common",)),
     EnvVar(
-        "REPRO_ENGINE", "choice: serial / batched", "per-GAConfig",
-        "Forces the mapper engine during benches — how `benchmarks.run "
-        "--engines` A/B-times the two engines.  Contradicts "
-        "REPRO_CAMPAIGN=1 with `serial` (the campaign path is "
-        "batched-only) and the budget helper raises.",
-        ("benchmarks.common", "benchmarks.run")),
-    EnvVar(
-        "REPRO_CAMPAIGN", "flag", "off",
-        "Batches each cross-model bench sweep into one campaign row set "
-        "(`benchmarks.run --campaign` sets it per pass).",
-        ("benchmarks.common", "benchmarks.run")),
-    EnvVar(
         "REPRO_DEVICES", "spec: count / 'all' / i,j,...", "unset",
         "Device pool for campaign chunk sharding when the GAConfig does "
         "not name one (see repro.dist.pool.parse_device_spec); unset "

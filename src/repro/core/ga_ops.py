@@ -1,14 +1,14 @@
-"""Shared GA randomness and operators for the serial and batched MSE engines.
+"""Shared GA randomness and operators for the MSE engine and its reference.
 
-The golden-parity contract between ``mapper.search_model(engine="serial")``
-and the one-program batched engine (``repro.core.engine``) rests on two rules
-enforced by this module:
+The golden-parity contract between the one-program engine
+(``repro.core.engine``) and the per-layer reference GA the tests hold it to
+(tests/_reference_ga.py) rests on two rules enforced by this module:
 
   1. **One random stream per (layer, spec) row.**  All data-independent
      randomness of a GA run — parent-selection ranks, crossover masks and
      permutations, mutation masks/steps/divisor snaps — is drawn up front by
      :func:`draw_run` from a single ``numpy`` Generator, in one fixed call
-     order.  Both engines call the same function with the same seed, so they
+     order.  Both GAs call the same function with the same seed, so they
      consume bit-identical draws no matter how the generations are executed.
 
   2. **One operator formula, two array backends.**  The apply functions below
@@ -17,7 +17,7 @@ enforced by this module:
      backend as the ``xp`` argument.  Genomes are integers (exact in both
      backends) and the only floating-point arithmetic — the geometric tile
      step ``round(tile * step)`` — is forced to float32 on both sides, so the
-     serial host loop and the jitted device loop produce identical genomes.
+     host loop and the jitted device loop produce identical genomes.
 
 Rank-based parent selection is expressed as draws of *sorted positions* from
 the fixed rank distribution (the probability of picking the j-th best genome
@@ -190,7 +190,7 @@ def clip_genomes(g, tile_lo, tile_hi, table_lens, xp=np):
 
     Works on any leading batch shape ``(..., 10)``; ``tile_lo``/``tile_hi``/
     ``table_lens`` broadcast against it (per-row bounds for the batched
-    engine, flat vectors for the serial one).
+    engine, flat vectors for a single-layer host loop).
     """
     tiles = xp.clip(g[..., 0:6], tile_lo, tile_hi)
     idx = xp.mod(g[..., 6:10], table_lens)
@@ -220,15 +220,15 @@ def apply_mutation(g, d: GenDraws, tile_lo, tile_hi, table_lens, xp=np):
 
 def next_population(pop, order_idx, d: GenDraws, tile_lo, tile_hi,
                     table_lens, n_elite: int, xp=np):
-    """One serial-engine breeding step: elites survive, children are bred
+    """One host-side breeding step: elites survive, children are bred
     from rank-selected parents (crossover -> clip -> mutate).
 
     ``d`` must be one generation's draws (already ``gen_slice``\\ d).  This is
-    THE host-side generation step — ``mapper._search_serial`` and the
-    measured-objective kernel tuner (``kernel_bridge.tune_kernel``) both call
-    it, so a modeled and a measured GA walking the same draw stream breed
-    bit-identical genomes whenever their objectives rank populations the
-    same way."""
+    THE host-side generation step — the reference GA
+    (tests/_reference_ga.py) and the measured-objective kernel tuner
+    (``kernel_bridge.tune_kernel``) both call it, so a modeled and a
+    measured GA walking the same draw stream breed bit-identical genomes
+    whenever their objectives rank populations the same way."""
     elites = pop[order_idx[:n_elite]]
     parents = pop[order_idx[d.ranks]]          # rank-based selection
     children = apply_crossover(parents, d, xp)

@@ -22,12 +22,13 @@ numpy (``bridge_tile_feasible``) with the identical float32 arithmetic, and
 the property tests pin the two to exact agreement.
 
 ``MeasuredRunner`` times lowered kernels (compiled on TPU, interpret mode on
-CPU) behind a ``ResultCache`` timing cache, and ``tune_kernel`` runs the
-serial GA with measured wall-clock as the objective — falling back to the
-modeled objective when Pallas is unavailable (``REPRO_NO_PALLAS=1``), so the
-tier-1 suite stays hermetic.  ``rank_correlation_study`` records how well
-the model's predicted cost ranks real measured cost per mapping (the
-``benchmarks.run --autotune`` BENCH pass).
+CPU) behind a ``ResultCache`` timing cache, and ``tune_kernel`` walks the
+reference GA's trajectory with measured wall-clock as the objective —
+falling back to the modeled objective when Pallas is unavailable
+(``REPRO_NO_PALLAS=1``), so the tier-1 suite stays hermetic.
+``rank_correlation_study`` records how well the model's predicted cost
+ranks real measured cost per mapping (the ``benchmarks.run --autotune``
+BENCH pass).
 
 ``core -> kernels`` is a one-way dependency: kernel modules are imported
 lazily inside the functions that execute or size them, so importing
@@ -522,7 +523,7 @@ class TuneResult(NamedTuple):
 # Small default budget: measured tuning pays a jit compile per DISTINCT
 # lowered config, so the sweet spot is few generations over a population
 # that dedups heavily through the timing cache.
-TUNE_CFG = GAConfig(population=12, generations=6, engine="serial")
+TUNE_CFG = GAConfig(population=12, generations=6)
 
 
 def tune_kernel(wl: KernelWorkload, spec: FlexSpec,
@@ -531,7 +532,7 @@ def tune_kernel(wl: KernelWorkload, spec: FlexSpec,
     """GA search over the map space with MEASURED kernel wall-clock as the
     objective (modeled runtime when Pallas is unavailable).
 
-    Walks the exact serial-engine trajectory — same seeded draw stream,
+    Walks the reference GA's exact trajectory — same seeded draw stream,
     same ``ga_ops.next_population`` breeding step — with the per-genome
     objective swapped: cost-model-feasible genomes are lowered and timed
     (deduped through the runner's timing cache), infeasible ones keep the

@@ -10,18 +10,13 @@ classes and further inside PartFlex subsets:
     apply the hard-partition legality (tiles),
   * FullFlex axes roam the full constrained space C_X.
 
-Two interchangeable MSE engines sit behind ``GAConfig.engine``:
-
-  * ``"batched"`` (default): the whole model's GA — every unique layer's
-    population stacked into an (L, P, 10) tensor — runs as ONE jitted XLA
-    program per search (see repro.core.engine).
-  * ``"serial"``: the classic per-layer Python loop, one device dispatch per
-    layer per generation.
-
-Both engines consume identical random streams and operator arithmetic
-(repro.core.ga_ops), so they return bit-identical results for the same
-``GAConfig`` — the golden-parity property tested in
-tests/test_batched_engine.py.
+Every map-space search runs on one engine (repro.core.engine): the GA of
+every unique (layer, spec) row is stacked into an (L, P, 10) genome tensor
+and runs as ONE jitted XLA program per chunk of rows.  ``search``,
+``search_model`` and ``search_campaign`` only plan rows and fold the row
+results back.  The tests hold the engine bit for bit to a plain per-layer
+reference GA (tests/_reference_ga.py) that consumes the same random streams
+and operator arithmetic (repro.core.ga_ops).
 """
 from __future__ import annotations
 
@@ -36,15 +31,12 @@ import numpy as np
 from repro.dist.pool import InFlightQueue, parse_device_spec
 
 from . import device_pool, ga_ops, tracing
-from .cost_model import (CostResult, evaluate_kinds_impl,
-                         evaluate_population, evaluate_rows)
+from .cost_model import CostResult, evaluate_kinds_impl, evaluate_rows
 from .engine import (ROW_BUCKET, EngineRow, _bucket, pack_chunks,
                      run_batched_ga)
 from .mapspace import Mapping, MapSpace, mapspace_for
 from .spec import FlexSpec
 from .workloads import Layer, NUM_DIMS, group_table, layers_as_array
-
-ENGINES = ("batched", "serial")
 
 
 def _normalize_devices(devices):
@@ -79,7 +71,6 @@ class GAConfig:
     tile_divisor_bias: float = 0.3  # GAMMA-style: snap tiles to divisors
     seed: int = 0
     objective: str = "runtime"  # runtime | energy | edp
-    engine: str = "batched"     # batched | serial (identical results)
     pipeline: bool = False      # overlap host draw prep with device compute
                                 # across engine chunks (scheduling only —
                                 # results are bit-identical either way)
@@ -91,15 +82,10 @@ class GAConfig:
                                 # results are bit-identical either way
 
     def __post_init__(self):
-        if self.engine not in ENGINES:
-            raise ValueError(f"unknown engine {self.engine!r}; "
-                             f"expected one of {ENGINES}")
-        # Degenerate GA shapes used to slip through and make the engines
-        # disagree (generations=0: the serial loop dies on its best-genome
-        # assert while the batched engine returns an inf-objective garbage
-        # row; elite_frac >= 1 or population < 2 leave no children to
-        # breed).  Reject them HERE so both engines fail identically, at
-        # construction, with an actionable message.
+        # Degenerate GA shapes (generations=0 returns an inf-objective
+        # garbage row; elite_frac >= 1 or population < 2 leave no children
+        # to breed) are rejected HERE, at construction, with an actionable
+        # message.
         if self.population < 2:
             raise ValueError(
                 f"population must be >= 2 (elites plus at least one child), "
@@ -139,18 +125,12 @@ class MapperResult:
                 "edp": self.edp}[name]
 
 
-def _objective_values(res: CostResult, objective: str) -> np.ndarray:
-    arr = {"runtime": res.runtime, "energy": res.energy,
-           "edp": res.edp}[objective]
-    return np.asarray(arr)
-
-
 class _Operators:
     """Constraint-respecting GA operators over genome matrices (N, 10).
 
     Thin host-side wrapper over the shared draw/apply functions in
-    ``ga_ops`` — the batched engine applies the identical arithmetic in JAX,
-    which is what keeps the two engines in exact agreement."""
+    ``ga_ops`` (the engine applies the identical arithmetic in JAX); the
+    fixed-config search breeds with it."""
 
     def __init__(self, space: MapSpace, cfg: GAConfig,
                  rng: np.random.Generator):
@@ -172,64 +152,6 @@ class _Operators:
             ga_ops.apply_crossover(np.asarray(parents), d, np))
 
 
-def _search_serial(layer: Layer, spec: FlexSpec, cfg: GAConfig
-                   ) -> MapperResult:
-    """Per-layer GA with one device dispatch per generation (the reference
-    engine the batched one is held to)."""
-    rng = np.random.default_rng(cfg.seed)
-    space = mapspace_for(layer, spec)
-    pop = ga_ops.initial_population(rng, space, cfg)
-    n_elite = ga_ops.n_elite(cfg)
-    draws = ga_ops.draw_run(rng, space, cfg, cfg.generations,
-                            cfg.population - n_elite)
-    lens = space.table_lens()
-
-    dims = jnp.asarray(layer.dims)
-    stride = jnp.asarray(layer.stride)
-    dw = jnp.asarray(layer.depthwise)
-    # native-pinned R runs the pre-R cost program (bit parity with v4)
-    r_live = (len(space.repr_table) > 1
-              or int(space.repr_table[0]) != 8 * spec.hw.bytes_per_elem)
-    grouped, groups = _kind_args([layer])
-    grouped = None if grouped is None else grouped[0]
-    groups = None if groups is None else (groups[0][0], groups[1][0])
-
-    best_hist: List[float] = []
-    best_g: Optional[np.ndarray] = None
-    best_obj = np.inf
-    best_idx_res: Optional[Tuple[CostResult, int]] = None
-
-    for gen in range(cfg.generations):
-        tiles, orders, pairs, shapes, reprs = space.decode_batch(pop)
-        res = evaluate_population(
-            dims, stride, dw, jnp.asarray(tiles), jnp.asarray(orders),
-            jnp.asarray(pairs), jnp.asarray(shapes), spec.hw,
-            space.hard_partition,
-            jnp.asarray(reprs) if r_live else None, grouped, groups)
-        obj = _objective_values(res, cfg.objective)
-        order_idx = np.argsort(obj, kind="stable")
-        if obj[order_idx[0]] < best_obj:
-            best_obj = float(obj[order_idx[0]])
-            best_g = pop[order_idx[0]].copy()
-            best_idx_res = (res, int(order_idx[0]))
-        best_hist.append(best_obj)
-
-        pop = ga_ops.next_population(pop, order_idx,
-                                     ga_ops.gen_slice(draws, gen),
-                                     space.tile_lo, space.tile_hi, lens,
-                                     n_elite, np)
-
-    assert best_g is not None and best_idx_res is not None
-    res, i = best_idx_res
-    return MapperResult(
-        mapping=space.decode(best_g),
-        runtime=float(res.runtime[i]), energy=float(res.energy[i]),
-        edp=float(res.edp[i]), util=float(res.util[i]),
-        dram_elems=float(res.dram_elems[i]),
-        feasible=bool(res.feasible[i]), history=best_hist,
-    )
-
-
 def _row_to_result(layer: Layer, spec: FlexSpec, row) -> MapperResult:
     space = mapspace_for(layer, spec)
     return MapperResult(
@@ -244,8 +166,6 @@ def search(layer: Layer, spec: FlexSpec,
            cfg: Optional[GAConfig] = None) -> MapperResult:
     """MSE for one layer on one accelerator (paper Fig 6 inner loop)."""
     cfg = cfg or GAConfig()
-    if cfg.engine == "serial":
-        return _search_serial(layer, spec, cfg)
     row = run_batched_ga([EngineRow(layer, spec, cfg.seed)], cfg)[0]
     return _row_to_result(layer, spec, row)
 
@@ -291,7 +211,7 @@ def plan_model_rows(layers: Sequence[Layer], dedup: bool = True
                     ) -> Tuple[List[int], Dict[tuple, int]]:
     """One model's engine-row plan: ``row_index`` lists the first-occurrence
     layer indices that become rows, ``seen`` maps each dedup key to its row
-    position.  THE row-planning convention — ``search_model_batched``,
+    position.  THE row-planning convention — ``search_model``,
     ``search_campaign`` and the DSE service all call this one function, so
     their per-layer GA seeds (``cfg.seed + 1000 * first_occurrence_index``)
     and dedup behavior can never drift apart."""
@@ -339,9 +259,12 @@ def _model_result(results: Sequence[MapperResult]) -> ModelResult:
 
 def search_model(layers: Sequence[Layer], spec: FlexSpec,
                  cfg: Optional[GAConfig] = None,
-                 dedup: bool = True) -> ModelResult:
+                 dedup: bool = True,
+                 row_cache=None) -> ModelResult:
     """Per-layer MSE (flexible accelerators re-map every layer; paper Sec 3.1
-    scope: layers run sequentially).
+    scope: layers run sequentially).  All unique layers' GAs run in ONE
+    jitted XLA program (an (L, P, 10) genome tensor through a fori_loop over
+    generations) — see repro.core.engine.
 
     Dedup cache: identical layer *shapes* share one search — ResNet-style
     nets repeat blocks heavily.  The cache key is :func:`_dedup_key`, i.e.
@@ -350,36 +273,9 @@ def search_model(layers: Sequence[Layer], spec: FlexSpec,
     shapes resolve to the same (shared) MapperResult object.  Per-layer GA
     seeds derive from the *first occurrence* index (``seed + 1000*i``), so
     dedup changes no result, only how often the search runs.
-
-    ``cfg.engine`` selects the batched one-dispatch engine (default) or the
-    serial per-layer loop; both return identical results (golden parity).
+    ``row_cache`` answers already-searched rows from a persistent store (see
+    :func:`repro.core.engine.run_batched_ga`) without changing any result.
     """
-    cfg = cfg or GAConfig()
-    if cfg.engine == "batched":
-        return search_model_batched(layers, spec, cfg, dedup=dedup)
-    results: List[Optional[MapperResult]] = [None] * len(layers)
-    seen: Dict[tuple, int] = {}
-    for i, layer in enumerate(layers):
-        key = _dedup_key(layer)
-        if dedup and key in seen:
-            results[i] = results[seen[key]]
-            continue
-        lcfg = dataclasses.replace(cfg, seed=cfg.seed + 1000 * i)
-        results[i] = search(layer, spec, lcfg)
-        seen[key] = i
-    return _model_result(results)
-
-
-def search_model_batched(layers: Sequence[Layer], spec: FlexSpec,
-                         cfg: Optional[GAConfig] = None,
-                         dedup: bool = True,
-                         row_cache=None) -> ModelResult:
-    """Batched MSE: all unique layers' GAs run in ONE jitted XLA program
-    (an (L, P, 10) genome tensor through a fori_loop over generations) —
-    see repro.core.engine.  Same dedup cache and per-layer seeds as the
-    serial loop, hence bit-identical results.  ``row_cache`` answers
-    already-searched rows from a persistent store (see
-    :func:`repro.core.engine.run_batched_ga`) without changing any result."""
     cfg = cfg or GAConfig()
     row_index, seen = plan_model_rows(layers, dedup)
     rows = request_rows(layers, spec, cfg, row_index)
@@ -401,7 +297,7 @@ def search_campaign(requests: Sequence[Tuple[Sequence[Layer], FlexSpec]],
     of padding each model/spec call separately, and with ``cfg.pipeline``
     each chunk's host draw prep overlaps the previous chunk's device
     compute.  Per-request results are bit-identical to per-request
-    ``search_model_batched`` calls: rows keep the same per-layer dedup and
+    ``search_model`` calls: rows keep the same per-layer dedup and
     seed convention (``cfg.seed + 1000 * first_occurrence_index``), and rows
     are independent, so packing them differently changes nothing — which is
     also why a device pool (``cfg.devices`` / ``REPRO_DEVICES``) can spread
@@ -427,20 +323,6 @@ def search_campaign(requests: Sequence[Tuple[Sequence[Layer], FlexSpec]],
         out.append(assemble_model_result(layers, spec, row_index, seen,
                                          chunk, dedup))
     return out
-
-
-def search_specs_batched(layers: Sequence[Layer],
-                         specs: Sequence[FlexSpec],
-                         cfg: Optional[GAConfig] = None,
-                         dedup: bool = True) -> List[ModelResult]:
-    """MSE for several candidate accelerators *sharing an HWConfig* in one
-    jitted dispatch: the engine's row axis carries (spec, unique-layer)
-    pairs, with per-row padded tables and hard-partition flags.  Each spec's
-    ModelResult is bit-identical to its own ``search_model_batched`` call
-    (same per-layer seeds and draw streams).  One-model special case of
-    :func:`search_campaign`."""
-    return search_campaign([(layers, spec) for spec in specs], cfg,
-                           dedup=dedup)
 
 
 def _inert_mapping_rows(shape: Tuple[int, ...], native_bits: int = 8

@@ -285,7 +285,7 @@ class DSEService:
         all_rows = [r for q in group for r in q.rows]
         # placement/scheduling only — never changes results
         exec_cfg = dataclasses.replace(
-            group[0].cfg, engine="batched", pipeline=self.pipeline,
+            group[0].cfg, pipeline=self.pipeline,
             devices=self.devices if self.devices is not None
             else group[0].cfg.devices)
         fresh = {k for q in group for k in q.keys

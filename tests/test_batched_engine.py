@@ -1,23 +1,25 @@
 """Golden-parity and behaviour tests for the batched MSE engine.
 
-The contract (ISSUE 2): with a fixed seed and identical GAConfig,
-``search_model_batched`` and the serial ``search_model`` return *identical*
-best objectives per layer — any silent cost-model or operator drift during
-the engine refactor trips these tests.
+The contract: with a fixed seed and identical GAConfig, the engine and the
+per-layer reference GA (tests/_reference_ga.py) return *identical* results
+per layer — any silent cost-model or operator drift in the engine trips
+these tests.
 """
-import dataclasses
 import re
 
 import numpy as np
 import pytest
 
 from repro.core import (FULLFLEX, GAConfig, PARTFLEX, inflex_baseline,
-                        make_variant, run_dse, search, search_model,
-                        search_model_batched, search_specs_batched)
+                        make_variant, run_dse, search, search_campaign,
+                        search_model)
 from repro.core import engine, ga_ops
 from repro.core import mapper as mapper_mod
 from repro.core.engine import ROW_BUCKET, EngineRow, run_batched_ga
 from repro.core.workloads import Layer, get_model
+
+import _reference_ga
+from _reference_ga import run_rows, search_layer
 
 # the paper's quoted MnasNet layers 1 and 29
 LAYER1 = Layer("mnas.layer1", (32, 3, 224, 224, 3, 3))
@@ -25,8 +27,6 @@ LAYER29 = Layer("mnas.layer29", (1, 480, 14, 14, 5, 5), depthwise=True)
 LAYERS = [LAYER1, LAYER29]
 
 CFG = GAConfig(population=16, generations=6, seed=7)
-SERIAL = dataclasses.replace(CFG, engine="serial")
-BATCHED = dataclasses.replace(CFG, engine="batched")
 
 SPECS = {
     "InFlex": inflex_baseline(),
@@ -47,34 +47,60 @@ def _assert_identical(a, b):
     assert a.mapping == b.mapping
 
 
+def _on_reference(monkeypatch, fn, *args, **kwargs):
+    """``fn(*args, **kwargs)`` with the engine replaced by the reference GA."""
+    with monkeypatch.context() as m:
+        m.setattr(mapper_mod, "run_batched_ga", run_rows)
+        return fn(*args, **kwargs)
+
+
 @pytest.mark.parametrize("flex", sorted(SPECS))
-def test_golden_parity_search_model(flex):
+def test_golden_parity_search_model(flex, monkeypatch):
     spec = SPECS[flex]
-    serial = search_model(LAYERS, spec, SERIAL)
-    batched = search_model_batched(LAYERS, spec, CFG)
-    assert serial.runtime == batched.runtime
-    assert serial.energy == batched.energy
-    for rs, rb in zip(serial.per_layer, batched.per_layer):
+    ref = _on_reference(monkeypatch, search_model, LAYERS, spec, CFG)
+    batched = search_model(LAYERS, spec, CFG)
+    assert ref.runtime == batched.runtime
+    assert ref.energy == batched.energy
+    for rs, rb in zip(ref.per_layer, batched.per_layer):
         _assert_identical(rs, rb)
 
 
 def test_golden_parity_single_layer_search():
     for spec in SPECS.values():
-        _assert_identical(search(LAYER29, spec, SERIAL),
-                          search(LAYER29, spec, BATCHED))
+        _assert_identical(search_layer(LAYER29, spec, CFG),
+                          search(LAYER29, spec, CFG))
 
 
-def test_engine_default_is_batched_and_validated():
-    assert GAConfig().engine == "batched"
-    with pytest.raises(ValueError):
-        GAConfig(engine="warp-drive")
+# one layer of each kind the engine's program variants trace differently
+KIND_CASES = {
+    "depthwise": (LAYER29, ("1111", "0101")),
+    "grouped": (next(l for l in get_model("kimi-k2-decode32k")
+                     if l.name == "L4.scores"), ("1111", "0101")),
+    "ragged": (next(l for l in get_model("kimi-k2-decode32k")
+                    if l.name == "L3.experts.gate_up"), ("1111", "0101")),
+    "r-open": (LAYER1, ("11111",)),
+}
 
 
-def test_search_specs_batched_matches_per_spec():
+@pytest.mark.parametrize("kind", list(KIND_CASES))
+def test_engine_matches_reference_ga(kind):
+    """``search`` on the engine equals the reference GA field by field for
+    every layer kind (the grouped and ragged variants included)."""
+    layer, classes = KIND_CASES[kind]
+    assert layer.grouped == (kind in ("grouped", "ragged"))
+    assert layer.ragged == (kind == "ragged")
+    cfg = GAConfig(population=12, generations=4, seed=7)
+    for cs in classes:
+        spec = make_variant(cs, FULLFLEX)
+        _assert_identical(search_layer(layer, spec, cfg),
+                          search(layer, spec, cfg))
+
+
+def test_search_campaign_matches_per_spec():
     specs = [SPECS["InFlex"], SPECS["FullFlex"]]
-    combined = search_specs_batched(LAYERS, specs, CFG)
+    combined = search_campaign([(LAYERS, spec) for spec in specs], CFG)
     for spec, mres in zip(specs, combined):
-        solo = search_model_batched(LAYERS, spec, CFG)
+        solo = search_model(LAYERS, spec, CFG)
         assert mres.runtime == solo.runtime
         for ra, rb in zip(mres.per_layer, solo.per_layer):
             _assert_identical(ra, rb)
@@ -107,25 +133,26 @@ def test_dedup_shares_search_across_equal_shapes(monkeypatch):
     assert calls == [1]                       # one engine row for both
     assert res.per_layer[0] is res.per_layer[1]
 
-    # serial engine: one _search_serial invocation for the pair
-    serial_calls = []
-    real_serial = mapper_mod._search_serial
+    # reference GA: one per-layer search for the pair
+    ref_calls = []
+    real_search = _reference_ga._search
 
-    def counting_serial(layer, sp, cfg):
-        serial_calls.append(layer.name)
-        return real_serial(layer, sp, cfg)
+    def counting_search(layer, sp, cfg):
+        ref_calls.append(layer.name)
+        return real_search(layer, sp, cfg)
 
-    monkeypatch.setattr(mapper_mod, "_search_serial", counting_serial)
-    res_s = search_model(twins, spec, SERIAL)
-    assert serial_calls == ["conv_a"]
+    monkeypatch.setattr(_reference_ga, "_search", counting_search)
+    monkeypatch.setattr(mapper_mod, "run_batched_ga", run_rows)
+    res_s = search_model(twins, spec, CFG)
+    assert ref_calls == ["conv_a"]
     assert res_s.per_layer[0] is res_s.per_layer[1]
 
 
 def test_dedup_off_matches_dedup_on_for_unique_layers():
     layers = get_model("ncf")  # all-unique GEMM tower
     spec = SPECS["FullFlex"]
-    a = search_model_batched(layers, spec, CFG, dedup=True)
-    b = search_model_batched(layers, spec, CFG, dedup=False)
+    a = search_model(layers, spec, CFG, dedup=True)
+    b = search_model(layers, spec, CFG, dedup=False)
     assert a.runtime == b.runtime
 
 

@@ -5,29 +5,25 @@ Each test here pins a bug that existed before this change:
   * empty campaigns crashed on the engine's row assert instead of
     returning ``[]``;
   * degenerate ``GAConfig``s (``generations=0``, ``elite_frac >= 1``, tiny
-    populations) were accepted and then made the serial and batched engines
-    *disagree* (assert-crash vs inf-objective garbage row);
+    populations) were accepted and then returned garbage (an assert-crash
+    or an inf-objective row);
   * an exception while preparing/dispatching chunk i+1 in the pipelined
-    engine loop silently abandoned the already-dispatched in-flight chunk;
-  * ``benchmarks.common.ga_budget()`` silently forced ``engine="batched"``
-    when ``REPRO_ENGINE=serial`` and ``REPRO_CAMPAIGN=1`` were both set, so
-    an A/B run could record a mislabeled "serial" pass.
+    engine loop silently abandoned the already-dispatched in-flight chunk.
+
+It also holds the one search path to what the removed forks computed:
+``run_dse`` over candidates on several HWConfigs, and the fig13 study with
+and without its campaign batching.
 """
 import dataclasses
-import sys
-from pathlib import Path
 
 import pytest
 
-from repro.core import (GAConfig, get_model, inflex_baseline, make_variant,
-                        run_batched_ga, run_dse, search_campaign,
-                        search_specs_batched)
+from repro.core import (GAConfig, HWConfig, future_proofing_study, get_model,
+                        inflex_baseline, make_variant, run_batched_ga,
+                        run_dse, search_campaign, search_model)
 from repro.core import engine as engine_mod
+from repro.core import mapper as mapper_mod
 from repro.core.engine import EngineRow, ROW_BUCKET
-
-REPO = Path(__file__).resolve().parents[1]
-if str(REPO) not in sys.path:          # benchmarks/ lives at the repo root
-    sys.path.insert(0, str(REPO))
 
 LAYERS = get_model("ncf")
 CFG = GAConfig(population=6, generations=2, seed=5)
@@ -40,7 +36,6 @@ CFG = GAConfig(population=6, generations=2, seed=5)
 def test_empty_campaigns_return_empty():
     assert run_batched_ga([], CFG) == []
     assert search_campaign([], CFG) == []
-    assert search_specs_batched(LAYERS, [], CFG) == []
     assert run_dse(LAYERS, [], CFG) == []
     assert run_dse(LAYERS, [], CFG, with_flexion=True) == []
 
@@ -56,10 +51,10 @@ def test_empty_request_inside_campaign_is_fine():
 
 
 # --------------------------------------------------------------------------
-# degenerate GAConfigs are rejected identically for both engines
+# degenerate GAConfigs are rejected at construction
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("engine", ["serial", "batched"])
+@pytest.mark.parametrize("how", ["construct", "replace"])
 @pytest.mark.parametrize("bad", [
     dict(generations=0), dict(generations=-3),
     dict(population=1), dict(population=0),
@@ -67,14 +62,15 @@ def test_empty_request_inside_campaign_is_fine():
     dict(mutation_rate=1.0001), dict(mutation_rate=-0.5),
     dict(crossover_rate=2.0), dict(crossover_rate=-1.0),
 ])
-def test_degenerate_gaconfigs_rejected_for_both_engines(engine, bad):
-    """Construction (and dataclasses.replace, which re-runs __post_init__)
-    must raise for BOTH engines — the old behavior let ``generations=0``
-    through and the engines then returned different garbage."""
+def test_degenerate_gaconfigs_rejected(how, bad):
+    """Construction and dataclasses.replace (which re-runs __post_init__)
+    must both raise — the old behavior let ``generations=0`` through and
+    the search then returned garbage."""
     with pytest.raises(ValueError):
-        GAConfig(engine=engine, **bad)
-    with pytest.raises(ValueError):
-        dataclasses.replace(GAConfig(engine=engine), **bad)
+        if how == "construct":
+            GAConfig(**bad)
+        else:
+            dataclasses.replace(GAConfig(), **bad)
 
 
 def test_boundary_gaconfigs_accepted():
@@ -124,27 +120,47 @@ def test_pipeline_poisoned_chunk_collects_in_flight_and_names_chunk(
 
 
 # --------------------------------------------------------------------------
-# ga_budget: REPRO_ENGINE=serial + REPRO_CAMPAIGN=1 is a contradiction
+# the one search path: mixed HWConfigs and the study's batching
 # --------------------------------------------------------------------------
 
-def test_ga_budget_rejects_engine_campaign_conflict(monkeypatch):
-    from benchmarks.common import ga_budget
+def test_run_dse_mixed_hw_matches_per_spec(monkeypatch):
+    """Candidates on two HWConfigs go through one campaign per HWConfig and
+    come back in candidate order, each equal to its own ``search_model``."""
+    small = HWConfig(num_pes=16, dram_bw=2.0)
+    specs = [inflex_baseline(), make_variant("1111", hw=small),
+             make_variant("1111"), make_variant("0101", hw=small)]
+    calls = []
+    real = mapper_mod.run_batched_ga
 
-    monkeypatch.setenv("REPRO_ENGINE", "serial")
-    monkeypatch.setenv("REPRO_CAMPAIGN", "1")
-    with pytest.raises(RuntimeError, match="REPRO_CAMPAIGN"):
-        ga_budget()
+    def counting(rows, cfg, row_cache=None):
+        calls.append({r.spec.hw for r in rows})
+        return real(rows, cfg, row_cache=row_cache)
 
-    # the non-conflicting combinations keep working, correctly labeled
-    monkeypatch.setenv("REPRO_ENGINE", "batched")
-    cfg = ga_budget()
-    assert cfg.engine == "batched" and cfg.pipeline
+    with monkeypatch.context() as m:
+        m.setattr(mapper_mod, "run_batched_ga", counting)
+        rows = run_dse(LAYERS, specs, CFG)
+    assert calls == [{HWConfig()}, {small}]
+    assert [r.spec_name for r in rows] == [s.name for s in specs]
+    for spec, r in zip(specs, rows):
+        solo = search_model(LAYERS, spec, CFG)
+        assert r.runtime == solo.runtime and r.energy == solo.energy
+        for a, b in zip(r.model_result.per_layer, solo.per_layer):
+            assert a.mapping == b.mapping and a.history == b.history
+    assert rows[1].runtime != rows[2].runtime   # the HWConfig mattered
 
-    monkeypatch.delenv("REPRO_ENGINE")
-    cfg = ga_budget()
-    assert cfg.engine == "batched" and cfg.pipeline
 
-    monkeypatch.setenv("REPRO_ENGINE", "serial")
-    monkeypatch.delenv("REPRO_CAMPAIGN")
-    cfg = ga_budget()
-    assert cfg.engine == "serial" and not cfg.pipeline
+def test_study_table_same_with_and_without_campaign():
+    """The fig13 study's table and cells agree exactly whether its phases
+    run as cross-model campaigns or model by model."""
+    cfg = GAConfig(population=6, generations=2, seed=3)
+    runs = []
+    for campaign in (True, False):
+        results = {}
+        table = future_proofing_study(
+            base_model="ncf", future_models=("ncf", "dlrm"),
+            class_strs=("1111", "0101"), cfg=cfg, campaign=campaign,
+            results=results)
+        runs.append((table, {k: (spec.name, res.runtime, res.energy,
+                                 [r.mapping for r in res.per_layer])
+                             for k, (spec, res) in results.items()}))
+    assert runs[0] == runs[1]

@@ -217,10 +217,8 @@ def test_result_cache_thread_safety_under_contention():
 
 
 def test_row_cache_key_excludes_names_and_placement():
-    cfg1 = GAConfig(population=8, generations=3, engine="serial",
-                    pipeline=False)
-    cfg2 = GAConfig(population=8, generations=3, engine="batched",
-                    pipeline=True, devices=2)
+    cfg1 = GAConfig(population=8, generations=3, pipeline=False)
+    cfg2 = GAConfig(population=8, generations=3, pipeline=True, devices=2)
     rows1 = _rows(_model_a(), cfg1)
     rows2 = _rows([conv("other-name", 16, 8, 14, 14, 3, 3),
                    conv("x", 16, 8, 14, 14, 3, 3),

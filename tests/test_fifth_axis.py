@@ -20,6 +20,8 @@ from repro.core.classes import ALL_CLASSES_5, class_str
 from repro.core.precision import FULL_BITS, PART_BITS
 from repro.core.spec import FlexSpec, HWConfig
 
+from _reference_ga import search_layer
+
 LAYER = Layer("t", (64, 32, 28, 28, 3, 3))
 
 # one common C_X scale for all 32 classes: the 5-axis FullFlex accelerator
@@ -138,11 +140,12 @@ def test_rpinned_space_draws_no_r_randomness():
 
 
 def test_rpinned_serial_batched_bit_parity():
-    cfg_s = GAConfig(population=16, generations=4, seed=0, engine="serial")
-    cfg_b = GAConfig(population=16, generations=4, seed=0, engine="batched")
+    """The engine equals the reference GA (tests/_reference_ga.py) with R
+    pinned and with R open."""
+    cfg = GAConfig(population=16, generations=4, seed=0)
     for cs in ("1111", "11111"):
-        rs = search(LAYER, make_variant(cs), cfg_s)
-        rb = search(LAYER, make_variant(cs), cfg_b)
+        rs = search_layer(LAYER, make_variant(cs), cfg)
+        rb = search(LAYER, make_variant(cs), cfg)
         assert rs.mapping == rb.mapping
         assert rs.runtime == rb.runtime
         assert rs.energy == rb.energy

@@ -2,10 +2,11 @@
 
 The committed ``BENCH_mapper.json`` pins the fast-mode fig7/fig13 derived
 paper metrics plus the flexion pass's estimator invariants.  These tests
-re-run the benches through every MSE path — serial, batched, and the
-cross-model campaign — and assert
+re-run the benches twice — on the engine (the ``campaign`` path every bench
+runs) and with the engine replaced by the per-layer reference GA
+(tests/_reference_ga.py) — and assert
 
-  * the three paths agree with each other *bit-identically* (the engines'
+  * the two paths agree with each other *bit-identically* (the engine's
     golden-parity contract; same process, same machine, no excuses), and
   * each path reproduces the committed anchor values (floats at rel 1e-6 —
     the same cross-machine slack CI's ``scripts/diff_bench.py`` gate uses,
@@ -38,7 +39,7 @@ GOLDEN_KEYS = {
 BENCH_MODULES = {"fig7": "benchmarks.fig7_tile",
                  "fig13": "benchmarks.fig13_futureproof",
                  "flexion": "benchmarks.flexion_bench"}
-PATHS = ("serial", "batched", "campaign")
+PATHS = ("reference", "campaign")
 ANCHOR_RTOL = 1e-6
 
 # filled as the parametrized runs execute: (bench, path) -> golden values
@@ -69,12 +70,10 @@ def _committed_values(doc, bench):
 
 def _run_bench(bench, path, monkeypatch):
     monkeypatch.setenv("REPRO_BENCH_MODE", "fast")
-    if path == "campaign":
-        monkeypatch.setenv("REPRO_ENGINE", "batched")
-        monkeypatch.setenv("REPRO_CAMPAIGN", "1")
-    else:
-        monkeypatch.setenv("REPRO_ENGINE", path)
-        monkeypatch.delenv("REPRO_CAMPAIGN", raising=False)
+    if path == "reference":
+        from repro.core import mapper
+        from _reference_ga import run_rows
+        monkeypatch.setattr(mapper, "run_batched_ga", run_rows)
     mod = importlib.import_module(BENCH_MODULES[bench])
     return mod.run(print_fn=lambda *a, **k: None)
 
@@ -103,8 +102,9 @@ def test_path_reproduces_committed_metrics(bench, path, golden, monkeypatch):
 @pytest.mark.slow
 @pytest.mark.parametrize("bench", sorted(GOLDEN_KEYS))
 def test_paths_agree_bit_identically(bench):
-    """Serial, batched and campaign must agree exactly — same machine, same
-    process, so this is the unforgiving form of the parity contract."""
+    """The reference GA and the engine must agree exactly — same machine,
+    same process, so this is the unforgiving form of the parity
+    contract."""
     runs = {p: _RESULTS.get((bench, p)) for p in PATHS}
     if any(v is None for v in runs.values()):
         pytest.skip("per-path runs were deselected")

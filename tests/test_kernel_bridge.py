@@ -224,7 +224,7 @@ def _fake_timer(key):
     return 1e-4 + h * 1e-7
 
 
-TUNE_CFG = GAConfig(population=8, generations=3, engine="serial")
+TUNE_CFG = GAConfig(population=8, generations=3)
 
 
 def test_tune_kernel_frozen_timer_bit_reproducible():
@@ -313,7 +313,7 @@ def test_tune_kernel_measured_end_to_end(kind):
     if not runner.available():
         pytest.skip("pallas unavailable")
     res = tune_kernel(wl, SPEC_F32,
-                      GAConfig(population=6, generations=2, engine="serial"),
+                      GAConfig(population=6, generations=2),
                       runner)
     assert res.objective == "measured"
     assert res.best_cost > 0.0
