@@ -23,11 +23,18 @@ Compile-once design (the whole fig7+fig13 suite shares one program):
     zero-padded to a ``GEN_BUCKET`` multiple (never executed past the
     bound).
 
-Randomness is drawn host-side (``ga_ops.draw_run``, one numpy Generator per
-row seeded with the mapper's convention) and shipped as scan inputs.
-A fully device-side ``jax.random`` variant was measured and rejected: on the
-CPU backend the threefry key derivation tripled both compile time and
-steady-state latency (see docs/mapper.md).
+Randomness is drawn host-side and shipped as scan inputs.  Each row reads
+the numpy Generator seeded with the mapper's convention
+(``cfg.seed + 1000 * first_occurrence_index``), whose calls depend on the
+row's map space only through the R test (``ga_ops.r_open``).  So one
+:func:`run_batched_ga` call draws each (seed, R-open) stream once
+(``ga_ops.draw_stream``, kept in a :class:`StreamMemo` for the call) and
+derives every row that reads it from that stream under the row's map space
+(``ga_ops.row_draws``): a row's arrays equal ``initial_population`` then
+``draw_run`` on its own Generator.  A fully device-side ``jax.random``
+variant was measured and rejected: on the CPU backend the threefry key
+derivation tripled both compile time and steady-state latency, and it
+would change every stream (see docs/mapper.md).
 
 Golden parity with the per-layer reference GA of tests/_reference_ga.py is
 by construction: both consume the same per-row draw streams and apply the
@@ -36,6 +43,7 @@ strict-improve best tracking) — see tests/test_batched_engine.py.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import os
 import threading
@@ -308,6 +316,11 @@ def run_batched_ga(rows: Sequence[EngineRow], cfg,
     run the ragged program variant (``_ga_program_ragged``).  Rows are
     independent, so the packing changes no result.
 
+    Rows that read the same Generator stream (:func:`_stream_key`: equal
+    seed and R test) share its draws: the call's :class:`StreamMemo` holds
+    each stream from the first chunk that reads it to the last, in host
+    memory only, and is gone when the call returns.
+
     Chunks are independent, so they can run anywhere: with a device pool
     (``cfg.devices`` or ``REPRO_DEVICES``, see ``repro.core.device_pool``)
     chunk ``i`` is ``device_put`` onto pool device ``i % D`` and the same
@@ -321,8 +334,9 @@ def run_batched_ga(rows: Sequence[EngineRow], cfg,
     :class:`~repro.dist.pool.InFlightQueue`: chunk ``i`` is dispatched (JAX
     dispatch is asynchronous) and while the device crunches it, the host
     assembles the next chunks' draw streams — the host-side hot path of a
-    campaign-sized row set, drawn row by row on ``_prepare_chunk``'s draw
-    threads — keeping up to one chunk in flight *per pool device* before
+    campaign-sized row set, derived row by row on ``_prepare_chunk``'s draw
+    threads from the call's :class:`StreamMemo` — keeping up to one chunk in
+    flight *per pool device* before
     blocking on the oldest.  Scheduling only; per-chunk
     inputs and outputs are unchanged, so results stay bit-identical to the
     unpipelined loop.  If preparing or dispatching a later chunk raises, the
@@ -355,6 +369,7 @@ def run_batched_ga(rows: Sequence[EngineRow], cfg,
     pool = device_pool.pool_for(cfg)
     chunk_pos = pack_chunks([r.layer for r in rows])
     chunks = [[rows[i] for i in pos] for pos in chunk_pos]
+    memo = StreamMemo(rows)
     out: List[RowResult] = []
     if getattr(cfg, "pipeline", False):
         n_chunks = len(chunks)
@@ -372,7 +387,7 @@ def run_batched_ga(rows: Sequence[EngineRow], cfg,
         try:
             for idx, chunk in enumerate(chunks):
                 try:
-                    inputs = _prepare_chunk(chunk, cfg, hw)
+                    inputs = _prepare_chunk(chunk, cfg, hw, memo)
                     outputs = _dispatch_chunk(
                         inputs, cfg, hw,
                         device=pool.device_for(idx) if pool else None)
@@ -396,7 +411,7 @@ def run_batched_ga(rows: Sequence[EngineRow], cfg,
             raise
     else:
         for idx, chunk in enumerate(chunks):
-            inputs = _prepare_chunk(chunk, cfg, hw)
+            inputs = _prepare_chunk(chunk, cfg, hw, memo)
             out.extend(_collect_chunk(
                 len(chunk), inputs.gens,
                 _dispatch_chunk(inputs, cfg, hw,
@@ -425,11 +440,11 @@ def _host_cores() -> int:
     return os.cpu_count() or 1
 
 
-def _draw_workers(n_rows: int) -> int:
-    """Threads that draw a chunk of ``n_rows`` live rows: one per row, up
-    to the host's cores and ``DRAW_WORKERS``.  A one-row chunk is drawn
-    inline on the calling thread."""
-    return max(1, min(n_rows, _host_cores(), DRAW_WORKERS))
+def _draw_workers(n_items: int) -> int:
+    """Threads for ``n_items`` of a chunk's draws (its new streams, or its
+    live rows): one per item, up to the host's cores and ``DRAW_WORKERS``.
+    A single item is drawn inline on the calling thread."""
+    return max(1, min(n_items, _host_cores(), DRAW_WORKERS))
 
 
 _DRAW_POOL_LOCK = threading.Lock()
@@ -449,20 +464,75 @@ def _draw_pool() -> ThreadPoolExecutor:
         return _draw_pool_executor
 
 
-def _prepare_chunk(rows: Sequence[EngineRow], cfg, hw: HWConfig
-                   ) -> ChunkInputs:
+def _on_draw_threads(fn, n: int) -> int:
+    """``fn(i)`` for each ``i < n`` on :func:`_draw_workers` threads: the
+    caller and the draw pool's threads each take every k-th item.  Every
+    thread finishes before this returns or an error leaves, so nothing
+    is written after the chunk is abandoned.  Returns the thread count."""
+    workers = _draw_workers(n)
+
+    def run(first: int) -> None:
+        for i in range(first, n, workers):
+            fn(i)
+
+    helpers = [_draw_pool().submit(run, w) for w in range(1, workers)]
+    try:
+        run(0)
+    finally:
+        wait(helpers)
+    for h in helpers:
+        h.result()
+    return workers
+
+
+def _stream_key(row: EngineRow) -> tuple:
+    """The draw stream a row reads: its seed and its R test, which decides
+    the R-axis draws (``ga_ops.r_open``); nothing else of the layer or spec
+    touches the Generator."""
+    return row.seed, ga_ops.r_open(padded_tables(row.spec).lens)
+
+
+class StreamMemo:
+    """The draw streams of one :func:`run_batched_ga` call by
+    :func:`_stream_key`: each is drawn by the first chunk that needs it
+    and dropped after its last row, counted from the call's rows.  A
+    stream at GA 100 x 100 holds ~1.2 MB of host memory, plus the per-gene
+    columns its rows derive (``ga_ops.RunStream.columns``, ~0.4 MB a
+    stream in a fig13 sweep)."""
+
+    def __init__(self, rows: Sequence[EngineRow]):
+        self.uses = collections.Counter(_stream_key(r) for r in rows)
+        self.streams: dict = {}
+
+    def release(self, keys) -> None:
+        for k in keys:
+            self.uses[k] -= 1
+            if self.uses[k] <= 0:
+                self.streams.pop(k, None)
+
+
+def _prepare_chunk(rows: Sequence[EngineRow], cfg, hw: HWConfig,
+                   memo: Optional[StreamMemo] = None) -> ChunkInputs:
     """Assemble one chunk's padded host arrays (tables, populations, draw
     streams).  Pure host work — under ``cfg.pipeline`` it overlaps the
     previous chunk's device compute.
 
-    The rows' populations and draw streams are drawn on
-    :func:`_draw_workers` threads: the caller and the draw pool's threads
-    each take every k-th row.  Each row still draws from its own Generator
-    in the same call order, so the chunk is bit-identical to a one-thread
-    draw.  An error in any row is raised here once every thread is done.
+    Rows that share a :func:`_stream_key` share their Generator's draws
+    (``ga_ops.draw_stream``): the streams ``memo`` lacks are drawn first,
+    on :func:`_draw_workers` threads (a stream is a few long Generator
+    fills, which release the interpreter lock), then each row's population
+    and draws are derived from its stream under its map space
+    (``ga_ops.row_draws``) on the calling thread: a row is ~30 short numpy
+    copies, and handing the interpreter lock between threads for each
+    cost more than the threads gained (PERF.md).  ``memo`` carries the
+    streams across the chunks of one call; without it the chunk draws
+    every stream it reads.  Each row's arrays equal its own Generator's,
+    so the chunk is bit-identical to a one-Generator-per-row draw.  An
+    error in any stream is raised here once every thread is done.
 
     Grouped rows add the traced ``grouped`` flags and ragged rows the
     group tables of the ragged program variant (``ChunkInputs``)."""
+    memo = StreamMemo(rows) if memo is None else memo
     kinds = [row.layer.kind for row in rows]
     ragged = [row.layer for row in rows if row.layer.ragged]
     with tracing.span("engine.prepare", rows=len(rows), chunks=1,
@@ -502,8 +572,17 @@ def _prepare_chunk(rows: Sequence[EngineRow], cfg, hw: HWConfig
                     t.orders, t.pairs, t.shapes, t.reprs, t.lens)
 
         # -- per-row state + draws, inert-padded to the buckets -------------
-        workers = _draw_workers(len(rows))
-        with tracing.span("engine.prepare.draws", workers=workers):
+        with tracing.span("engine.prepare.draws") as draws_span:
+            keys = [_stream_key(row) for row in rows]
+            new = [k for k in dict.fromkeys(keys) if k not in memo.streams]
+            drawn = [None] * len(new)
+
+            def draw_stream(j: int) -> None:
+                drawn[j] = ga_ops.draw_stream(*new[j], cfg, gens, n_children)
+
+            workers = _on_draw_threads(draw_stream, len(new))
+            memo.streams.update(zip(new, drawn))
+
             dims = np.ones((n_pad, 6), np.int32)
             stride = np.ones(n_pad, np.int32)
             depthwise = np.zeros(n_pad, np.bool_)
@@ -512,37 +591,22 @@ def _prepare_chunk(rows: Sequence[EngineRow], cfg, hw: HWConfig
             hard_partition = np.zeros(n_pad, np.bool_)
             grouped = np.zeros(n_pad, np.bool_)
             pop0 = np.ones((n_pad, population, GENOME_LEN), np.int32)
-            draw_stack = ga_ops.empty_draw_stack(gens_pad, n_pad, n_children)
-
-            def draw_rows(first: int) -> None:
-                for i in range(first, len(rows), workers):
-                    row = rows[i]
-                    space = mapspace_for(row.layer, row.spec)
-                    rng = np.random.default_rng(row.seed)
-                    pop0[i] = ga_ops.initial_population(rng, space, cfg)
-                    row_draws = ga_ops.draw_run(rng, space, cfg, gens,
-                                                n_children)
-                    for field, stacked in zip(row_draws, draw_stack):
-                        stacked[:gens, i] = field
-                    dims[i] = space.dims
-                    stride[i] = row.layer.stride
-                    depthwise[i] = row.layer.depthwise
-                    grouped[i] = row.layer.grouped
-                    tile_lo[i] = space.tile_lo
-                    tile_hi[i] = space.tile_hi
-                    hard_partition[i] = space.hard_partition
-
-            # rows write disjoint slices, so no lock.  Every thread finishes
-            # before the chunk is returned or an error leaves, so nothing
-            # writes into a chunk after it is abandoned.
-            helpers = [_draw_pool().submit(draw_rows, w)
-                       for w in range(1, workers)]
-            try:
-                draw_rows(0)
-            finally:
-                wait(helpers)
-            for h in helpers:
-                h.result()
+            draw_stack = ga_ops.empty_draw_stack(gens_pad, n_pad, n_children,
+                                                 gens, len(rows))
+            for i, row in enumerate(rows):
+                space = mapspace_for(row.layer, row.spec)
+                pop0[i], _ = ga_ops.row_draws(
+                    memo.streams[keys[i]], space,
+                    GenDraws(*(f[:gens, i] for f in draw_stack)))
+                dims[i] = space.dims
+                stride[i] = row.layer.stride
+                depthwise[i] = row.layer.depthwise
+                grouped[i] = row.layer.grouped
+                tile_lo[i] = space.tile_lo
+                tile_hi[i] = space.tile_hi
+                hard_partition[i] = space.hard_partition
+            draws_span.counts.update(workers=workers, streams=len(new))
+            memo.release(keys)
 
         group_dims = group_live = None
         if ragged:

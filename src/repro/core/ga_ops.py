@@ -8,8 +8,15 @@ The golden-parity contract between the one-program engine
      randomness of a GA run — parent-selection ranks, crossover masks and
      permutations, mutation masks/steps/divisor snaps — is drawn up front by
      :func:`draw_run` from a single ``numpy`` Generator, in one fixed call
-     order.  Both GAs call the same function with the same seed, so they
+     order.  Both GAs call the same functions with the same seed, so they
      consume bit-identical draws no matter how the generations are executed.
+     A row's draws come in two stages: the stream stage
+     (:func:`draw_stream`, :func:`draw_run_stream`) makes every Generator
+     call and depends on the map space only through :func:`r_open`; the
+     row stage (:func:`row_draws`, :func:`run_draws`) applies the space.
+     ``initial_population`` and ``draw_run`` are the two stages composed,
+     and the engine draws each (seed, R-open) stream once per call and
+     runs the row stage for every row that reads it.
 
   2. **One operator formula, two array backends.**  The apply functions below
      (`apply_crossover`, `apply_mutation`, `clip_genomes`) are written against
@@ -27,7 +34,7 @@ position into a genome index via their own stable argsort.
 from __future__ import annotations
 
 from functools import lru_cache
-from typing import NamedTuple
+from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -60,26 +67,41 @@ def gen_slice(draws: GenDraws, g: int) -> GenDraws:
     return GenDraws(*(f[g] for f in draws))
 
 
-def empty_draw_stack(gens_pad: int, n_rows: int, n_children: int) -> GenDraws:
-    """Inert (zero/one) draw arrays for a padded engine chunk: rows past the
+# (dtype, trailing shape, inert value) of each GenDraws field: the inert
+# values move no gene (no mask set, step 1.0, divisor 1, walk +1)
+_LAYOUT = GenDraws(
+    ranks=(np.int32, (), 0),
+    perm=(np.int32, (), 0),
+    cross_mask=(np.bool_, (GENOME_LEN,), False),
+    cross_do=(np.bool_, (), False),
+    m_tile=(np.bool_, (NUM_DIMS,), False),
+    step=(np.float32, (NUM_DIMS,), 1.0),
+    snap=(np.bool_, (NUM_DIMS,), False),
+    dv=(np.int32, (NUM_DIMS,), 1),
+    m_idx=(np.bool_, (N_IDX,), False),
+    walk=(np.bool_, (N_IDX,), False),
+    stepdir=(np.int32, (N_IDX,), 1),
+    sampled=(np.int32, (N_IDX,), 0),
+)
+
+
+def _unwritten_draws(shape) -> GenDraws:
+    return GenDraws(*(np.empty(shape + tail, dtype)
+                      for dtype, tail, _ in _LAYOUT))
+
+
+def empty_draw_stack(gens_pad: int, n_rows: int, n_children: int,
+                     gens: int = 0, live: int = 0) -> GenDraws:
+    """Draw arrays for a padded engine chunk, inert everywhere except
+    ``[:gens, :live]``, which is left unwritten for the live rows to fill
+    (:func:`run_draws` writes every element of its ``out``).  Rows past the
     true row count and generations past the fori_loop bound are never
-    executed, so their contents only need shape-stable placeholders.  Shared
-    by every chunk-preparation path (plain and pipelined)."""
-    shape = (gens_pad, n_rows, n_children)
-    return GenDraws(
-        ranks=np.zeros(shape, np.int32),
-        perm=np.zeros(shape, np.int32),
-        cross_mask=np.zeros(shape + (GENOME_LEN,), np.bool_),
-        cross_do=np.zeros(shape, np.bool_),
-        m_tile=np.zeros(shape + (NUM_DIMS,), np.bool_),
-        step=np.ones(shape + (NUM_DIMS,), np.float32),
-        snap=np.zeros(shape + (NUM_DIMS,), np.bool_),
-        dv=np.ones(shape + (NUM_DIMS,), np.int32),
-        m_idx=np.zeros(shape + (N_IDX,), np.bool_),
-        walk=np.zeros(shape + (N_IDX,), np.bool_),
-        stepdir=np.ones(shape + (N_IDX,), np.int32),
-        sampled=np.zeros(shape + (N_IDX,), np.int32),
-    )
+    executed, so their contents only need shape-stable placeholders."""
+    stack = _unwritten_draws((gens_pad, n_rows, n_children))
+    for field, (_, _, inert) in zip(stack, _LAYOUT):
+        field[gens:] = inert
+        field[:gens, live:] = inert
+    return stack
 
 
 @lru_cache(maxsize=4096)
@@ -116,31 +138,61 @@ _U_COLS = 38
 _U_R_COLS = 4
 
 
-def draw_run(rng: np.random.Generator, space, cfg, gens: int,
-             n: int) -> GenDraws:
-    """Draw every random quantity for ``gens`` generations of ``n`` children.
+def r_open(lens) -> bool:
+    """Whether the representation table is open, from a space's
+    ``table_lens()``: the one test that decides whether a row's Generator
+    makes the R-axis draws, so with the seed it keys the row's stream."""
+    return bool(lens[3] > 1)
 
-    Four bulk Generator calls (uniform slab, normal steps, mate
-    permutations, walk directions) — a model-level batched search makes one
-    ``draw_run`` per row, so per-call Generator overhead is the engine's
-    host-side hot path.  Pinned axes (InFlex or unit dims) have their masks
-    forced off, so the applied operators never move them; ``space`` supplies
-    those constraints (``tile_lo``/``tile_hi``, ``dims``, ``table_lens()``).
 
-    The R-axis slab (two extra calls) is drawn ONLY when the representation
-    table is open: a pinned-R run consumes the byte-identical Generator
-    stream of the v4 9-gene engine, which is what makes the R-pinned golden
-    metrics reproduce bit-identically.  The inert fill (1.0 / +1) makes every
-    R-gene predicate false (1.0 < 0.5, 1.0 < rate for rate <= 1).
-    """
+class RunStream(NamedTuple):
+    """What one Generator gives a :func:`draw_run` of ``gens`` generations
+    of ``n`` children before any map space is applied: every row whose
+    Generator has the same seed and R test (:func:`r_open`) reads the same
+    stream.  The shared fields are final; the rest are the unmasked tests,
+    and the uniforms (one contiguous ``(G, n)`` slab per gene) that
+    :func:`run_draws` turns into a row's fields."""
+
+    r_open: bool
+    ranks: np.ndarray       # (G, n)     i32  final
+    perm: np.ndarray        # (G, n)     i32  final
+    cross_mask: np.ndarray  # (G, n, 10) bool final
+    cross_do: np.ndarray    # (G, n)     bool final
+    step: np.ndarray        # (G, n, 6)  f32  final
+    walk: np.ndarray        # (G, n, 4)  bool final
+    stepdir: np.ndarray     # (G, n, 4)  i32  final
+    m_tile_u: np.ndarray    # (G, n, 6)  bool u < mutation_rate
+    snap_u: np.ndarray      # (G, n, 6)  bool u < tile_divisor_bias
+    m_idx_u: np.ndarray     # (G, n, 4)  bool u < mutation_rate
+    dv_u: np.ndarray        # (6, G, n)  f64  divisor-pick uniforms
+    sampled_u: np.ndarray   # (4, G, n)  f64  resample uniforms
+    columns: dict           # (G, n) i32 dv / sampled columns by (field,
+    #                         gene, dim or table length), kept by run_draws
+    #                         for the stream's other rows
+
+
+_SHARED_FIELDS = ("ranks", "perm", "cross_mask", "cross_do", "step", "walk",
+                  "stepdir")
+
+
+def draw_run_stream(rng: np.random.Generator, open_r: bool, cfg, gens: int,
+                    n: int) -> RunStream:
+    """The stream stage of :func:`draw_run`: every Generator call of a run
+    in its fixed order, and all that follows from the draws alone.
+
+    Four bulk calls (uniform slab, normal steps, mate permutations, walk
+    directions), then the R-axis slab (two more calls) ONLY when the
+    representation table is open: a pinned-R run consumes the
+    byte-identical Generator stream of the v4 9-gene engine, which is what
+    makes the R-pinned golden metrics reproduce bit-identically.  The inert
+    fill (1.0 / +1) makes every R-gene predicate false (1.0 < 0.5, 1.0 <
+    rate for rate <= 1)."""
     u = rng.random((gens, n, _U_COLS))
     normal = rng.normal(0.0, 0.7, (gens, n, NUM_DIMS))
     perm = rng.permuted(
         np.tile(np.arange(n, dtype=np.int32), (gens, 1)), axis=1)
     stepdir = (rng.integers(0, 2, (gens, n, 3), dtype=np.int32) * 2 - 1)
-
-    lens = np.asarray(space.table_lens(), np.int64)             # (4,)
-    if lens[3] > 1:
+    if open_r:
         u_r = rng.random((gens, n, _U_R_COLS))
         stepdir_r = (rng.integers(0, 2, (gens, n, 1), dtype=np.int32) * 2 - 1)
     else:
@@ -153,31 +205,80 @@ def draw_run(rng: np.random.Generator, space, cfg, gens: int,
         np.searchsorted(_rank_cdf(cfg.population), u[:, :, 0],
                         side="right"),
         cfg.population - 1).astype(np.int32)
-    cross_mask = np.concatenate(
-        [u[:, :, 1:10], u_r[:, :, 0:1]], axis=-1) < 0.5
-    cross_do = u[:, :, 10] < cfg.crossover_rate
+    return RunStream(
+        r_open=open_r, ranks=ranks, perm=perm,
+        cross_mask=np.concatenate([u[:, :, 1:10], u_r[:, :, 0:1]],
+                                  axis=-1) < 0.5,
+        cross_do=u[:, :, 10] < cfg.crossover_rate,
+        step=np.exp(normal).astype(np.float32),
+        walk=np.concatenate([u[:, :, 32:35], u_r[:, :, 2:3]], axis=-1) < 0.5,
+        stepdir=np.concatenate([stepdir, stepdir_r], axis=-1),
+        m_tile_u=u[:, :, 11:17] < cfg.mutation_rate,
+        snap_u=u[:, :, 17:23] < cfg.tile_divisor_bias,
+        m_idx_u=np.concatenate([u[:, :, 29:32], u_r[:, :, 1:2]],
+                               axis=-1) < cfg.mutation_rate,
+        dv_u=np.ascontiguousarray(np.moveaxis(u[:, :, 23:29], -1, 0)),
+        sampled_u=np.concatenate([np.moveaxis(u[:, :, 35:38], -1, 0),
+                                  u_r[None, :, :, 3]]),
+        columns={})
 
+
+def _column(stream: RunStream, field: str, j: int, n: int) -> np.ndarray:
+    """``dv`` for gene ``j`` of a dim ``n`` (a divisor of ``n`` picked
+    uniformly), or ``sampled`` for index gene ``j`` of a table of length
+    ``n``, from the stream's uniforms; each made once per stream."""
+    col = stream.columns.get((field, j, n))
+    if col is None:
+        if field == "dv":
+            divs = divisors(n)
+            col = divs[(stream.dv_u[j] * len(divs)).astype(np.int64)]
+        else:
+            col = (stream.sampled_u[j] * n).astype(np.int32)
+        stream.columns[field, j, n] = col
+    return col
+
+
+def run_draws(stream: RunStream, space,
+              out: Optional[GenDraws] = None) -> GenDraws:
+    """The row stage of :func:`draw_run`: ``stream`` under one map space.
+    Pinned axes (InFlex or unit dims) have their masks forced off, so the
+    applied operators never move them; ``space`` supplies those
+    constraints (``tile_lo``/``tile_hi``, ``dims``, ``table_lens()``).
+    Writes into ``out`` (arrays of the stream's leading shape, e.g. views
+    into an engine chunk's draw stack) when given.
+
+    The masks are copied whole and their pinned genes cleared, and ``dv``
+    and ``sampled`` are written gene by gene from columns the stream keeps
+    (:func:`_column`): numpy broadcasting a per-gene vector over the last
+    axis runs an inner loop of 4 or 6 elements per child, several times
+    slower."""
+    lens = np.asarray(space.table_lens(), np.int64)             # (4,)
+    assert r_open(lens) == stream.r_open, "stream drawn for another R test"
+    if out is None:
+        out = _unwritten_draws(stream.ranks.shape)
+    for name in _SHARED_FIELDS:
+        getattr(out, name)[...] = getattr(stream, name)
     tile_open = space.tile_lo != space.tile_hi                  # (6,)
-    m_tile = (u[:, :, 11:17] < cfg.mutation_rate) & tile_open
-    step = np.exp(normal).astype(np.float32)
-    snap = (u[:, :, 17:23] < cfg.tile_divisor_bias) & tile_open
-    dv = np.ones((gens, n, NUM_DIMS), np.int32)
-    for d in np.nonzero(tile_open)[0]:
-        divs = divisors(int(space.dims[d]))
-        dv[:, :, d] = divs[(u[:, :, 23 + d] * len(divs)).astype(np.int64)]
+    out.m_tile[...] = stream.m_tile_u
+    out.m_tile[..., ~tile_open] = False
+    out.snap[...] = stream.snap_u
+    out.snap[..., ~tile_open] = False
+    out.m_idx[...] = stream.m_idx_u
+    out.m_idx[..., lens <= 1] = False
+    for d in range(NUM_DIMS):
+        out.dv[..., d] = (_column(stream, "dv", d, int(space.dims[d]))
+                          if tile_open[d] else 1)
+    for j in range(N_IDX):
+        out.sampled[..., j] = _column(stream, "sampled", j, int(lens[j]))
+    return out
 
-    idx_open = lens > 1
-    u_midx = np.concatenate([u[:, :, 29:32], u_r[:, :, 1:2]], axis=-1)
-    m_idx = (u_midx < cfg.mutation_rate) & idx_open
-    walk = np.concatenate([u[:, :, 32:35], u_r[:, :, 2:3]], axis=-1) < 0.5
-    sampled = (np.concatenate([u[:, :, 35:38], u_r[:, :, 3:4]], axis=-1)
-               * lens).astype(np.int32)
-    stepdir = np.concatenate([stepdir, stepdir_r], axis=-1)
 
-    return GenDraws(ranks=ranks, perm=perm, cross_mask=cross_mask,
-                    cross_do=cross_do, m_tile=m_tile, step=step, snap=snap,
-                    dv=dv, m_idx=m_idx, walk=walk, stepdir=stepdir,
-                    sampled=sampled)
+def draw_run(rng: np.random.Generator, space, cfg, gens: int,
+             n: int) -> GenDraws:
+    """Draw every random quantity for ``gens`` generations of ``n`` children:
+    :func:`draw_run_stream` then :func:`run_draws`."""
+    return run_draws(draw_run_stream(rng, r_open(space.table_lens()), cfg,
+                                     gens, n), space)
 
 
 # --------------------------------------------------------------------------
@@ -244,14 +345,60 @@ def single_generation_draws(rng: np.random.Generator, space, cfg,
     return gen_slice(draw_run(rng, space, cfg, 1, n), 0)
 
 
-def initial_population(rng: np.random.Generator, space, cfg) -> np.ndarray:
-    """Sample the starting population and seed slot 0 with the accelerator's
-    baseline fixed mapping (clipped to the layer) so the InFlex design point
-    is always present — both engines start from this exact population."""
-    pop = space.sample(rng, cfg.population)
+def sample_uniforms(rng: np.random.Generator, open_r: bool,
+                    n: int) -> np.ndarray:
+    """The ``(n, 10)`` uniforms behind ``n`` sampled genomes: one ``(n, 9)``
+    draw, then the R column in a SEPARATE call made only when the R table
+    is open (zeros when pinned), so a pinned-R space consumes the
+    byte-identical Generator stream of the v4 9-gene sampler."""
+    u = rng.random((n, 9))
+    u_r = rng.random((n, 1)) if open_r else np.zeros((n, 1))
+    return np.concatenate([u, u_r], axis=-1)
+
+
+def population_from(space, u: np.ndarray) -> np.ndarray:
+    """The starting population from its :func:`sample_uniforms`, with slot 0
+    seeded by the accelerator's baseline fixed mapping (clipped to the
+    layer) so the InFlex design point is always present."""
+    pop = space.genomes_from(u)
     base = space.clip(np.concatenate([
         np.minimum(np.asarray(space.spec.tile.fixed_tile, np.int32),
                    space.dims),
         [0, 0, 0, 0]])[None, :])
     pop[0] = base[0]
     return pop
+
+
+def initial_population(rng: np.random.Generator, space, cfg) -> np.ndarray:
+    """Sample the starting population (:func:`population_from`) — both
+    engines start from this exact population."""
+    return population_from(space, sample_uniforms(
+        rng, r_open(space.table_lens()), cfg.population))
+
+
+class DrawStream(NamedTuple):
+    """A row's whole Generator sequence: its initial population's uniforms,
+    then its GA run's stream."""
+
+    pop_u: np.ndarray       # (P, 10) f64
+    run: RunStream
+
+
+def draw_stream(seed: int, open_r: bool, cfg, gens: int,
+                n: int) -> DrawStream:
+    """The stream stage of a row seeded ``seed``: what
+    :func:`initial_population` then :func:`draw_run` draw from
+    ``default_rng(seed)``.  It depends on the map space only through
+    :func:`r_open`, so rows with equal ``(seed, open_r)`` share it."""
+    rng = np.random.default_rng(seed)
+    pop_u = sample_uniforms(rng, open_r, cfg.population)
+    return DrawStream(pop_u, draw_run_stream(rng, open_r, cfg, gens, n))
+
+
+def row_draws(stream: DrawStream, space, out: Optional[GenDraws] = None
+              ) -> Tuple[np.ndarray, GenDraws]:
+    """The row stage: ``stream`` under one map space, as the row's initial
+    population and GA draws (into ``out`` when given, see
+    :func:`run_draws`)."""
+    return (population_from(space, stream.pop_u),
+            run_draws(stream.run, space, out))

@@ -20,7 +20,7 @@ from typing import NamedTuple, Optional, Tuple
 
 import numpy as np
 
-from .ga_ops import clip_genomes
+from .ga_ops import clip_genomes, r_open, sample_uniforms
 from .spec import FULLFLEX, FlexSpec, HWConfig, INFLEX, ShapeSpec
 from .workloads import Layer, NUM_DIMS
 
@@ -129,19 +129,20 @@ class MapSpace:
         engine samples one population per row, so this is a hot path).
 
         The R gene is drawn in a SEPARATE call made only when the R table is
-        open — a pinned-R space consumes the byte-identical Generator stream
-        of the v4 9-gene sampler (golden-parity discipline; see ga_ops)."""
+        open (``ga_ops.sample_uniforms``): a pinned-R space consumes the
+        byte-identical Generator stream of the v4 9-gene sampler
+        (golden-parity discipline; see ga_ops)."""
+        return self.genomes_from(
+            sample_uniforms(rng, r_open(self.table_lens()), n))
+
+    def genomes_from(self, u: np.ndarray) -> np.ndarray:
+        """Legal genomes from ``(n, 10)`` uniforms in [0, 1)."""
         lo = np.concatenate([self.tile_lo, np.zeros(3, np.int64)])
         lens = self.table_lens().astype(np.int64)
         span = np.concatenate([(self.tile_hi - self.tile_lo + 1).astype(
             np.int64), lens[:3]])
-        u = rng.random((n, 9))
-        if lens[3] > 1:
-            u_r = rng.random((n, 1))
-        else:
-            u_r = np.zeros((n, 1))
-        legacy = (lo + u * span).astype(np.int32)
-        r = (u_r * lens[3]).astype(np.int32)
+        legacy = (lo + u[:, :9] * span).astype(np.int32)
+        r = (u[:, 9:] * lens[3]).astype(np.int32)
         return np.concatenate([legacy, r], axis=-1)
 
     def table_lens(self) -> np.ndarray:

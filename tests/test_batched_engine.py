@@ -13,8 +13,10 @@ import pytest
 from repro.core import (FULLFLEX, GAConfig, PARTFLEX, inflex_baseline,
                         make_variant, run_dse, search, search_campaign,
                         search_model)
-from repro.core import engine, ga_ops
+from repro.core import engine, ga_ops, tracing
 from repro.core import mapper as mapper_mod
+from repro.core.mapper import plan_model_rows, request_rows
+from repro.core.mapspace import mapspace_for
 from repro.core.engine import ROW_BUCKET, EngineRow, run_batched_ga
 from repro.core.workloads import Layer, get_model
 
@@ -171,6 +173,19 @@ def _chunk_rows(n):
             for i in range(n)]
 
 
+def _assert_chunks_equal(a, b):
+    assert a.gens == b.gens
+    for name in engine.ChunkInputs._fields:
+        x, y = getattr(a, name), getattr(b, name)
+        if name == "draws":
+            for field in x._fields:
+                assert np.array_equal(getattr(x, field),
+                                      getattr(y, field)), field
+        elif name != "gens":
+            assert (x is None) == (y is None), name
+            assert x is None or np.array_equal(x, y), name
+
+
 @pytest.mark.parametrize("n_rows", [1, 7, ROW_BUCKET])
 def test_pooled_draws_match_inline_draws(monkeypatch, n_rows):
     """Every array of a chunk drawn on several threads equals the one a
@@ -182,17 +197,7 @@ def test_pooled_draws_match_inline_draws(monkeypatch, n_rows):
     pooled = engine._prepare_chunk(rows, CFG, hw)
     monkeypatch.setattr(engine, "_draw_workers", lambda n: 1)
     inline = engine._prepare_chunk(rows, CFG, hw)
-    assert pooled.gens == inline.gens
-    for name in engine.ChunkInputs._fields:
-        if name == "gens":
-            continue
-        a, b = getattr(pooled, name), getattr(inline, name)
-        if name == "draws":
-            for field in a._fields:
-                assert np.array_equal(getattr(a, field),
-                                      getattr(b, field)), field
-        else:
-            assert np.array_equal(a, b), name
+    _assert_chunks_equal(pooled, inline)
 
 
 @pytest.mark.parametrize("bad_row", [5, ROW_BUCKET + 5])
@@ -232,6 +237,153 @@ def test_draw_error_reaches_the_chunk_handler(monkeypatch, bad_row):
     assert isinstance(err.value.__cause__, ValueError)
     assert len(queues) == 1 and len(queues[0]) == 0
     assert collected == [ROW_BUCKET] * idx
+
+
+# -- one draw stream per (seed, R test) within a call ------------------------
+
+def _per_row_draws(rows, cfg):
+    """A chunk's ``pop0`` and draw stack drawn the per-row way: each row's
+    own Generator, ``initial_population`` then ``draw_run``."""
+    n_children = cfg.population - ga_ops.n_elite(cfg)
+    gens = cfg.generations
+    gens_pad = engine._bucket(max(gens, 1), engine.GEN_BUCKET)
+    pop0 = np.ones((ROW_BUCKET, cfg.population, ga_ops.GENOME_LEN),
+                   np.int32)
+    stack = ga_ops.empty_draw_stack(gens_pad, ROW_BUCKET, n_children)
+    for i, row in enumerate(rows):
+        space = mapspace_for(row.layer, row.spec)
+        rng = np.random.default_rng(row.seed)
+        pop0[i] = ga_ops.initial_population(rng, space, cfg)
+        for field, stacked in zip(
+                ga_ops.draw_run(rng, space, cfg, gens, n_children), stack):
+            stacked[:gens, i] = field
+    return pop0, stack
+
+
+def _assert_per_row_draws(chunk, rows, cfg):
+    pop0, stack = _per_row_draws(rows, cfg)
+    assert np.array_equal(chunk.pop0, pop0)
+    for field in stack._fields:
+        assert np.array_equal(getattr(chunk.draws, field),
+                              getattr(stack, field)), field
+
+
+def _campaign_rows(models, classes, cfg, n_layers=6):
+    """A campaign's rows: the seed convention repeats each seed across the
+    requests' specs and models."""
+    rows = []
+    for model in models:
+        layers = get_model(model)[:n_layers]
+        row_index, _ = plan_model_rows(layers)
+        for cs, flex in classes:
+            rows += request_rows(layers, make_variant(cs, flex), cfg,
+                                 row_index)
+    return rows
+
+
+def _kimi_rows(ragged, cfg):
+    layers = [l for l in get_model("kimi-k2-decode32k")
+              if l.grouped and l.ragged == ragged][:4]
+    return [r for spec in (make_variant("1111"), make_variant("0101"))
+            for r in request_rows(layers, spec, cfg, range(len(layers)))]
+
+
+MEMO_CASES = {
+    # one seed per layer position, read by 3 specs of 2 models
+    "shared-seeds": lambda cfg: _campaign_rows(
+        ("mnasnet", "resnet50"),
+        (("1111", FULLFLEX), ("1111", PARTFLEX), ("0101", FULLFLEX)), cfg),
+    # the same seeds R-pinned and R-open: two streams each
+    "r-open-and-pinned": lambda cfg: _campaign_rows(
+        ("mnasnet",), (("1111", FULLFLEX), ("11111", FULLFLEX)), cfg),
+    "grouped": lambda cfg: _kimi_rows(False, cfg),
+    "ragged": lambda cfg: _kimi_rows(True, cfg),
+    "one-row": lambda cfg: _campaign_rows(
+        ("mnasnet",), (("11111", FULLFLEX),), cfg, n_layers=1),
+}
+
+
+@pytest.mark.parametrize("case", list(MEMO_CASES))
+def test_shared_streams_equal_per_row_draws(case):
+    """A chunk drawn from shared streams equals the per-row draws, and
+    draws each distinct (seed, R test) stream once."""
+    rows = MEMO_CASES[case](CFG)
+    assert 1 <= len(rows) <= ROW_BUCKET
+    hw = rows[0].spec.hw
+    sink = {}
+    with tracing.recording(sink):
+        chunk = engine._prepare_chunk(rows, CFG, hw)
+    _assert_per_row_draws(chunk, rows, CFG)
+    keys = {engine._stream_key(r) for r in rows}
+    assert sink["engine.prepare.draws:streams"] == len(keys)
+    if case == "r-open-and-pinned":
+        seeds = {r.seed for r in rows}
+        assert len(keys) == 2 * len(seeds)
+    elif case != "one-row":
+        assert len(keys) < len(rows)
+    if case in ("grouped", "ragged"):
+        assert chunk.grouped is not None
+        assert (chunk.group_dims is not None) == (case == "ragged")
+
+
+@pytest.mark.parametrize("pipeline", [False, True])
+def test_stream_memo_carries_across_the_chunks_of_a_call(monkeypatch,
+                                                         pipeline):
+    """One call's chunks share its memo: each stream is drawn once for the
+    call, and every chunk equals the per-row draws and the same chunk
+    prepared alone."""
+    rows = _campaign_rows(("mnasnet", "mobilenetv2"),
+                          (("1111", FULLFLEX), ("11111", FULLFLEX),
+                           ("0101", PARTFLEX)), CFG, n_layers=12)
+    assert len(rows) > ROW_BUCKET
+    prepared = []
+    real = engine._prepare_chunk
+
+    def spy(chunk_rows, cfg, hw, memo=None):
+        assert memo is not None
+        prepared.append((list(chunk_rows), real(chunk_rows, cfg, hw, memo)))
+        return prepared[-1][1]
+
+    monkeypatch.setattr(engine, "_prepare_chunk", spy)
+    sink = {}
+    with tracing.recording(sink):
+        run_batched_ga(rows, GAConfig(population=CFG.population,
+                                      generations=CFG.generations,
+                                      seed=CFG.seed, pipeline=pipeline))
+    assert len(prepared) == 2
+    assert sink["engine.prepare.draws:streams"] == len(
+        {engine._stream_key(r) for r in rows})
+    monkeypatch.setattr(engine, "_prepare_chunk", real)
+    for chunk_rows, chunk in prepared:
+        _assert_per_row_draws(chunk, chunk_rows, CFG)
+        _assert_chunks_equal(chunk, real(chunk_rows, CFG, chunk_rows[0]
+                                         .spec.hw))
+
+
+@pytest.mark.parametrize("stage", ["stream", "row"])
+def test_stream_error_reaches_the_chunk_handler(monkeypatch, stage):
+    """An error in a stream draw (on a draw thread) or in a row's
+    derivation fails its chunk with the chunk's context."""
+    rows = [EngineRow(Layer(f"l{i}", (8, 4, 6, 6, 3, 3)),
+                      make_variant("1111"), seed=i)
+            for i in range(ROW_BUCKET + 6)]
+    bad = ROW_BUCKET + 2
+    name = "draw_stream" if stage == "stream" else "row_draws"
+    real = getattr(ga_ops, name)
+
+    def failing(first, *args):
+        if (first == bad if stage == "stream"
+                else args[0].layer.name == f"l{bad}"):
+            raise ValueError(f"bad {stage}")
+        return real(first, *args)
+
+    monkeypatch.setattr(ga_ops, name, failing)
+    cfg = GAConfig(population=8, generations=4, seed=3, pipeline=True)
+    with pytest.raises(RuntimeError,
+                       match="engine chunk 1/2 .* failed during "
+                             "prepare/dispatch") as err:
+        run_batched_ga(rows, cfg)
+    assert isinstance(err.value.__cause__, ValueError)
 
 
 def _evaluate_gathers(hlo: str):

@@ -43,6 +43,8 @@ def test_recorder_counts_live_rows_and_chunks(recorded):
     assert len(out) == N_ROWS
     assert sink["engine.prepare:rows"] == N_ROWS
     assert sink["engine.prepare:chunks"] == 2
+    # every row has a seed of its own, so a stream of its own
+    assert sink["engine.prepare.draws:streams"] == N_ROWS
     for name in ("engine.prepare", "engine.prepare.tables",
                  "engine.prepare.draws", "engine.dispatch", "engine.wait",
                  "engine.unpack"):
@@ -164,12 +166,15 @@ def test_study_phase_keys(study_timings):
 @pytest.mark.parametrize("metric", [
     "prepare_us_per_row.campaign", "draws_us_per_row.campaign",
     "wait_share.campaign", "flexion_draw_s.campaign", "design_s.campaign",
-    "flexion_s.campaign", "sweep_s.campaign"])
+    "flexion_s.campaign", "sweep_s.campaign", "stream_share.campaign"])
 def test_benchmark_readers_find_the_spans(study_timings, metric):
     value = _reader(metric)({"counters": {"timings": [study_timings]}})
     assert value is not None and math.isfinite(value)
-    if metric == "wait_share.campaign":
+    if metric in ("wait_share.campaign", "stream_share.campaign"):
         assert 0.0 <= value <= 100.0
+    if metric == "stream_share.campaign":
+        # the sweep's 2 specs x 2 models read each R-pinned seed's stream
+        assert value < 100.0
 
 
 def test_ga_program_scopes():
